@@ -112,7 +112,7 @@ def spmm_vmem_bytes(*, bm: int, bk: int, bn: int, unroll: int,
 
     Pipelined: ``acc(row·bn·4) + out window(row·bn·2) + A ring
     (2·unroll·bm·bk) + B ring (2·unroll·contract·bn)`` plus, when
-    quantized, the per-step scale window — ``(1, unroll)`` fp32 per-block,
+    quantized, the per-step scale window — ``(1, 1, unroll)`` fp32 per-block,
     ``(1, unroll, bm)`` in rowwise mode.  Legacy: the BlockSpec
     auto-pipeline double-buffers ``unroll`` A tiles and ``unroll`` B
     stripes instead of the explicit rings (per-block scales ride the SMEM

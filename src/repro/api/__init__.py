@@ -27,8 +27,9 @@ from repro.core.policies import (SchedulePolicy, available_policies,
                                  get_policy, register_policy,
                                  unregister_policy)
 
-from .backends import (BACKENDS, available_backends, default_backend,
-                       resolve_backend, set_default_backend, use_backend)
+from .backends import (BACKENDS, LANE, BlockShapeError, available_backends,
+                       default_backend, resolve_backend, set_default_backend,
+                       use_backend)
 from .executor import apply_plan, execute_plan, pick_bn
 from .plan import SPGEMM, SPMM, SegmentPlan
 from .planner import (clear_plan_cache, pattern_fingerprint, plan_cache_stats,
@@ -48,6 +49,6 @@ __all__ = [
     "SchedulePolicy", "register_policy", "unregister_policy", "get_policy",
     "available_policies",
     # backends
-    "BACKENDS", "available_backends", "default_backend", "set_default_backend",
-    "resolve_backend", "use_backend",
+    "BACKENDS", "LANE", "BlockShapeError", "available_backends",
+    "default_backend", "set_default_backend", "resolve_backend", "use_backend",
 ]

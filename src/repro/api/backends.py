@@ -1,6 +1,4 @@
-"""Backend dispatch for plan execution — replaces the ``ops.INTERPRET`` global.
-
-Three backends, one switch:
+"""Backend dispatch for plan execution — three backends, one switch:
 
 * ``"pallas"``    — compiled Pallas kernels (TPU).
 * ``"interpret"`` — the same Pallas kernels in interpret mode (CPU-correct;
@@ -21,6 +19,27 @@ from typing import Iterator, Optional, Tuple
 import jax
 
 BACKENDS: Tuple[str, ...] = ("pallas", "interpret", "reference")
+
+#: Lane width of the TPU's vector registers.  On the compiled backend every
+#: block dimension and every N-tile is a multiple of it: Mosaic refuses the
+#: A-tile DMA of a 64-wide block ("Slice shape … must be aligned to tiling
+#: (128)") and any B/C window narrower than 128 columns.
+LANE = 128
+
+
+class BlockShapeError(ValueError):
+    """A block shape the compiled ``pallas`` backend cannot run."""
+
+
+def check_block_shape(block_shape, backend: str) -> None:
+    """Refuse block dimensions that are not multiples of :data:`LANE` on the
+    compiled backend; ``interpret`` and ``reference`` take any block."""
+    if backend == "pallas" and any(int(d) % LANE for d in block_shape):
+        raise BlockShapeError(
+            f"block shape {tuple(int(d) for d in block_shape)} cannot run "
+            f"on backend='pallas': every block dimension must be a multiple "
+            f"of {LANE} (the TPU lane width); use 128-wide blocks, or the "
+            f"'interpret'/'reference' backend for smaller ones")
 
 _default_backend: Optional[str] = None
 

@@ -19,6 +19,9 @@ The N-tile width is normalized in one place (:func:`pick_bn`): the executor
 either shrinks ``bn`` to the largest divisor of N or pads N up to a tile
 multiple and slices the result — arbitrary N is legal (the old
 ``SpmmPlan.__call__`` crashed on any N not divisible by the tile width).
+On the compiled ``pallas`` backend the tile is always a multiple of the
+128-wide lane dimension (Mosaic refuses narrower B/C windows), so a decode
+batch of N = 8 runs as one padded 128-wide tile.
 """
 from __future__ import annotations
 
@@ -33,18 +36,30 @@ from repro.kernels import ref
 from repro.kernels.segment_spgemm import segment_spgemm
 from repro.kernels.segment_spmm import segment_spmm
 
-from .backends import backend_interpret_flag, resolve_backend
+from .backends import (LANE, backend_interpret_flag, check_block_shape,
+                       resolve_backend)
 from .plan import SPGEMM, SPMM, SegmentPlan
 
 
-def pick_bn(n: int, bn: int) -> Tuple[int, int]:
+def pick_bn(n: int, bn: int, *, align: int = 1) -> Tuple[int, int]:
     """Normalize the N-tile width for an ``(…, N)`` right-hand side.
 
     Returns ``(bn_eff, pad)`` with ``(n + pad) % bn_eff == 0``.  Prefers the
     largest divisor of ``n`` that is ≤ ``bn`` when it keeps tiles reasonably
     wide (at least half the request, or the full lane width); otherwise keeps
     the requested width and zero-pads N (padded C columns are sliced off).
+
+    ``align > 1`` makes ``bn_eff`` a multiple of ``align`` (the compiled
+    backend passes :data:`~repro.api.backends.LANE`): N is first padded up
+    to a multiple of ``align`` and the same rule runs in units of ``align``.
     """
+    if align > 1:
+        units = -(-n // align)
+        bu = max(1, min(bn // align, units))
+        if units % bu:
+            div = max(d for d in range(1, bu + 1) if units % d == 0)
+            bu = div if 2 * div >= bu else bu
+        return bu * align, -(-units // bu) * bu * align - n
     bn = max(1, min(bn, n))
     if n % bn == 0:
         return bn, 0
@@ -103,8 +118,9 @@ def _run_spmm(plan: SegmentPlan, x: jax.Array, *, backend: str,
             out = ref.spmm_ref(blocks, plan.a_brow, plan.a_bcol, gm, gk, x,
                                scales=scales)
         return out.astype(out_dtype)
+    check_block_shape(blocks.shape[1:], backend)
     n = x.shape[1]
-    bn_eff, pad = pick_bn(n, bn)
+    bn_eff, pad = pick_bn(n, bn, align=LANE if backend == "pallas" else 1)
     xp = jnp.pad(x, ((0, 0), (0, pad))) if pad else x
     out = segment_spmm(
         blocks, plan.slot_idx, plan.m_idx, plan.k_idx, plan.seg_start,
@@ -141,6 +157,8 @@ def _run_spgemm(plan: SegmentPlan, *, backend: str,
             plan.c_brow_arr, plan.c_bcol_arr,
             a_scales=plan.lhs_scales, b_scales=plan.rhs_scales)
         return out.astype(out_dtype)
+    check_block_shape(plan.lhs_blocks.shape[1:], backend)
+    check_block_shape(plan.rhs_blocks.shape[1:], backend)
     return segment_spgemm(
         plan.lhs_blocks, plan.rhs_blocks, plan.a_idx, plan.b_idx, plan.c_idx,
         plan.seg_start, plan.seg_write, plan.accum_prev, plan.valid,

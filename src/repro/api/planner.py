@@ -49,7 +49,7 @@ from repro.core.schedule import (PREFETCH_MODES, LaneLayout,
                                  lane_traffic_spgemm, lane_traffic_spmm,
                                  partition_lanes)
 
-from .backends import resolve_backend
+from .backends import LANE, check_block_shape, resolve_backend
 from .plan import SPGEMM, SPMM, SegmentPlan
 
 
@@ -488,7 +488,9 @@ def plan_matmul(a: BSR, b_or_shape=None, *, policy: str = "segment",
         search must honour.  Winning schedules are cached by pattern
         fingerprint, so repeat patterns pay zero search cost.
       backend: preferred execution backend recorded on the plan (resolvable
-        later; ``None`` defers to the process default).
+        later; ``None`` defers to the process default).  ``"pallas"``
+        refuses block dimensions that are not multiples of 128 here, with a
+        :class:`~repro.api.backends.BlockShapeError`.
       fold_len: temporal-fold cap on segment length (fold-capable policies).
       with_grad: also build the transposed schedule so ``apply_plan`` can run
         the backward pass (SpMM only).
@@ -523,7 +525,8 @@ def plan_matmul(a: BSR, b_or_shape=None, *, policy: str = "segment",
         :class:`~repro.analysis.VmemBudgetError` at plan time — a bad
         (block, bn, unroll) knob combination fails here, not as an OOM at
         launch.  The N-tile width is taken as the executor default
-        (``bn_hint`` or 512) clamped by ``pick_bn`` to the traffic hint's N.
+        (``bn_hint`` or 512) clamped by ``pick_bn`` to the traffic hint's N
+        (lane-aligned for ``backend="pallas"``).
       pipeline: ``False`` builds the plan for the legacy BlockSpec
         auto-pipeline instead of the explicit DMA pipeline; the recorded
         traffic estimate follows the same switch.
@@ -601,6 +604,8 @@ def plan_matmul(a: BSR, b_or_shape=None, *, policy: str = "segment",
 
     kind = SPGEMM if b is not None else SPMM
     mats = (a, b) if b is not None else (a,)
+    for m in mats:
+        check_block_shape(m.block_shape, backend)
     key = pattern_fingerprint(kind, f"{policy}#{pol.serial}", fold_len,
                               with_grad, *mats, n_lanes=n_lanes,
                               unroll=unroll, block_dtype=block_dtype,
@@ -649,7 +654,8 @@ def plan_matmul(a: BSR, b_or_shape=None, *, policy: str = "segment",
         from repro.analysis.budget import check_plan_vmem
 
         from .executor import pick_bn
-        bn_eff, _ = pick_bn(max(1, hint), bn_hint or 512)
+        bn_eff, _ = pick_bn(max(1, hint), bn_hint or 512,
+                            align=LANE if backend == "pallas" else 1)
         check_plan_vmem(plan, bn=bn_eff, limit=vmem_limit_bytes,
                         label=f"plan_matmul[{kind}]")
     return plan

@@ -42,6 +42,7 @@ def reduced_config(cfg: ModelConfig) -> ModelConfig:
         local_window=32,
         n_frontend_tokens=min(cfg.n_frontend_tokens, 8),
         attn_chunk=64,
+        ffn_block=32,     # d_model=64: interpret/reference backends only
         remat=False)
 
 

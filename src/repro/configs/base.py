@@ -36,7 +36,7 @@ class ModelConfig:
     n_frontend_tokens: int = 0
     # the paper's technique: block-sparse FFN weights
     ffn_block_sparse: bool = False
-    ffn_block: int = 64
+    ffn_block: int = 128          # a multiple of 128 on backend="pallas"
     ffn_density: float = 0.25
     # misc
     dtype: str = "bfloat16"

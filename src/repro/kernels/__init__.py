@@ -2,13 +2,13 @@
 
 Each kernel module pairs with a pure-jnp oracle in :mod:`repro.kernels.ref`;
 :mod:`repro.kernels.ops` exposes the jit'd public wrappers (interpret mode
-auto-selected on CPU).
+unless the default backend is ``pallas``).
 """
 from . import ops, ref
-from .ops import (INTERPRET, SpgemmPlan, SpmmPlan, flash_mha, moe_apply,
+from .ops import (SpgemmPlan, SpmmPlan, flash_mha, moe_apply,
                   plan_spgemm, plan_spmm, rg_lru_scan)
 
 __all__ = [
-    "ops", "ref", "INTERPRET", "SpgemmPlan", "SpmmPlan", "flash_mha",
+    "ops", "ref", "SpgemmPlan", "SpmmPlan", "flash_mha",
     "moe_apply", "plan_spgemm", "plan_spmm", "rg_lru_scan",
 ]

@@ -21,8 +21,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 _NEG_INF = -1e30
 
@@ -138,6 +136,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
         ],
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), out_dtype),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(q, k, v)

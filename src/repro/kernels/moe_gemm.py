@@ -24,8 +24,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def _kernel(chunk_expert, x, w, out):
     out[...] = jax.lax.dot_general(
@@ -80,7 +78,7 @@ def moe_gemm(x_sorted, w, chunk_expert, *, chunk_rows: int = 128,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, d_out), out_dtype),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
     )(chunk_expert, x_sorted, w)
 

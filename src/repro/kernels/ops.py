@@ -5,10 +5,9 @@ Plan construction moved to :mod:`repro.api` — :func:`plan_spmm` /
 ``repro.api.plan_matmul`` and return the unified :class:`SegmentPlan`
 (call-compatible with the old ``SpmmPlan``/``SpgemmPlan``).
 
-``INTERPRET`` is likewise deprecated: backend selection (compiled /
-interpret / reference) now lives in :mod:`repro.api.backends`; the module
-global is kept only so old call sites keep working and mirrors the default
-backend at import time.
+Backend selection (compiled / interpret / reference) lives in
+:mod:`repro.api.backends`; the wrappers below resolve their ``interpret``
+flag from it at call time, so importing this module touches no device.
 """
 from __future__ import annotations
 
@@ -18,6 +17,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.api.backends import default_backend
 from repro.api.plan import SegmentPlan
 from repro.api.planner import plan_matmul
 from repro.core.formats import BSR
@@ -27,11 +27,10 @@ from .moe_gemm import build_moe_chunks, moe_gemm
 from .rg_lru import rg_lru
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-INTERPRET = _default_interpret()   # deprecated: see repro.api.backends
+def _interpret(flag: Optional[bool]) -> bool:
+    """An explicit ``interpret`` flag, else compiled only when the default
+    backend is ``pallas``."""
+    return default_backend() != "pallas" if flag is None else flag
 
 # Deprecated aliases — both old plan classes are now the one SegmentPlan.
 SpmmPlan = SegmentPlan
@@ -73,7 +72,7 @@ def flash_mha(q, k, v, *, causal: bool = True, window: Optional[int] = None,
     head is read from HBM once instead of ``rep`` times (the old path
     materialized ``jnp.repeat`` copies of K and V).
     """
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = _interpret(interpret)
     b, tq, h, d = q.shape
     tk, hkv = k.shape[1], k.shape[2]
     rep = h // hkv
@@ -108,7 +107,7 @@ def flash_mha(q, k, v, *, causal: bool = True, window: Optional[int] = None,
 
 def rg_lru_scan(x, a_gate, x_gate, a_param, h0=None, *, ct: int = 128,
                 interpret: Optional[bool] = None):
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = _interpret(interpret)
     if h0 is None:
         h0 = jnp.zeros((x.shape[0], x.shape[2]), jnp.float32)
     return rg_lru(x, a_gate, x_gate, a_param, h0, ct=min(ct, x.shape[1]),
@@ -123,7 +122,7 @@ def moe_apply(x, w_up, w_down, router_logits, *, top_k: int = 1,
     x: (T, d_model); w_up: (E, d_model, d_ff); w_down: (E, d_ff, d_model).
     Returns (T, d_model).
     """
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = _interpret(interpret)
     t, d_model = x.shape
     n_exp = w_up.shape[0]
     top_vals, top_idx = jax.lax.top_k(router_logits, top_k)      # (T, top_k)
@@ -153,6 +152,6 @@ def moe_apply(x, w_up, w_down, router_logits, *, top_k: int = 1,
 
 
 __all__ = [
-    "INTERPRET", "SpmmPlan", "SpgemmPlan", "plan_spmm", "plan_spgemm",
+    "SpmmPlan", "SpgemmPlan", "plan_spmm", "plan_spgemm",
     "flash_mha", "rg_lru_scan", "moe_apply", "ref",
 ]

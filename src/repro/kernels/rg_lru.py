@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def _kernel(x_ref, ag_ref, xg_ref, ap_ref, h0_ref, o_ref, hT_ref, h_ref, *,
             ct, n_chunks, c):
@@ -87,7 +85,7 @@ def rg_lru(x, a_gate, x_gate, a_param, h0, *, ct: int = 128, c: float = 8.0,
             jax.ShapeDtypeStruct((b, d), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(x, a_gate, x_gate, a_param, h0)
     return out, h_t

@@ -5,7 +5,7 @@ Two-phase TPU adaptation of SEGMENTBC (§III-B): the *symbolic* phase
 time — the V-space becomes a static compressed coordinate list at block
 granularity — and this *numeric* kernel executes the (m, k, n) block triples
 in Segment order through an **explicit double-buffered DMA pipeline**: both
-operand block arrays live in HBM (``pltpu.ANY`` refs) and the kernel issues
+operand block arrays live in HBM (``pl.ANY`` refs) and the kernel issues
 ``pltpu.make_async_copy`` for triple *i+1*'s A/B tiles into ``2·unroll``-slot
 VMEM ring buffers while triple *i* runs on the MXU, waiting only at
 consumption:
@@ -42,7 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 from .segment_spmm import resolve_pipeline, validate_schedule_args
 
 
@@ -207,9 +206,9 @@ def _make_pipeline_kernel(lane_len: int, unroll: int, masked: bool,
             # the fp32 product (after the dot, before accumulation) is
             # exact; the step's scales arrive as one VMEM vector each
             if quant_a == "block":
-                contrib = contrib * a_scale_ref[0, g]
+                contrib = contrib * a_scale_ref[0, :, g:g + 1]
             if quant_b == "block":
-                contrib = contrib * b_scale_ref[0, g]
+                contrib = contrib * b_scale_ref[0, :, g:g + 1]
             if masked:
                 contrib = jnp.where(valid[i] == 1, contrib, 0.0)
             acc[...] += contrib
@@ -321,11 +320,12 @@ def segment_spgemm(a_blocks, b_blocks, a_idx, b_idx, c_idx, seg_start,
     n_steps = lane_len // unroll
     scalars = (a_idx, b_idx, c_idx, seg_start, seg_write, accum_prev,
                valid, a_fetch, b_fetch, a_slot, b_slot)
-    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY)]
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)]
     operands = [a_blocks, b_blocks]
+    # (steps, 1, unroll) per-block scales: see segment_spmm for the layout
     scale_spec = pl.BlockSpec(
-        (1, unroll), lambda l, s, *rest: (l * n_steps + s, 0))
+        (1, 1, unroll), lambda l, s, *rest: (l * n_steps + s, 0, 0))
 
     def row_spec(rows):
         return pl.BlockSpec(
@@ -333,14 +333,14 @@ def segment_spgemm(a_blocks, b_blocks, a_idx, b_idx, c_idx, seg_start,
 
     if quant_a == "block":
         in_specs.append(scale_spec)
-        operands.append(jnp.take(a_scales, a_idx).reshape(-1, unroll))
+        operands.append(jnp.take(a_scales, a_idx).reshape(-1, 1, unroll))
     elif quant_a == "rowwise":
         in_specs.append(row_spec(bm))
         operands.append(
             jnp.take(a_scales, a_idx, axis=0).reshape(-1, unroll, bm))
     if quant_b == "block":
         in_specs.append(scale_spec)
-        operands.append(jnp.take(b_scales, b_idx).reshape(-1, unroll))
+        operands.append(jnp.take(b_scales, b_idx).reshape(-1, 1, unroll))
     elif quant_b == "rowwise":
         in_specs.append(row_spec(bk))
         operands.append(
@@ -367,7 +367,7 @@ def segment_spgemm(a_blocks, b_blocks, a_idx, b_idx, c_idx, seg_start,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(*scalars, *operands)
 
@@ -423,6 +423,6 @@ def _legacy_spgemm_call(a_blocks, b_blocks, a_idx, b_idx, c_idx, seg_start,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(*prefetch, *operands)

@@ -4,7 +4,7 @@ The TPU realization of the paper's dynamic dataflow for sparse-weight
 layers.  The kernel runs a **lane-parallel work list** of nonzero A-block
 multiplies whose *order is the reuse mechanism*, and moves its operands
 through an **explicit double-buffered DMA pipeline**: A and B live in HBM
-(``pltpu.ANY`` refs) and the kernel issues ``pltpu.make_async_copy`` for
+(``pl.ANY`` refs) and the kernel issues ``pltpu.make_async_copy`` for
 item *i+1*'s tiles into a ``2·unroll``-slot VMEM ring buffer while item *i*
 runs on the MXU, waiting on a copy only at consumption — the SpArch-style
 fetch/merge overlap, scheduled ahead of time instead of reactively:
@@ -62,8 +62,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from .compat import CompilerParams
 
 
 def _make_legacy_kernel(lane_len: int, unroll: int, transpose_lhs: bool,
@@ -252,7 +250,8 @@ def _make_pipeline_kernel(lane_len: int, unroll: int, transpose_lhs: bool,
                 # algebraically exact: (s·Aq) @ B == s · (Aq @ B).  The
                 # step's scales arrive as one VMEM vector (gathered through
                 # slot_idx at call time) — no per-item SMEM scalar loads.
-                contrib = contrib * scale_ref[0, g]
+                # The (1, 1) lane slice broadcasts over the tile.
+                contrib = contrib * scale_ref[0, :, g:g + 1]
             if masked:
                 contrib = jnp.where(valid[i] == 1, contrib, 0.0)
             acc[...] += contrib
@@ -441,15 +440,17 @@ def segment_spmm(a_blocks, slot_idx, m_idx, k_idx, seg_start, seg_write,
     n_steps = lane_len // unroll
     scalars = (slot_idx, m_idx, k_idx, seg_start, seg_write, accum_prev,
                valid, a_fetch, b_fetch, a_slot, b_slot)
-    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY)]
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)]
     operands = [a_blocks, b_dense]
     if quant == "block":
         # one fp32 scale per item, laid out per grid step — the kernel reads
-        # its step's scales as a single VMEM vector
-        scale_items = jnp.take(a_scales, slot_idx).reshape(-1, unroll)
+        # its step's scales as a single VMEM vector.  The unit middle axis
+        # makes the block's last two dims equal the array's, which is what
+        # Mosaic's (8, 128) tiling rule accepts for a (1, unroll) window.
+        scale_items = jnp.take(a_scales, slot_idx).reshape(-1, 1, unroll)
         in_specs.append(pl.BlockSpec(
-            (1, unroll), lambda l, j, s, *rest: (l * n_steps + s, 0)))
+            (1, 1, unroll), lambda l, j, s, *rest: (l * n_steps + s, 0, 0)))
         operands.append(scale_items)
     elif quant == "rowwise":
         # one (bm,) scale row per item — the step's window is (unroll, bm)
@@ -487,7 +488,7 @@ def segment_spmm(a_blocks, slot_idx, m_idx, k_idx, seg_start, seg_write,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-        compiler_params=CompilerParams(dimension_semantics=semantics),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
     )(*scalars, *operands)
 
 
@@ -543,6 +544,6 @@ def _legacy_spmm_call(a_blocks, slot_idx, m_idx, k_idx, seg_start, seg_write,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(*prefetch, *operands)

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 This proves the distribution config is coherent without hardware: for the
@@ -14,9 +11,13 @@ Usage:
   python -m repro.launch.dryrun --arch ... --shape ... --multi-pod
   python -m repro.launch.dryrun --all          # every live cell, subprocesses
 Artifacts: artifacts/dryrun/<arch>__<shape>__<mesh>.json
+
+``main`` fakes 512 host devices through ``XLA_FLAGS`` before JAX starts a
+backend; importing the module changes nothing.
 """
 import argparse
 import json
+import os
 import subprocess
 import sys
 import time
@@ -28,7 +29,8 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import REGISTRY, SHAPES, cell_is_live, get_config
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_production_mesh
 from repro.models import build_model, cache_specs, input_specs
 from repro.optim import AdamW, constant
 from repro.roofline.analysis import (collective_bytes, model_flops,
@@ -135,7 +137,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         metrics_sh = jax.tree.map(
             lambda _: NamedSharding(mesh, P()),
             {"loss": 0, "grad_norm": 0, "lr": 0})
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step, donate_argnums=(0,),
                 in_shardings=((param_sh, opt_sh), batch_sh),
@@ -150,7 +152,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             return logits[:, -1].astype(jnp.float32)   # last-position logits
         batch_sh = make_shardings(mesh, batch_pspecs(mesh, specs))
         dp = dp_axes(mesh)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 prefill, in_shardings=(param_sh, batch_sh),
                 out_shardings=NamedSharding(mesh, P(dp, "model")),
@@ -179,7 +181,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         logits_sh = NamedSharding(mesh, sanitize_pspec(
             mesh, P(dp, "model"),
             (specs["token"].shape[0], cfg.padded_vocab)))
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 decode, donate_argnums=(1,),
                 in_shardings=(param_sh, cache_sh, tok_sh, pos_sh),
@@ -278,6 +280,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None, choices=list(SHAPES))

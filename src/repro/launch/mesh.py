@@ -1,34 +1,20 @@
-"""Production mesh construction + small cross-version jax.sharding shims.
+"""Mesh construction.
 
 Functions (not module-level constants) so importing this module never
 touches jax device state — the dry-run pins the device count via XLA_FLAGS
-*before* any jax initialization.
-
-``jax.sharding.AxisType`` / ``jax.set_mesh`` only exist in newer JAX; on
-older versions every mesh axis is implicitly Auto and the ``Mesh`` object
-itself is the context manager, so the helpers degrade gracefully.
+before any jax initialization.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def make_mesh(shape, axes):
-    """``jax.make_mesh`` with explicit-Auto axis types where supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` on new JAX; the mesh's own resource-env
-    context manager on old JAX."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto`` (the sharding rules place
+    activations through constraints, not explicit-axis types)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False, n_pods: int = 2):

@@ -16,6 +16,7 @@ import json
 
 from repro.configs import REGISTRY, get_config, reduced_config
 from repro.configs.base import ShapeConfig
+from repro.launch.cache import enable_compile_cache
 from repro.models import build_model
 from repro.runtime import Trainer, TrainerConfig
 
@@ -36,12 +37,13 @@ def main() -> None:
     ap.add_argument("--layers", type=int, default=None)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced_config(cfg)
     over = {}
     if args.sparse_ffn:
-        over.update(ffn_block_sparse=True, ffn_block=32, ffn_density=0.5)
+        over.update(ffn_block_sparse=True, ffn_density=0.5)
     if args.d_model:
         over["d_model"] = args.d_model
     if args.layers:

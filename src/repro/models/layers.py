@@ -40,7 +40,7 @@ def dense_apply(p, x):
     return y
 
 
-def sparse_dense_init(key, d_in, d_out, *, block=64, density=0.25,
+def sparse_dense_init(key, d_in, d_out, *, block=128, density=0.25,
                       policy="segment", dtype=jnp.float32):
     """Block-sparse drop-in for :func:`dense_init` via :mod:`repro.api`.
 

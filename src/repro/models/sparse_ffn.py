@@ -44,7 +44,7 @@ class SparseLinear:
     d_in: int
 
     @staticmethod
-    def create(key, d_in, d_out, *, block=64, density=0.25,
+    def create(key, d_in, d_out, *, block=128, density=0.25,
                policy: str = "segment", dtype=jnp.float32):
         if d_in % block or d_out % block:
             raise ValueError(f"d_in={d_in} and d_out={d_out} must be "
@@ -122,7 +122,8 @@ class SparseMLP:
     down: SparseLinear
 
     @staticmethod
-    def create(key, d_model, d_ff, *, block=64, density=0.25, dtype=jnp.float32):
+    def create(key, d_model, d_ff, *, block=128, density=0.25,
+               dtype=jnp.float32):
         k1, k2, k3 = jax.random.split(key, 3)
         up, p_up = SparseLinear.create(k1, d_model, d_ff, block=block,
                                        density=density, dtype=dtype)

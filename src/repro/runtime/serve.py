@@ -146,8 +146,11 @@ class Engine:
         # assert these stay flat across request arrivals/retirements
         self.decode_traces = 0
         self.prefill_traces = 0
-        self._decode = jax.jit(self._decode_fn)
-        self._prefill = jax.jit(self._prefill_fn, static_argnames=("fresh",))
+        # the cache is donated: each step replaces it, so the device never
+        # holds two copies of it
+        self._decode = jax.jit(self._decode_fn, donate_argnums=(1,))
+        self._prefill = jax.jit(self._prefill_fn, static_argnames=("fresh",),
+                                donate_argnums=(1,))
 
     # -- model introspection -------------------------------------------------
 

@@ -109,8 +109,34 @@ class Trainer:
         self.opt = AdamW(lr=cosine_with_warmup(tcfg.peak_lr, tcfg.warmup,
                                                tcfg.steps))
         key = jax.random.PRNGKey(seed)
-        params = model.init(key)
-        opt_state = self.opt.init(params)
+        self._step_fn = make_train_step(model, self.opt, tcfg.accum_steps,
+                                        mesh)
+        state_sh = None
+        if mesh is None:
+            params = model.init(key)
+            opt_state = self.opt.init(params)
+        else:
+            from repro.optim import AdamWState
+            abstract = jax.eval_shape(model.init, key)
+            pspecs = params_pspecs(abstract)
+            p_sh = make_shardings(mesh, pspecs, abstract)
+            opt_abs = jax.eval_shape(self.opt.init, abstract)
+            opt_sh = AdamWState(
+                step=NamedSharding(mesh, P()),
+                m=make_shardings(mesh, pspecs, opt_abs.m),
+                v=make_shardings(mesh, pspecs, opt_abs.v))
+            # each device materializes only its own shard of the params and
+            # of the AdamW state — nothing is built whole on one device
+            params = jax.jit(model.init, out_shardings=p_sh)(key)
+            opt_state = jax.jit(self.opt.init, out_shardings=opt_sh)(params)
+            state_sh = (p_sh, opt_sh)
+            # pin outputs to the same shardings as inputs: the state is
+            # donated and fed straight back in, so compiler-chosen output
+            # shardings would mismatch in_shardings on the second call.
+            self._step_fn = jax.jit(
+                self._step_fn, donate_argnums=(0,),
+                in_shardings=(state_sh, None),
+                out_shardings=(state_sh, None))
         self.state = (params, opt_state)
         self.start_step = 0
         self.ckpt = (CheckpointManager(tcfg.ckpt_dir)
@@ -118,26 +144,9 @@ class Trainer:
         if self.ckpt and tcfg.resume == "auto":
             latest = self.ckpt.latest_step()
             if latest is not None:
-                self.state = self.ckpt.restore(latest, self.state)
+                self.state = self.ckpt.restore(latest, self.state,
+                                               shardings=state_sh)
                 self.start_step = latest
-        self._step_fn = make_train_step(model, self.opt, tcfg.accum_steps,
-                                        mesh)
-        if mesh is not None:
-            from repro.optim import AdamWState
-            params = self.state[0]
-            pspecs = params_pspecs(params)
-            p_sh = make_shardings(mesh, pspecs, params)
-            opt_sh = AdamWState(
-                step=NamedSharding(mesh, P()),
-                m=make_shardings(mesh, pspecs, self.state[1].m),
-                v=make_shardings(mesh, pspecs, self.state[1].v))
-            # pin outputs to the same shardings as inputs: the state is
-            # donated and fed straight back in, so compiler-chosen output
-            # shardings would mismatch in_shardings on the second call.
-            self._step_fn = jax.jit(
-                self._step_fn, donate_argnums=(0,),
-                in_shardings=((p_sh, opt_sh), None),
-                out_shardings=((p_sh, opt_sh), None))
 
     def run(self, failure_hook: Optional[Callable[[int], None]] = None
             ) -> Dict[str, Any]:

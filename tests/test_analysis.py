@@ -426,7 +426,7 @@ def _toy_dma_call(mode, x):
 
     return pl.pallas_call(
         kernel,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((8, 128), lambda: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
         scratch_shapes=[pltpu.VMEM((8, 128), jnp.float32),

@@ -1,0 +1,138 @@
+"""Compile rehearsal: the Segment kernels as ``chip_smoke.py`` runs them,
+compiled by the TPU compiler for a described (not attached) v5e chip.
+
+Interpret mode cannot see Mosaic's tiling and layout rules; this file does,
+at the real widths, with no chip.  The topology is described inside a
+module-scoped fixture (never at import), so only the test worker that runs
+this file loads the TPU compiler.  Plus CPU checks of the compiled
+backend's N-tile alignment and block-shape refusal, and that importing the
+package starts no JAX backend.
+"""
+from __future__ import annotations
+
+import importlib.util
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+import jax
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import api
+from repro.core.formats import BSR
+
+ROOT = Path(__file__).resolve().parents[1]
+
+
+def _chip_smoke():
+    spec = importlib.util.spec_from_file_location("chip_smoke",
+                                                  ROOT / "chip_smoke.py")
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod     # dataclasses resolve their module
+    spec.loader.exec_module(mod)
+    return mod
+
+
+SMOKE = _chip_smoke()
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler in this environment
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of it
+    from jax.experimental.compilation_cache import compilation_cache
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _struct(tree, sharding):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+@pytest.mark.parametrize("case", SMOKE.KERNEL_CASES, ids=lambda c: c.name)
+def test_smoke_kernel_compiles_for_v5e(case, one_chip):
+    a, rhs = SMOKE.kernel_operands(case)
+    plan = SMOKE.kernel_plan(case, a, rhs)
+    assert plan.backend == "pallas"
+    args = [_struct(plan, one_chip)]
+    if case.n:
+        args.append(jax.ShapeDtypeStruct(rhs.shape, case.rhs_dtype,
+                                         sharding=one_chip))
+    compiled = jax.jit(api.execute_plan).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# CPU: what the compiled backend needs from the executor and the planner
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("n,bn", [(8, 512), (512, 512), (600, 512),
+                                  (1024, 512), (100, 128), (4, 256),
+                                  (384, 256), (2000, 1000)])
+def test_pick_bn_on_pallas_is_lane_aligned(n, bn):
+    bn_eff, pad = api.pick_bn(n, bn, align=api.LANE)
+    assert bn_eff % api.LANE == 0 and bn_eff >= api.LANE
+    assert (n + pad) % bn_eff == 0 and 0 <= pad
+    assert bn_eff <= max(bn, api.LANE)
+    assert (n + pad) - n < bn_eff + api.LANE   # never a whole spare tile
+
+
+def test_pick_bn_decode_width_pads_to_one_tile():
+    assert api.pick_bn(8, 512, align=api.LANE) == (128, 120)
+    assert api.pick_bn(512, 512, align=api.LANE) == (512, 0)
+
+
+def test_sub_lane_blocks_refused_on_pallas():
+    rng = np.random.default_rng(0)
+    a = BSR.random(rng, (256, 256), (64, 64), 0.5)
+    with pytest.raises(api.BlockShapeError, match="multiple of 128"):
+        api.plan_matmul(a, (256, 8), backend="pallas")
+    plan = api.plan_matmul(a, (256, 8))          # fine off the chip ...
+    x = np.ones((256, 8), np.float32)
+    with pytest.raises(api.BlockShapeError):     # ... until run compiled
+        api.execute_plan(plan, x, backend="pallas")
+    got = api.execute_plan(plan, x, backend="interpret")
+    np.testing.assert_allclose(got, a.to_dense() @ x, rtol=1e-5, atol=1e-4)
+
+
+def test_importing_the_package_starts_no_backend():
+    code = ("import repro.api, repro.models, repro.runtime, repro.kernels\n"
+            "import repro.launch.serve, repro.launch.train\n"
+            "import jax._src.xla_bridge as xb\n"
+            "assert not xb._backends, list(xb._backends)\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=str(ROOT / "src"))
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr
+
+
+def test_compile_cache_dir(monkeypatch):
+    from repro.launch.cache import DEFAULT_CACHE_DIR, enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(ROOT / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(DEFAULT_CACHE_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

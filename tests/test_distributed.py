@@ -30,14 +30,17 @@ def test_sharded_trainer_matches_single_device():
     from repro.configs.base import ShapeConfig
     from repro.models import build_model
     from repro.runtime import Trainer, TrainerConfig
-    from repro.launch.mesh import make_test_mesh, mesh_context
+    from repro.launch.mesh import make_test_mesh
 
     cfg = reduced_config(REGISTRY["granite-3-8b"])
     shape = ShapeConfig("t", "train", seq_len=32, global_batch=8)
     tc = TrainerConfig(steps=3, log_every=1, accum_steps=2)
     mesh = make_test_mesh(4, 2)
     t_mesh = Trainer(build_model(cfg), cfg, shape, tc, mesh=mesh)
-    with mesh_context(mesh):
+    # the state is born sharded: every leaf lives on the whole mesh
+    for leaf in jax.tree.leaves(t_mesh.state):
+        assert leaf.sharding.mesh.shape == mesh.shape, leaf.sharding
+    with jax.set_mesh(mesh):
         out_mesh = t_mesh.run()
     t_one = Trainer(build_model(cfg), cfg, shape, tc)
     out_one = t_one.run()
@@ -55,7 +58,7 @@ def test_compressed_dp_allreduce():
     from repro.optim import AdamW, constant
     from repro.runtime.compression import init_error_fb, make_compressed_dp_step
 
-    from repro.launch.mesh import make_mesh, mesh_context
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((8,), ("data",))
     w_true = jnp.asarray(np.random.default_rng(0).standard_normal(16),
                          dtype=jnp.float32)
@@ -73,7 +76,7 @@ def test_compressed_dp_allreduce():
     step = make_compressed_dp_step(loss_fn, opt, mesh, method="int8")
     rng = np.random.default_rng(1)
     losses = []
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         for i in range(60):
             x = jnp.asarray(rng.standard_normal((64, 16)), jnp.float32)
             y = x @ w_true
@@ -181,7 +184,7 @@ def test_dryrun_cell_small_mesh():
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import REGISTRY, reduced_config
     from repro.models import build_model
-    from repro.launch.mesh import make_test_mesh, mesh_context
+    from repro.launch.mesh import make_test_mesh
     from repro.sharding import make_shardings, params_pspecs, batch_pspecs
 
     cfg = reduced_config(REGISTRY["phi3.5-moe-42b-a6.6b"])
@@ -196,7 +199,7 @@ def test_dryrun_cell_small_mesh():
     def loss(params, batch):
         return model.loss_fn(params, batch)[0]
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         c = jax.jit(loss, in_shardings=(psh, bsh)).lower(ap, specs).compile()
     assert c.cost_analysis() is not None
     print("MINI_DRYRUN_OK")

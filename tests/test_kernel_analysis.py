@@ -46,7 +46,6 @@ from repro.analysis import (
 )
 from repro.api import execute_plan, plan_matmul
 from repro.core.formats import BSR
-from repro.kernels.compat import CompilerParams
 
 
 def _rules(findings):
@@ -80,7 +79,7 @@ def _cross_lane_scratch(x):
         scratch_shapes=[pltpu.VMEM((8, 128), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((32, 128), jnp.float32),
         interpret=True,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(x)
 
@@ -94,7 +93,7 @@ def _oob_dynamic_slice(x):
 
     return pl.pallas_call(
         kernel, grid=(4,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((8,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((32,), jnp.float32),
         interpret=True,
@@ -130,7 +129,7 @@ def _ring_toy(x, *, read_next_slot):
 
     return pl.pallas_call(
         kernel, grid=(n,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((8,), lambda s: (s,)),
         scratch_shapes=[pltpu.VMEM((2, 8), jnp.float32),
                         pltpu.SemaphoreType.DMA((2,))],
@@ -158,7 +157,7 @@ def _one_branch_wait(x):
 
     return pl.pallas_call(
         kernel, grid=(n,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((8,), lambda s: (s,)),
         scratch_shapes=[pltpu.VMEM((1, 8), jnp.float32),
                         pltpu.SemaphoreType.DMA((1,))],
@@ -256,7 +255,7 @@ def test_data_dependent_guard_is_unprovable_not_silent():
 
         return pl.pallas_call(
             kernel, grid=(2,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec((4,), lambda s: (0,))],
             out_specs=pl.BlockSpec((8,), lambda s: (s,)),
             scratch_shapes=[pltpu.VMEM((1, 8), jnp.float32),

@@ -48,7 +48,6 @@ from repro.api import apply_plan, plan_matmul
 from repro.core.formats import BSR
 from repro.core.schedule import (PREFETCH_MODES, fetch_flags,
                                  lane_traffic_spgemm, lane_traffic_spmm)
-from repro.kernels.compat import CompilerParams
 from repro.tune import Candidate, autotune_matmul
 from repro.tune.cost import DEFAULT_INTERPRET, DEFAULT_TPU, CostModel
 
@@ -132,13 +131,13 @@ def _xpass_toy(x, *, mutate=None):
 
     return pl.pallas_call(
         kernel, grid=(_N_PASS, _N_STEP),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((8,), lambda j, s: (j * _N_STEP + s,)),
         scratch_shapes=[pltpu.VMEM((2, 8), jnp.float32),
                         pltpu.SemaphoreType.DMA((2,))],
         out_shape=jax.ShapeDtypeStruct((_N_PASS * _N_STEP * 8,), jnp.float32),
         interpret=True,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
     )(x)
 
@@ -199,8 +198,8 @@ def _twin_ring_toy(xa, xb, *, swap_pass1_sems=False):
 
     return pl.pallas_call(
         kernel, grid=(_N_PASS, _N_STEP),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((8,), lambda j, s: (j * _N_STEP + s,)),
         scratch_shapes=[pltpu.VMEM((2, 8), jnp.float32),
                         pltpu.VMEM((2, 8), jnp.float32),
@@ -208,7 +207,7 @@ def _twin_ring_toy(xa, xb, *, swap_pass1_sems=False):
                         pltpu.SemaphoreType.DMA((2,))],
         out_shape=jax.ShapeDtypeStruct((_N_PASS * _N_STEP * 8,), jnp.float32),
         interpret=True,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
     )(xa, xb)
 
@@ -246,8 +245,8 @@ def _priority_toy(x_small, x_big, *, small_first):
 
     return pl.pallas_call(
         kernel, grid=(n,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((8, 128), lambda s: (s, 0)),
         scratch_shapes=[pltpu.VMEM((1, 8, 128), jnp.float32),
                         pltpu.VMEM((1, 8, 256), jnp.float32),

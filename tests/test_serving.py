@@ -123,10 +123,13 @@ def test_persistent_cache_reused_across_generations():
     cfg, model, params = _setup()
     eng = Engine(model, params, slots=2, max_len=64, prefill_buckets=(16,))
     shapes0 = jax.tree.map(lambda a: a.shape, eng.cache)
+    first = jax.tree.leaves(eng.cache)
     p1, p2 = _prompts(cfg, (6, 12), seed=3)
     eng.generate([Request(prompt=p1, max_new_tokens=2)])
     eng.generate([Request(prompt=p2, max_new_tokens=2)])
     assert jax.tree.map(lambda a: a.shape, eng.cache) == shapes0
+    # each step donates the cache it replaces: never two copies on device
+    assert all(a.is_deleted() for a in first)
 
 
 def test_slot_reuse_does_not_leak_previous_request():
