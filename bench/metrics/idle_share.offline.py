@@ -1,0 +1,2 @@
+"""``idle_share.offline``: Device idle share of the traced window."""
+from harness.readers import idle_share as read  # noqa: F401
