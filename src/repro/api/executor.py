@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels import ref
 from repro.kernels.segment_spgemm import segment_spgemm
 from repro.kernels.segment_spmm import segment_spmm
@@ -117,10 +118,12 @@ def _run_spmm(plan: SegmentPlan, x: jax.Array, *, backend: str,
         else:
             out = ref.spmm_ref(blocks, plan.a_brow, plan.a_bcol, gm, gk, x,
                                scales=scales)
+        obs.report_spmm(x.shape[1], x.shape[1])
         return out.astype(out_dtype)
     check_block_shape(blocks.shape[1:], backend)
     n = x.shape[1]
     bn_eff, pad = pick_bn(n, bn, align=LANE if backend == "pallas" else 1)
+    obs.report_spmm(n, n + pad)
     xp = jnp.pad(x, ((0, 0), (0, pad))) if pad else x
     out = segment_spmm(
         blocks, plan.slot_idx, plan.m_idx, plan.k_idx, plan.seg_start,
@@ -193,26 +196,36 @@ def execute_plan(plan: SegmentPlan, rhs=None, *, bn: Optional[int] = None,
     debug hook for hand-edited or externally-deserialized plans (planner
     output is better verified once via ``plan_matmul(..., verify=...)``,
     which amortizes through the plan cache).
+
+    The call is the host span ``segfold.execute``; inside it,
+    ``segfold.execute.launch`` covers the kernel call until it returns
+    (dispatch, not completion), and the rest is the host's own resolution.
     """
-    if verify:
-        from repro.analysis.invariants import verify_plan
-        level = "fast" if verify is True else verify
-        verify_plan(plan, level=level).raise_if_findings()
-    backend = resolve_backend(backend if backend is not None else plan.backend)
-    bn = _resolve_bn(plan, bn)
-    if out_dtype is None:
-        out_dtype = plan.out_dtype
-    out_dtype = jnp.float32 if out_dtype is None else jnp.dtype(out_dtype)
-    if plan.kind == SPMM:
-        if rhs is None:
-            raise ValueError("spmm plan needs a dense right-hand side")
-        return _run_spmm(plan, rhs, backend=backend, bn=bn, out_dtype=out_dtype)
-    if plan.kind == SPGEMM:
-        if rhs is not None:
-            raise ValueError("spgemm plan takes no right-hand side "
-                             "(B is frozen into the plan)")
-        return _run_spgemm(plan, backend=backend, out_dtype=out_dtype)
-    raise ValueError(f"unknown plan kind {plan.kind!r}")
+    with obs.span("execute"):
+        if verify:
+            from repro.analysis.invariants import verify_plan
+            level = "fast" if verify is True else verify
+            verify_plan(plan, level=level).raise_if_findings()
+        backend = resolve_backend(backend if backend is not None
+                                  else plan.backend)
+        bn = _resolve_bn(plan, bn)
+        if out_dtype is None:
+            out_dtype = plan.out_dtype
+        out_dtype = jnp.float32 if out_dtype is None else jnp.dtype(out_dtype)
+        if plan.kind == SPMM:
+            if rhs is None:
+                raise ValueError("spmm plan needs a dense right-hand side")
+            with obs.span("execute.launch"):
+                return _run_spmm(plan, rhs, backend=backend, bn=bn,
+                                 out_dtype=out_dtype)
+        if plan.kind == SPGEMM:
+            if rhs is not None:
+                raise ValueError("spgemm plan takes no right-hand side "
+                                 "(B is frozen into the plan)")
+            with obs.span("execute.launch"):
+                return _run_spgemm(plan, backend=backend,
+                                   out_dtype=out_dtype)
+        raise ValueError(f"unknown plan kind {plan.kind!r}")
 
 
 # ---------------------------------------------------------------------------

@@ -42,7 +42,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .segment_spmm import resolve_pipeline, validate_schedule_args
+from .segment_spmm import (kernel_name, resolve_pipeline,
+                           validate_schedule_args)
 
 
 def _make_legacy_kernel(lane_len: int, unroll: int, masked: bool,
@@ -369,6 +370,8 @@ def segment_spgemm(a_blocks, b_blocks, a_idx, b_idx, c_idx, seg_start,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name=kernel_name("segment_spgemm", pipeline=True,
+                         quant_a=quant_a, quant_b=quant_b),
     )(*scalars, *operands)
 
 
@@ -425,4 +428,6 @@ def _legacy_spgemm_call(a_blocks, b_blocks, a_idx, b_idx, c_idx, seg_start,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name=kernel_name("segment_spgemm", pipeline=False,
+                         quant_a=quant_a, quant_b=quant_b),
     )(*prefetch, *operands)

@@ -289,6 +289,26 @@ def _make_pipeline_kernel(lane_len: int, unroll: int, transpose_lhs: bool,
     return _kernel
 
 
+def kernel_name(op: str, *, pipeline: bool, transpose_lhs: bool = False,
+                quant_a: str | None = None, quant_b: str | None = None,
+                prefetch: str | None = None) -> str:
+    """The ``pallas_call`` name of one kernel variant: the op, then
+    ``pipeline``/``legacy``, ``tlhs`` for ``transpose_lhs``, ``qa<mode>`` /
+    ``qb<mode>`` for a quantized A / B operand and ``xpass`` for cross-pass
+    prefetch (``segment_spmm_pipeline_qablock``).  It names the kernel's
+    custom call in the compiled program, and so its profiler events."""
+    parts = [op, "pipeline" if pipeline else "legacy"]
+    if transpose_lhs:
+        parts.append("tlhs")
+    if quant_a is not None:
+        parts.append("qa" + quant_a)
+    if quant_b is not None:
+        parts.append("qb" + quant_b)
+    if prefetch == "cross_pass":
+        parts.append("xpass")
+    return "_".join(parts)
+
+
 def validate_schedule_args(n_items, n_lanes, unroll, arrays):
     """Shared scalar-prefetch schedule validation for both Segment kernels."""
     for name, arr in arrays.items():
@@ -489,6 +509,9 @@ def segment_spmm(a_blocks, slot_idx, m_idx, k_idx, seg_start, seg_write,
         out_shape=out_shape,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
+        name=kernel_name("segment_spmm", pipeline=True,
+                         transpose_lhs=transpose_lhs, quant_a=quant,
+                         prefetch=prefetch),
     )(*scalars, *operands)
 
 
@@ -546,4 +569,6 @@ def _legacy_spmm_call(a_blocks, slot_idx, m_idx, k_idx, seg_start, seg_write,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name=kernel_name("segment_spmm", pipeline=False,
+                         transpose_lhs=transpose_lhs, quant_a=quant),
     )(*prefetch, *operands)

@@ -434,7 +434,8 @@ def embedding_apply(p, tokens):
 
 def lm_head_apply(p, x):
     """Tied or untied head: x (B,T,D) @ table^T → (B,T,V)."""
-    return jnp.einsum("btd,vd->btv", x, p["table"].astype(x.dtype))
+    with jax.named_scope("lm_head"):
+        return jnp.einsum("btd,vd->btv", x, p["table"].astype(x.dtype))
 
 
 def cross_entropy(logits, targets, mask=None):

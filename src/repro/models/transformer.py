@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.core.formats import QUANT_DTYPES, quantize_blocks
 from repro.sharding import act_constrain
@@ -128,43 +129,52 @@ def _block_apply(cfg: ModelConfig, p, x, kind: str, *, positions,
         x = act_constrain(x, "seq")
     aux = jnp.zeros((), jnp.float32)
     new_cache: Dict[str, Any] = {}
+    # the named scopes ("attn", "ffn", "lm_head" in layers.py) are op
+    # metadata only: the profiler's op-profile view groups device ops by them
     if kind in ("attn", "attn_bidir", "local", "cross"):
         window = cfg.local_window if kind == "local" else None
-        h, kv = layers.attention_apply(
-            p["attn"], layers.rmsnorm_apply(p["norm1"], x, cfg.norm_eps),
-            n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.hd,
-            positions=positions, causal=(kind != "attn_bidir"), window=window,
-            rope_theta=cfg.rope_theta,
-            cache=cache.get("kv") if cache else None, cache_pos=cache_pos,
-            chunk=cfg.attn_chunk, ring=(kind == "local" and cache is not None))
+        with jax.named_scope("attn"):
+            h, kv = layers.attention_apply(
+                p["attn"], layers.rmsnorm_apply(p["norm1"], x, cfg.norm_eps),
+                n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.hd,
+                positions=positions, causal=(kind != "attn_bidir"),
+                window=window, rope_theta=cfg.rope_theta,
+                cache=cache.get("kv") if cache else None, cache_pos=cache_pos,
+                chunk=cfg.attn_chunk,
+                ring=(kind == "local" and cache is not None))
         x = x + h
         if kv is not None:
             new_cache["kv"] = kv
         if kind == "cross":
-            hx, xkv = layers.attention_apply(
-                p["xattn"], layers.rmsnorm_apply(p["norm_x"], x, cfg.norm_eps),
-                n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.hd,
-                positions=positions, causal=False, rope_theta=0.0,
-                kv_ctx=enc_out, chunk=cfg.attn_chunk)
+            with jax.named_scope("attn"):
+                hx, xkv = layers.attention_apply(
+                    p["xattn"],
+                    layers.rmsnorm_apply(p["norm_x"], x, cfg.norm_eps),
+                    n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.hd,
+                    positions=positions, causal=False, rope_theta=0.0,
+                    kv_ctx=enc_out, chunk=cfg.attn_chunk)
             x = x + hx
         n2 = layers.rmsnorm_apply(p["norm2"], x, cfg.norm_eps)
-        if sparse_mlp is not None:
-            x = x + sparse_mlp.apply(p["mlp"], n2)
-        else:
-            x = x + layers.swiglu_apply(p["mlp"], n2)
+        with jax.named_scope("ffn"):
+            if sparse_mlp is not None:
+                x = x + sparse_mlp.apply(p["mlp"], n2)
+            else:
+                x = x + layers.swiglu_apply(p["mlp"], n2)
     elif kind == "moe":
-        h, kv = layers.attention_apply(
-            p["attn"], layers.rmsnorm_apply(p["norm1"], x, cfg.norm_eps),
-            n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.hd,
-            positions=positions, causal=True, rope_theta=cfg.rope_theta,
-            cache=cache.get("kv") if cache else None, cache_pos=cache_pos,
-            chunk=cfg.attn_chunk)
+        with jax.named_scope("attn"):
+            h, kv = layers.attention_apply(
+                p["attn"], layers.rmsnorm_apply(p["norm1"], x, cfg.norm_eps),
+                n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.hd,
+                positions=positions, causal=True, rope_theta=cfg.rope_theta,
+                cache=cache.get("kv") if cache else None, cache_pos=cache_pos,
+                chunk=cfg.attn_chunk)
         x = x + h
         if kv is not None:
             new_cache["kv"] = kv
-        h, aux = moe.moe_apply(
-            p["moe"], layers.rmsnorm_apply(p["norm2"], x, cfg.norm_eps),
-            top_k=cfg.top_k, capacity_factor=cfg.moe_capacity_factor)
+        with jax.named_scope("ffn"):
+            h, aux = moe.moe_apply(
+                p["moe"], layers.rmsnorm_apply(p["norm2"], x, cfg.norm_eps),
+                top_k=cfg.top_k, capacity_factor=cfg.moe_capacity_factor)
         x = x + h
     elif kind == "rec":
         h, st = recurrent.rglru_block_apply(
@@ -172,8 +182,9 @@ def _block_apply(cfg: ModelConfig, p, x, kind: str, *, positions,
             state=cache.get("rec") if cache else None)
         x = x + h
         new_cache["rec"] = st
-        x = x + layers.swiglu_apply(
-            p["mlp"], layers.rmsnorm_apply(p["norm2"], x, cfg.norm_eps))
+        with jax.named_scope("ffn"):
+            x = x + layers.swiglu_apply(
+                p["mlp"], layers.rmsnorm_apply(p["norm2"], x, cfg.norm_eps))
     elif kind == "rwkv":
         st = cache.get("rwkv") if cache else recurrent.rwkv_block_state(
             x.shape[0], cfg.d_model, cfg.n_heads or 32, x.dtype)
@@ -350,8 +361,10 @@ class Transformer:
         # carries full f32 cache copies in the while tuple. This is a
         # CPU-only artifact (TPU bf16 dots are native); the dry-run measures
         # and subtracts it — see launch/dryrun.py `cpu_artifact_bytes`.
-        (x, aux), new_caches = jax.lax.scan(
-            body_fn, (x, jnp.zeros((), jnp.float32)), xs)
+        # the body is traced once and runs once per layer
+        with obs.repeated(jax.tree.leaves(params_g)[0].shape[0]):
+            (x, aux), new_caches = jax.lax.scan(
+                body_fn, (x, jnp.zeros((), jnp.float32)), xs)
         return x, aux, (new_caches if collect_cache else None)
 
     # -- forward (train / prefill logits) -------------------------------------

@@ -43,6 +43,14 @@ engine constructed with ``backend="interpret"`` (CPU correctness runs) or
 ``backend="pallas"`` (TPU) traces its jitted step functions under that
 backend, so Segment-plan layers in the model bake the right execution
 mode in.
+
+Observability: the two jitted programs are named ``engine_decode`` and
+``engine_prefill`` (``jit_engine_decode``/``jit_engine_prefill`` in a
+profiler trace); the host work is in ``segfold.engine.*`` spans
+(:mod:`repro.obs`): ``admit`` per admitted request holding a ``prefill``
+per chunk and the ``first_token`` sync, and ``step`` per decode step
+holding ``prepare``, ``dispatch``, ``sync`` and ``update``.
+:meth:`Engine.counters` counts the work.
 """
 from __future__ import annotations
 
@@ -54,6 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.api.backends import resolve_backend, use_backend
 
 
@@ -74,6 +83,11 @@ class _Slot:
     pos: int                           # tokens in cache == next write index
     last_tok: int                      # token to feed at the next step
     out: List[int] = dataclasses.field(default_factory=list)
+
+
+#: the names :meth:`Engine.counters` returns
+COUNTERS = ("decode_steps", "decode_rows", "prefill_chunks", "prefill_tokens",
+            "prefill_padded_tokens", "spmm_cols_useful", "spmm_cols_computed")
 
 
 def _round_up(x: int, m: int) -> int:
@@ -146,10 +160,21 @@ class Engine:
         # assert these stay flat across request arrivals/retirements
         self.decode_traces = 0
         self.prefill_traces = 0
+        self._counts = dict.fromkeys(COUNTERS, 0)
+        # per compiled program: the Segment SpMM calls its trace reported
+        self._spmm_calls: Dict[tuple, List[obs.SpmmCall]] = {}
+
         # the cache is donated: each step replaces it, so the device never
         # holds two copies of it
-        self._decode = jax.jit(self._decode_fn, donate_argnums=(1,))
-        self._prefill = jax.jit(self._prefill_fn, static_argnames=("fresh",),
+        def engine_decode(params, cache, tok, pos):
+            return self._decode_fn(params, cache, tok, pos)
+
+        def engine_prefill(params, cache, slot, tok, pos, last_idx, fresh):
+            return self._prefill_fn(params, cache, slot, tok, pos, last_idx,
+                                    fresh)
+
+        self._decode = jax.jit(engine_decode, donate_argnums=(1,))
+        self._prefill = jax.jit(engine_prefill, static_argnames=("fresh",),
                                 donate_argnums=(1,))
 
     # -- model introspection -------------------------------------------------
@@ -170,8 +195,9 @@ class Engine:
         """tok (S, 1), pos (S,) — one batched decode step at per-slot
         positions; returns (greedy next token (S,), new cache)."""
         self.decode_traces += 1
-        with use_backend(self.backend):
+        with use_backend(self.backend), obs.spmm_columns() as calls:
             logits, cache = self.model.decode_step(params, cache, tok, pos)
+        self._spmm_calls[("decode",)] = calls
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
 
     def _prefill_fn(self, params, cache, slot, tok, pos, last_idx, fresh):
@@ -187,9 +213,10 @@ class Engine:
             lambda a: jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=1), cache)
         if fresh:
             row = jax.tree.map(jnp.zeros_like, row)
-        with use_backend(self.backend):
+        with use_backend(self.backend), obs.spmm_columns() as calls:
             logits, row = self.model.decode_step(params, row, tok, pos,
                                                  logit_idx=last_idx)
+        self._spmm_calls[("prefill", tok.shape[1], fresh)] = calls
         cache = jax.tree.map(
             lambda full, r: jax.lax.dynamic_update_slice_in_dim(
                 full, r, slot, axis=1),
@@ -233,22 +260,37 @@ class Engine:
             done += c
         return chunks
 
+    def _count_spmm(self, program: tuple, useful: int) -> None:
+        """Add one run of ``program`` with ``useful`` real rows or tokens
+        to the SpMM column counters."""
+        for n, computed, runs in self._spmm_calls.get(program, ()):
+            self._counts["spmm_cols_useful"] += runs * min(useful, n)
+            self._counts["spmm_cols_computed"] += runs * computed
+
     def _admit(self, s: int, req: Request) -> None:
         prompt = req.prompt
         length = int(prompt.shape[0])
         done = 0
         tok_dev = None
-        for i, c in enumerate(self._chunk_schedule(length)):
-            n = min(c, length - done)
-            buf = np.zeros((1, c), np.int32)
-            buf[0, :n] = prompt[done:done + n]
-            tok_dev, self.cache = self._prefill(
-                self.params, self.cache, jnp.int32(s), jnp.asarray(buf),
-                jnp.int32(done), jnp.asarray([n - 1], jnp.int32),
-                fresh=(i == 0))
-            done += n
-        # only the final chunk's token matters — one host sync per admission
-        tok = int(np.asarray(tok_dev)[0])
+        with obs.span("engine.admit", rid=req.rid):
+            for i, c in enumerate(self._chunk_schedule(length)):
+                with obs.span("engine.prefill"):
+                    n = min(c, length - done)
+                    buf = np.zeros((1, c), np.int32)
+                    buf[0, :n] = prompt[done:done + n]
+                    tok_dev, self.cache = self._prefill(
+                        self.params, self.cache, jnp.int32(s),
+                        jnp.asarray(buf), jnp.int32(done),
+                        jnp.asarray([n - 1], jnp.int32), fresh=(i == 0))
+                self._counts["prefill_chunks"] += 1
+                self._counts["prefill_tokens"] += n
+                self._counts["prefill_padded_tokens"] += c
+                self._count_spmm(("prefill", c, i == 0), n)
+                done += n
+            # only the final chunk's token matters — one host sync per
+            # admission
+            with obs.span("engine.first_token"):
+                tok = int(np.asarray(tok_dev)[0])
         slot = _Slot(request=req, pos=length, last_tok=tok, out=[tok])
         self._slots[s] = slot
         if self._finished(slot):
@@ -284,21 +326,30 @@ class Engine:
         live = [s for s in range(self.slots) if self._slots[s] is not None]
         if not live:
             return 0
-        tok = np.zeros((self.slots, 1), np.int32)
-        pos = np.zeros((self.slots,), np.int32)
-        for s in live:
-            tok[s, 0] = self._slots[s].last_tok
-            pos[s] = self._slots[s].pos
-        nxt, self.cache = self._decode(self.params, self.cache,
-                                       jnp.asarray(tok), jnp.asarray(pos))
-        nxt = np.asarray(nxt)
-        for s in live:
-            slot = self._slots[s]
-            slot.pos += 1                       # last_tok now sits in cache
-            slot.last_tok = int(nxt[s])
-            slot.out.append(slot.last_tok)
-            if self._finished(slot):
-                self._retire(s)
+        with obs.span("engine.step"):
+            with obs.span("engine.prepare"):
+                tok = np.zeros((self.slots, 1), np.int32)
+                pos = np.zeros((self.slots,), np.int32)
+                for s in live:
+                    tok[s, 0] = self._slots[s].last_tok
+                    pos[s] = self._slots[s].pos
+                tok, pos = jnp.asarray(tok), jnp.asarray(pos)
+            with obs.span("engine.dispatch"):
+                nxt, self.cache = self._decode(self.params, self.cache, tok,
+                                               pos)
+            with obs.span("engine.sync"):
+                nxt = np.asarray(nxt)
+            with obs.span("engine.update"):
+                self._counts["decode_steps"] += 1
+                self._counts["decode_rows"] += len(live)
+                self._count_spmm(("decode",), len(live))
+                for s in live:
+                    slot = self._slots[s]
+                    slot.pos += 1               # last_tok now sits in cache
+                    slot.last_tok = int(nxt[s])
+                    slot.out.append(slot.last_tok)
+                    if self._finished(slot):
+                        self._retire(s)
         return len(live)
 
     def run(self) -> None:
@@ -319,6 +370,16 @@ class Engine:
     def compiled_shapes(self) -> Dict[str, int]:
         """Trace counts per step function — flat after warmup."""
         return {"decode": self.decode_traces, "prefill": self.prefill_traces}
+
+    def counters(self) -> Dict[str, int]:
+        """The work done since construction (:data:`COUNTERS`): decode
+        steps and the live rows they carried; prefill chunks, their real
+        prompt tokens and their bucket sizes; and the columns the Segment
+        SpMM kernels computed against those that held a real row or token,
+        summed over every SpMM call of every program run (N-tile padding,
+        free slots and a chunk's padding are the difference).  Read it
+        before and after a window and subtract."""
+        return dict(self._counts)
 
 
 class Server(Engine):

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import importlib.util
 import os
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -74,7 +75,24 @@ def test_smoke_kernel_compiles_for_v5e(case, one_chip):
         args.append(jax.ShapeDtypeStruct(rhs.shape, case.rhs_dtype,
                                          sharding=one_chip))
     compiled = jax.jit(api.execute_plan).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's custom call, and so its profiler events, carry the
+    # variant's own name
+    assert set(re.findall(r"%(segment_\w+?)(?:\.\d+)? = [^\n]*custom-call",
+                          text)) == {_kernel_name(case)}
+
+
+def _kernel_name(case) -> str:
+    """The ``pallas_call`` name the case's variant is given."""
+    name = "segment_spmm_pipeline" if case.n else "segment_spgemm_pipeline"
+    if case.quantize is not None:
+        mode = "rowwise" if case.quantize.endswith(".rowwise") else "block"
+        # an SpGEMM plan quantizes both of its operands
+        name += "_qa" + mode + ("" if case.n else "_qb" + mode)
+    if case.prefetch == "cross_pass":
+        name += "_xpass"
+    return name
 
 
 # ---------------------------------------------------------------------------
