@@ -161,9 +161,9 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     else:  # decode
         from repro.sharding import sanitize_pspec
         cache_abs = cache_specs(cfg, shape)
-        cache_sh = jax.tree.map(
-            lambda leaf: NamedSharding(
-                mesh, sanitize_pspec(mesh, cache_pspec(mesh, leaf), leaf.shape)),
+        cache_sh = jax.tree_util.tree_map_with_path(
+            lambda path, leaf: NamedSharding(mesh, sanitize_pspec(
+                mesh, cache_pspec(mesh, path, leaf), leaf.shape)),
             cache_abs)
         dp = dp_axes(mesh)
 
@@ -206,8 +206,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             if ax in mesh.axis_names:
                 dp_size *= mesh.shape[ax]
         tp = mesh.shape["model"]
-        for leaf in jax.tree.leaves(cache_abs):
-            if leaf.ndim >= 5 and leaf.dtype == jnp.bfloat16:
+        for path, leaf in jax.tree_util.tree_leaves_with_path(cache_abs):
+            if path[-1].key in ("k", "v") and leaf.dtype == jnp.bfloat16:
                 d = list(leaf.shape)
                 if d[1] % dp_size == 0:
                     d[1] //= dp_size

@@ -202,61 +202,53 @@ def _decode_mask(b, tq, tk, *, q_offset, kv_len, causal, window):
     return mask
 
 
-def _direct_attention(q, k, v, *, q_offset, kv_len, causal, window):
-    """Unchunked masked attention (decode path, Tq ≤ 8).
+def _direct_attention(q, k, v, *, q_offset, kv_len, causal, window,
+                      k_scale=None, v_scale=None):
+    """Unchunked masked attention over one layer's cache as stored (decode
+    path, Tq ≤ 8).
 
-    Keeps K/V in their cache dtype and accumulates in f32 via
-    ``preferred_element_type`` — an explicit .astype(f32) on the per-layer
-    cache slice gets hoisted out of the layer scan by XLA and materializes
-    the *entire* stacked cache in f32."""
-    b, tq, h, d = q.shape
-    tk, hkv = k.shape[1], k.shape[2]
-    rep = h // hkv
-    if rep > 1:
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
+    q: (B, t, H, D); k/v: (B, T, Hkv·D) in the cache dtype — bf16, or int8
+    with per-position, per-head f32 scales ``k_scale``/``v_scale``
+    (B, T, Hkv) that factor out of both dots (column-wise for QKᵀ, folded
+    into p for PV), so no dequantized copy is made.
+
+    Both dots read k and v in their stored layout, with f32 accumulation
+    via ``preferred_element_type``: QKᵀ contracts the merged minor axis
+    against a block-diagonal query (each head's D values in its kv group's
+    block, zeros elsewhere), and PV forms every head against the whole
+    minor axis and keeps the head's own block.  The zero blocks add exact
+    zeros, so the sums are the per-head dots; the cache slice is neither
+    relaid out per head nor copied to f32 (an explicit .astype(f32) on it
+    gets hoisted out of the layer scan by XLA and materializes the *entire*
+    stacked cache in f32)."""
     from repro.sharding import act_constrain
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(k.dtype), k,
+    b, tq, h, d = q.shape
+    tk, f = k.shape[1], k.shape[2]
+    hkv = f // d
+    rep = h // hkv
+    dt = jnp.bfloat16 if k.dtype == jnp.int8 else k.dtype
+    # own[G, g]: block G of the minor axis belongs to kv group g
+    own = jnp.eye(hkv, dtype=bool)[:, None, :, None]
+    # qbd[b, q, G·D + e, g·rep + r] = q[b, q, g·rep + r, e] where G == g
+    qx = jnp.moveaxis(q.astype(dt).reshape(b, tq, hkv, rep, d), 4, 2)
+    qbd = jnp.where(own, qx[:, :, None], 0).reshape(b, tq, f, h)
+    s = jnp.einsum("bkf,bqfh->bhqk", k.astype(dt), qbd,
                    preferred_element_type=jnp.float32) / np.sqrt(d)
+    if k_scale is not None:
+        s = s * jnp.repeat(k_scale, rep, axis=2).transpose(0, 2, 1)[:, :, None]
     s = act_constrain(s, "scores_t")   # keep KV timeline sequence-sharded
     mask = _decode_mask(b, tq, tk, q_offset=q_offset, kv_len=kv_len,
                         causal=causal, window=window)
     s = jnp.where(mask[:, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
-                     preferred_element_type=jnp.float32)
-    return out.astype(q.dtype)
-
-
-def _direct_attention_q8(q, kq, ks, vq, vs, *, q_offset, kv_len, causal,
-                         window):
-    """Decode attention over an int8 KV cache with factored scales.
-
-    q: (B,t,H,D); kq/vq: (B,T,Hkv,D) int8; ks/vs: (B,T,Hkv) f32.
-    s = (q·kqᵀ) ⊙ ks  and  out = (p ⊙ vs)·vq — the int8 tensors feed the
-    dots directly (native int8×bf16 on TPU), no dequantized copy."""
-    from repro.sharding import act_constrain
-    b, tq, h, d = q.shape
-    tk, hkv = kq.shape[1], kq.shape[2]
-    rep = h // hkv
-    if rep > 1:
-        kq = jnp.repeat(kq, rep, axis=2)
-        vq = jnp.repeat(vq, rep, axis=2)
-        ks = jnp.repeat(ks, rep, axis=2)
-        vs = jnp.repeat(vs, rep, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.bfloat16),
-                   kq.astype(jnp.bfloat16),
-                   preferred_element_type=jnp.float32) / np.sqrt(d)
-    s = s * ks.transpose(0, 2, 1)[:, :, None, :]        # column-wise dequant
-    s = act_constrain(s, "scores_t")
-    mask = _decode_mask(b, tq, tk, q_offset=q_offset, kv_len=kv_len,
-                        causal=causal, window=window)
-    s = jnp.where(mask[:, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    p = p * vs.transpose(0, 2, 1)[:, :, None, :]         # fold v scales into p
-    out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(jnp.bfloat16),
-                     vq.astype(jnp.bfloat16),
-                     preferred_element_type=jnp.float32)
+    if v_scale is not None:
+        p = p * jnp.repeat(v_scale, rep, axis=2).transpose(0, 2, 1)[:, :, None]
+    y = jnp.einsum("bhqk,bkf->bhqf", p.astype(dt), v.astype(dt),
+                   preferred_element_type=jnp.float32)
+    # y[b, g·rep + r, q, G·D + e] → the head's own block, G == g
+    y = y.reshape(b, hkv, rep, tq, hkv, d)
+    out = jnp.where(own[:, :, None], y, 0).sum(axis=4)
+    out = out.reshape(b, h, tq, d).transpose(0, 2, 1, 3)
     return out.astype(q.dtype)
 
 
@@ -287,31 +279,55 @@ def ring_decode_attention(q, ck, cv, k_pos, pos, window):
     return out.astype(q.dtype)
 
 
-def kv_cache_write(buf, new, pos):
-    """Write ``new`` (B, t, …) into ``buf`` (B, T, …) at time-axis offset
-    ``pos`` — a shared scalar (lockstep decode: one contiguous block write)
-    or a per-row ``(B,)`` vector (continuous batching: every slot writes at
-    its own position; vmapped dynamic-update, one row-local write each)."""
-    if getattr(pos, "ndim", 0):
-        return jax.vmap(
-            lambda c, n, p: jax.lax.dynamic_update_slice_in_dim(c, n, p, 0)
-        )(buf, new, pos)
-    return jax.lax.dynamic_update_slice_in_dim(buf, new, pos, axis=1)
+def kv_cache_write(buf, new, layer, pos):
+    """Write ``new`` (B, t, F) into layer ``layer`` of the layer-stacked
+    cache ``buf`` (L, B, T, F) at time index ``pos``; returns the updated
+    stack.  Only the B·t new token rows move: inside the layer scan the
+    stack is the scan's carry, so the donated cache is updated in place.
+
+    ``pos`` is a shared scalar (lockstep decode or a prefill chunk: one
+    contiguous ``dynamic_update_slice``), a per-row ``(B,)`` offset
+    (continuous batching: every slot writes at its own position) or a
+    ``(B, t)`` index per token (a ring buffer's slots); the last two are
+    one scatter of the B·t rows."""
+    new = new.astype(buf.dtype)
+    pos = jnp.asarray(pos, jnp.int32)
+    if pos.ndim == 0:
+        zero = jnp.zeros((), jnp.int32)
+        return jax.lax.dynamic_update_slice(buf, new[None],
+                                            (layer, zero, pos, zero))
+    b, t = new.shape[:2]
+    if pos.ndim == 1:
+        pos = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+    return buf.at[layer, jnp.arange(b)[:, None], pos].set(new)
+
+
+def _quantize_kv(x):
+    """Per-position, per-head symmetric int8: (…, D) → int8 (…, D) and f32
+    scales (…)."""
+    xf = x.astype(jnp.float32)
+    scale = jnp.maximum(jnp.max(jnp.abs(xf), axis=-1) / 127.0, 1e-8)
+    q = jnp.clip(jnp.round(xf / scale[..., None]), -127, 127)
+    return q.astype(jnp.int8), scale
 
 
 def attention_apply(p, x, *, n_heads, n_kv, head_dim, positions,
                     causal=True, window=None, rope_theta=10000.0,
-                    kv_ctx=None, cache=None, cache_pos=None, chunk=1024,
-                    ring=False):
+                    kv_ctx=None, cache=None, cache_layer=None, cache_pos=None,
+                    chunk=1024, ring=False):
     """Self-attention (or cross-attention when ``kv_ctx`` is given).
 
-    ``cache``: optional dict(k, v) of (B, T_max, n_kv, hd) — decode mode:
-    writes current kv at ``cache_pos`` and attends over the whole cache.
+    ``cache``: optional layer-stacked decode cache, dict(k, v) of
+    (L, B, T_max, n_kv·hd) (int8: plus scales ``k_s``/``v_s`` of
+    (L, B, T_max, n_kv)) — decode mode: writes the current kv into layer
+    ``cache_layer`` at ``cache_pos``, then attends over that layer's whole
+    cache.  The token write and the attention read use this one layout.
     ``cache_pos`` is a shared scalar or a per-row ``(B,)`` vector — the
     latter is the continuous-batching path where every slot sits at its own
     absolute position.  With ``ring=True`` the cache is a window-sized ring
     buffer (local attention decode: O(window) memory at any context
-    length).  Returns (out, new_cache).
+    length).  Returns (out, new_cache), the new cache being the updated
+    stack.
     """
     from repro.sharding import act_constrain
     b, t, _ = x.shape
@@ -327,73 +343,65 @@ def attention_apply(p, x, *, n_heads, n_kv, head_dim, positions,
     if kv_ctx is None and rope_theta:
         q = rope(q, positions, rope_theta)
         k = rope(k, positions, rope_theta)
-    new_cache = None
-    if cache is not None and ring:
-        w = cache["k"].shape[1]
-        pos_v = jnp.broadcast_to(jnp.asarray(cache_pos, jnp.int32), (b,))
-        # scatter each token into its ring slot (handles per-row positions
-        # and writes that wrap around the ring, which a block
-        # dynamic_update_slice would clamp at the edge)
-        slot_idx = jnp.mod(pos_v[:, None] + jnp.arange(t)[None, :], w)
-        rows = jnp.arange(b)[:, None]
-        ck = cache["k"].at[rows, slot_idx].set(k.astype(cache["k"].dtype))
-        cv = cache["v"].at[rows, slot_idx].set(v.astype(cache["v"].dtype))
-        new_cache = {"k": ck, "v": cv}
-        last = pos_v + (t - 1)
-        idx = jnp.arange(w)
-        k_pos = last[:, None] - jnp.mod(last[:, None] - idx[None, :], w)
-        out = ring_decode_attention(q, ck, cv, k_pos, pos_v, window or w)
-    elif cache is not None and "k_s" in cache:
-        # int8-quantized KV cache (beyond-paper, see EXPERIMENTS §Perf):
-        # per-position, per-head symmetric scales. Halves the decode
-        # memory-bound roofline term (the KV read is the floor). Scales
-        # factor OUT of both attention einsums — column-wise for QK^T,
-        # folded into p for PV — so no dequantized cache copy is ever
-        # materialized.
-        def quant(x_):
-            scale = jnp.max(jnp.abs(x_.astype(jnp.float32)), axis=-1) / 127.0
-            scale = jnp.maximum(scale, 1e-8)
-            q_ = jnp.clip(jnp.round(x_.astype(jnp.float32) / scale[..., None]),
-                          -127, 127).astype(jnp.int8)
-            return q_, scale
-        kq, ks_new = quant(k)
-        vq, vs_new = quant(v)
-        ck = kv_cache_write(cache["k"], kq, cache_pos)
-        cv = kv_cache_write(cache["v"], vq, cache_pos)
-        cks = kv_cache_write(cache["k_s"], ks_new, cache_pos)
-        cvs = kv_cache_write(cache["v_s"], vs_new, cache_pos)
-        new_cache = {"k": ck, "v": cv, "k_s": cks, "v_s": cvs}
-        if t > 8:
-            # a guard, not an assert: serving stacks routinely run under
-            # ``python -O``, which strips asserts — and a silently oversized
-            # query here would attend with garbage positions, not crash
-            raise ValueError(
-                f"int8 KV cache path supports decode-sized queries (t <= 8), "
-                f"got t={t}; chunk the prefill (Engine does this via "
-                f"prefill_buckets) or use the fp32 cache for long queries")
-        out = _direct_attention_q8(q, ck, cks, cv, cvs,
-                                   q_offset=cache_pos, kv_len=cache_pos + t,
-                                   causal=causal, window=window)
-    elif cache is not None:
-        # decode: insert at cache_pos (per-row or shared), attend over the
-        # full cache masked to each row's own valid length
-        ck = kv_cache_write(cache["k"], k.astype(cache["k"].dtype), cache_pos)
-        cv = kv_cache_write(cache["v"], v.astype(cache["v"].dtype), cache_pos)
-        new_cache = {"k": ck, "v": cv}
-        if t <= 8:
-            # single-token decode: direct masked attention — scores are
-            # (B, H, t, T): tiny, and the T axis keeps its sequence-parallel
-            # sharding (the chunked scan's reshape would force a reshard)
-            out = _direct_attention(q, ck, cv, q_offset=cache_pos,
-                                    kv_len=cache_pos + t, causal=causal,
-                                    window=window)
-        else:
-            out = chunked_attention(q, ck, cv, causal=causal, window=window,
-                                    q_offset=cache_pos, kv_len=cache_pos + t,
-                                    chunk=chunk)
-    else:
+    if cache is None:
         out = chunked_attention(q, k, v, causal=causal and kv_ctx is None,
                                 window=window, q_offset=0, chunk=chunk)
+        out = out.reshape(b, t, n_heads * head_dim)
+        return dense_apply(p["wo"], out), None
+
+    t_max = cache["k"].shape[2]
+    pos_v = jnp.broadcast_to(jnp.asarray(cache_pos, jnp.int32), (b,))
+    new = {"k": k, "v": v}
+    if "k_s" in cache:
+        # int8-quantized KV cache (beyond-paper, see EXPERIMENTS §Perf):
+        # per-position, per-head symmetric scales. Halves the decode
+        # memory-bound roofline term (the KV read is the floor).
+        (new["k"], new["k_s"]), (new["v"], new["v_s"]) = (_quantize_kv(k),
+                                                          _quantize_kv(v))
+    # a ring buffer takes each token at its own slot (per-row positions and
+    # writes that wrap around the ring, which a block
+    # dynamic_update_slice would clamp at the edge)
+    at = (jnp.mod(pos_v[:, None] + jnp.arange(t)[None, :], t_max) if ring
+          else cache_pos)
+    new_cache = {name: kv_cache_write(cache[name], a.reshape(b, t, -1),
+                                      cache_layer, at)
+                 for name, a in new.items()}
+    cur = {name: jax.lax.dynamic_index_in_dim(a, cache_layer, 0,
+                                              keepdims=False)
+           for name, a in new_cache.items()}
+    if ring:
+        ck = cur["k"].reshape(b, t_max, n_kv, head_dim)
+        cv = cur["v"].reshape(b, t_max, n_kv, head_dim)
+        if "k_s" in cur:
+            ck = ck.astype(jnp.float32) * cur["k_s"][..., None]
+            cv = cv.astype(jnp.float32) * cur["v_s"][..., None]
+        last = pos_v + (t - 1)
+        idx = jnp.arange(t_max)
+        k_pos = last[:, None] - jnp.mod(last[:, None] - idx[None, :], t_max)
+        out = ring_decode_attention(q, ck, cv, k_pos, pos_v, window or t_max)
+    elif t <= 8:
+        # single-token decode: direct masked attention over the full cache
+        # masked to each row's own valid length — scores are (B, H, t, T):
+        # tiny, and the T axis keeps its sequence-parallel sharding (the
+        # chunked scan's reshape would force a reshard)
+        out = _direct_attention(q, cur["k"], cur["v"], q_offset=cache_pos,
+                                kv_len=cache_pos + t, causal=causal,
+                                window=window, k_scale=cur.get("k_s"),
+                                v_scale=cur.get("v_s"))
+    elif "k_s" in cur:
+        # a guard, not an assert: serving stacks routinely run under
+        # ``python -O``, which strips asserts — and a silently oversized
+        # query here would attend with garbage positions, not crash
+        raise ValueError(
+            f"int8 KV cache path supports decode-sized queries (t <= 8), "
+            f"got t={t}; chunk the prefill (Engine does this via "
+            f"prefill_buckets) or use the fp32 cache for long queries")
+    else:
+        out = chunked_attention(
+            q, cur["k"].reshape(b, t_max, n_kv, head_dim),
+            cur["v"].reshape(b, t_max, n_kv, head_dim), causal=causal,
+            window=window, q_offset=cache_pos, kv_len=cache_pos + t,
+            chunk=chunk)
     out = out.reshape(b, t, n_heads * head_dim)
     return dense_apply(p["wo"], out), new_cache
 

@@ -123,8 +123,12 @@ def _block_init(cfg: ModelConfig, key, kind: str, sparse_mlp: Optional[SparseMLP
 
 def _block_apply(cfg: ModelConfig, p, x, kind: str, *, positions,
                  sparse_mlp: Optional[SparseMLP], enc_out=None,
-                 cache=None, cache_pos=None):
-    """Returns (x, aux_loss, new_cache)."""
+                 cache=None, layer=None, cache_pos=None):
+    """Returns (x, aux_loss, new_cache).
+
+    ``cache`` is the block's layer-stacked decode cache and ``layer`` the
+    block's index in it; ``new_cache`` is the stack with this layer's
+    tokens (KV caches) or state (recurrent blocks) written in."""
     if cfg.seq_shard and cache is None:
         x = act_constrain(x, "seq")
     aux = jnp.zeros((), jnp.float32)
@@ -139,8 +143,8 @@ def _block_apply(cfg: ModelConfig, p, x, kind: str, *, positions,
                 n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.hd,
                 positions=positions, causal=(kind != "attn_bidir"),
                 window=window, rope_theta=cfg.rope_theta,
-                cache=cache.get("kv") if cache else None, cache_pos=cache_pos,
-                chunk=cfg.attn_chunk,
+                cache=cache.get("kv") if cache else None, cache_layer=layer,
+                cache_pos=cache_pos, chunk=cfg.attn_chunk,
                 ring=(kind == "local" and cache is not None))
         x = x + h
         if kv is not None:
@@ -166,8 +170,8 @@ def _block_apply(cfg: ModelConfig, p, x, kind: str, *, positions,
                 p["attn"], layers.rmsnorm_apply(p["norm1"], x, cfg.norm_eps),
                 n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.hd,
                 positions=positions, causal=True, rope_theta=cfg.rope_theta,
-                cache=cache.get("kv") if cache else None, cache_pos=cache_pos,
-                chunk=cfg.attn_chunk)
+                cache=cache.get("kv") if cache else None, cache_layer=layer,
+                cache_pos=cache_pos, chunk=cfg.attn_chunk)
         x = x + h
         if kv is not None:
             new_cache["kv"] = kv
@@ -179,15 +183,17 @@ def _block_apply(cfg: ModelConfig, p, x, kind: str, *, positions,
     elif kind == "rec":
         h, st = recurrent.rglru_block_apply(
             p["rec"], layers.rmsnorm_apply(p["norm1"], x, cfg.norm_eps),
-            state=cache.get("rec") if cache else None)
+            state=_state_read(cache["rec"], layer) if cache else None)
         x = x + h
-        new_cache["rec"] = st
+        new_cache["rec"] = (_state_write(cache["rec"], layer, st) if cache
+                            else st)
         with jax.named_scope("ffn"):
             x = x + layers.swiglu_apply(
                 p["mlp"], layers.rmsnorm_apply(p["norm2"], x, cfg.norm_eps))
     elif kind == "rwkv":
-        st = cache.get("rwkv") if cache else recurrent.rwkv_block_state(
-            x.shape[0], cfg.d_model, cfg.n_heads or 32, x.dtype)
+        st = (_state_read(cache["rwkv"], layer) if cache
+              else recurrent.rwkv_block_state(x.shape[0], cfg.d_model,
+                                              cfg.n_heads or 32, x.dtype))
         h, st_tm = recurrent.rwkv_time_mix(
             p["rwkv"], layers.rmsnorm_apply(p["norm1"], x, cfg.norm_eps),
             cfg.n_heads or 32, {"shift": st["shift"], "S": st["S"]})
@@ -196,22 +202,43 @@ def _block_apply(cfg: ModelConfig, p, x, kind: str, *, positions,
             p["rwkv"], layers.rmsnorm_apply(p["norm2"], x, cfg.norm_eps),
             st["cm_shift"])
         x = x + h
-        new_cache["rwkv"] = {"shift": st_tm["shift"], "S": st_tm["S"],
-                             "cm_shift": cm_shift}
+        st = {"shift": st_tm["shift"], "S": st_tm["S"], "cm_shift": cm_shift}
+        new_cache["rwkv"] = (_state_write(cache["rwkv"], layer, st) if cache
+                             else st)
     else:
         raise ValueError(kind)
     return x, aux, new_cache
 
 
+def _state_read(stack, layer):
+    """One layer's recurrent state out of its layer-stacked cache."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+        stack)
+
+
+def _state_write(stack, layer, state):
+    """The stack with layer ``layer``'s recurrent state replaced whole
+    (O(d) a row: no token granularity to exploit)."""
+    return jax.tree.map(
+        lambda a, s: jax.lax.dynamic_update_index_in_dim(
+            a, s.astype(a.dtype), layer, 0), stack, state)
+
+
 def _block_cache_init(cfg: ModelConfig, kind: str, b: int, t_max: int, dt):
+    """One layer's decode cache.  K and V are (B, T, n_kv·hd): heads and
+    head dim merged into one minor axis, so the token write and the
+    attention read share a layout with no padding of hd to the lane
+    width."""
     def kv(t_len):
+        f = cfg.n_kv * cfg.hd
         if cfg.kv_cache_dtype == "int8":
-            return {"k": jnp.zeros((b, t_len, cfg.n_kv, cfg.hd), jnp.int8),
-                    "v": jnp.zeros((b, t_len, cfg.n_kv, cfg.hd), jnp.int8),
+            return {"k": jnp.zeros((b, t_len, f), jnp.int8),
+                    "v": jnp.zeros((b, t_len, f), jnp.int8),
                     "k_s": jnp.zeros((b, t_len, cfg.n_kv), jnp.float32),
                     "v_s": jnp.zeros((b, t_len, cfg.n_kv), jnp.float32)}
-        return {"k": jnp.zeros((b, t_len, cfg.n_kv, cfg.hd), dt),
-                "v": jnp.zeros((b, t_len, cfg.n_kv, cfg.hd), dt)}
+        return {"k": jnp.zeros((b, t_len, f), dt),
+                "v": jnp.zeros((b, t_len, f), dt)}
     if kind in ("attn", "cross", "moe"):
         return {"kv": kv(t_max)}
     if kind == "local":
@@ -327,45 +354,49 @@ class Transformer:
 
     # -- scanned stacks -------------------------------------------------------
     def _run_group(self, params_g, x, kinds, *, positions, enc_out=None,
-                   caches=None, cache_pos=None, collect_cache=False):
+                   caches=None, cache_pos=None):
+        """Scan the group's layers over ``x``.  With ``caches`` (decode and
+        prefill) the layer-stacked cache rides in the scan's carry: each
+        layer writes its new tokens (or its recurrent state) into the
+        stack and reads its own slice back, so a donated cache is updated
+        in place and no second stack is built.  Returns (x, aux, caches).
+        """
         cfg = self.cfg
 
         def body(carry, inp):
-            x, aux = carry
-            p_l = inp[0]
-            cache_l = inp[1] if caches is not None else None
+            x, aux, cache = carry
+            p_l, layer = inp
             if isinstance(kinds, tuple):
-                new_c = {}
-                for j, kd in enumerate(kinds):
-                    sub_c = cache_l[f"b{j}"] if cache_l is not None else None
-                    x, a, nc = _block_apply(
-                        cfg, p_l[f"b{j}"], x, kd, positions=positions,
-                        sparse_mlp=self.sparse_mlp, enc_out=enc_out,
-                        cache=sub_c, cache_pos=cache_pos)
-                    new_c[f"b{j}"] = nc
-                    aux = aux + a
+                blocks = [(p_l[f"b{j}"], kd, f"b{j}")
+                          for j, kd in enumerate(kinds)]
             else:
-                x, a, new_c = _block_apply(
-                    cfg, p_l, x, kinds, positions=positions,
+                blocks = [(p_l, kinds, None)]
+            for p_b, kd, sub in blocks:
+                c_b = cache if cache is None or sub is None else cache[sub]
+                x, a, c_b = _block_apply(
+                    cfg, p_b, x, kd, positions=positions,
                     sparse_mlp=self.sparse_mlp, enc_out=enc_out,
-                    cache=cache_l, cache_pos=cache_pos)
+                    cache=c_b, layer=layer, cache_pos=cache_pos)
+                if cache is not None:
+                    cache = c_b if sub is None else {**cache, sub: c_b}
                 aux = aux + a
-            return (x, aux), (new_c if collect_cache else 0)
+            return (x, aux, cache), None
 
         body_fn = body
         if cfg.remat and caches is None:
             body_fn = jax.checkpoint(body, prevent_cse=False)
-        xs = (params_g,) if caches is None else (params_g, caches)
+        n = jax.tree.leaves(params_g)[0].shape[0]
+        xs = (params_g, None if caches is None else jnp.arange(n))
         # NOTE (decode on CPU backend): XLA's bf16-dot emulation hoists f32
         # converts of the per-layer KV-cache slices out of this scan and
         # carries full f32 cache copies in the while tuple. This is a
         # CPU-only artifact (TPU bf16 dots are native); the dry-run measures
         # and subtracts it — see launch/dryrun.py `cpu_artifact_bytes`.
         # the body is traced once and runs once per layer
-        with obs.repeated(jax.tree.leaves(params_g)[0].shape[0]):
-            (x, aux), new_caches = jax.lax.scan(
-                body_fn, (x, jnp.zeros((), jnp.float32)), xs)
-        return x, aux, (new_caches if collect_cache else None)
+        with obs.repeated(n):
+            (x, aux, caches), _ = jax.lax.scan(
+                body_fn, (x, jnp.zeros((), jnp.float32), caches), xs)
+        return x, aux, caches
 
     # -- forward (train / prefill logits) -------------------------------------
     def forward(self, params, tokens, vis_embeds=None, enc_embeds=None):
@@ -425,7 +456,8 @@ class Transformer:
                        for j, kd in enumerate(kinds)}
             else:
                 one = _block_cache_init(cfg, kinds, batch_size, max_len, dt)
-            return jax.tree.map(lambda a: jnp.broadcast_to(a, (n,) + a.shape), one)
+            return jax.tree.map(lambda a: jnp.zeros((n,) + a.shape, a.dtype),
+                                one)
 
         return {name: stack(kinds, n) for (name, kinds, n) in self.groups
                 if name != "enc"}
@@ -460,7 +492,7 @@ class Transformer:
                 continue
             x, _, nc = self._run_group(
                 params[name], x, kinds, positions=positions, enc_out=enc_out,
-                caches=cache[name], cache_pos=pos, collect_cache=True)
+                caches=cache[name], cache_pos=pos)
             new_cache[name] = nc
         x = layers.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
         # gather each row's output position *before* the lm_head so the

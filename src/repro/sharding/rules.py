@@ -110,24 +110,25 @@ def batch_pspecs(mesh: Mesh, batch):
         batch)
 
 
-def cache_pspec(mesh: Mesh, leaf) -> P:
+def cache_pspec(mesh: Mesh, path, leaf) -> P:
     """Decode-state sharding: batch on dp, axis-2 on model.
 
-    KV caches are (layers, B, T, n_kv, hd) → **sequence-parallel decode**:
-    the 32k KV timeline shards over the model axis (1.1 TB of command-r
-    cache → 2.1 GB/chip); attention reductions over T psum across shards.
+    KV caches ``k``/``v`` are (layers, B, T, n_kv·hd) → **sequence-parallel
+    decode**: the 32k KV timeline shards over the model axis (1.1 TB of
+    command-r cache → 2.1 GB/chip); attention reductions over T psum across
+    shards.  The int8 cache's scales ``k_s``/``v_s`` (layers, B, T, n_kv)
+    shard T with it (otherwise every layer reshards them — §Perf C4).
     RWKV state (layers, B, H, hd, hd) shards heads on the same rule.
+    ``path`` is the leaf's key path in the cache tree, as
+    ``jax.tree_util.tree_map_with_path`` gives it.
     """
     dp = dp_axes(mesh)
     ndim = leaf.ndim
-    if ndim >= 5:
+    names = _path_names(path)
+    timeline = bool(names) and names[-1] in ("k", "v", "k_s", "v_s")
+    if timeline or ndim >= 5:
         tp = "model" if (leaf.shape[2] % mesh.shape["model"] == 0) else None
         return P(None, dp, tp, *([None] * (ndim - 3)))
-    if ndim == 4 and leaf.shape[2] >= 1024 \
-            and leaf.shape[2] % mesh.shape["model"] == 0:
-        # int8-KV scale arrays (layers, B, T, n_kv): T-shard to match the
-        # quantized cache (otherwise every layer reshards them — §Perf C4)
-        return P(None, dp, "model", None)
     if ndim >= 2:
         return P(None, dp, *([None] * (ndim - 2)))
     return P()

@@ -95,6 +95,73 @@ def _kernel_name(case) -> str:
     return name
 
 
+#: the served configuration whose decode program the rehearsal compiles
+SERVED = ROOT / "bench" / "configs" / "phi3-mini-3.8b-bsffn.json"
+
+#: an HLO instruction: name, the shape of what it outputs, its opcode
+_HLO_OP = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(", re.M)
+_BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1,
+          "u8": 1, "pred": 1}
+
+
+def test_engine_decode_updates_cache_in_place(one_chip, monkeypatch):
+    """``Engine``'s decode program at the served phi3-mini configuration
+    (32 layers, 7 slots, max_len 1024, block-sparse FFN on the Segment
+    kernels), the cache donated, compiled for a described v5e from shapes
+    alone: the cache rides in the layer scan's carry, so no copy, dynamic
+    slice or dynamic update outputs an array as large as one stacked K or V
+    cache, and the temporaries stay below one stacked K+V cache."""
+    import json
+
+    from repro.launch.serve import serving_config
+    from repro.models import build_model
+    from repro.runtime.serve import Engine
+
+    served = json.loads(SERVED.read_text())
+    cfg = serving_config(served["registry"], sparse_ffn=True,
+                         ffn_block=served["ffn_block"],
+                         ffn_density=served["ffn_density"])
+    assert (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv, cfg.d_ff,
+            cfg.vocab) == (
+        served["num_hidden_layers"], served["hidden_size"],
+        served["num_attention_heads"], served["num_key_value_heads"],
+        served["intermediate_size"], served["vocab_size"])
+    model = build_model(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    init_cache = model.init_cache
+    monkeypatch.setattr(model, "init_cache", lambda b, t: jax.eval_shape(
+        lambda: init_cache(b, t)))
+    engine = Engine(model, params, slots=served["slots"],
+                    max_len=served["max_len"], backend="pallas")
+    slots = served["slots"]
+    compiled = engine._decode.lower(
+        _struct(params, one_chip), _struct(engine.cache, one_chip),
+        jax.ShapeDtypeStruct((slots, 1), np.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((slots,), np.int32, sharding=one_chip)).compile()
+
+    k = engine.cache["layers"]["kv"]["k"]
+    k_bytes = k.size * k.dtype.itemsize
+    moved = []
+    for name, dtype, dims, opcode in _HLO_OP.findall(compiled.as_text()):
+        kind = name + " " + opcode
+        if not any(w in kind for w in ("copy", "dynamic-slice",
+                                       "dynamic-update-slice")):
+            continue
+        size = _BYTES[dtype] * int(np.prod([int(d) for d in dims.split(",")
+                                            if d]))
+        if size >= k_bytes:
+            moved.append((name, dtype, dims))
+    assert not moved, moved
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 * k_bytes, mem.temp_size_in_bytes
+    # the donated cache is the output cache
+    assert mem.alias_size_in_bytes >= 2 * k_bytes, mem.alias_size_in_bytes
+    # one layout for the token write and the attention read
+    assert k.shape == (cfg.n_layers, slots, served["max_len"],
+                       cfg.n_kv * cfg.hd)
+
+
 # ---------------------------------------------------------------------------
 # CPU: what the compiled backend needs from the executor and the planner
 # ---------------------------------------------------------------------------

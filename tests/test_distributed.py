@@ -205,3 +205,62 @@ def test_dryrun_cell_small_mesh():
     print("MINI_DRYRUN_OK")
     """)
     assert "MINI_DRYRUN_OK" in out
+
+
+def test_cache_pspec_shards_kv_timeline():
+    """Sequence-parallel decode on a 1×4 mesh: ``cache_pspec`` puts the KV
+    timeline of the (layers, B, T, n_kv·hd) K/V leaves, and of the int8
+    cache's (layers, B, T, n_kv) scales, on ``model``; decode steps on
+    those shardings, the cache donated, match one device."""
+    out = _run("""
+    import dataclasses
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.configs import REGISTRY, reduced_config
+    from repro.models import build_model
+    from repro.launch.mesh import make_test_mesh
+    from repro.sharding import cache_pspec, sanitize_pspec
+
+    mesh = make_test_mesh(1, 4)
+    for kv_dtype, leaves in (("bfloat16", {"k", "v"}),
+                             ("int8", {"k", "v", "k_s", "v_s"})):
+        cfg = dataclasses.replace(reduced_config(REGISTRY["granite-3-8b"]),
+                                  kv_cache_dtype=kv_dtype)
+        model = build_model(cfg)
+        params = model.init(jax.random.PRNGKey(0))
+        cache = model.init_cache(2, 64)
+        cache_sh = jax.tree_util.tree_map_with_path(
+            lambda path, leaf: NamedSharding(mesh, sanitize_pspec(
+                mesh, cache_pspec(mesh, path, leaf), leaf.shape)), cache)
+        seen = set()
+        for path, sh in jax.tree_util.tree_leaves_with_path(cache_sh):
+            seen.add(path[-1].key)
+            assert sh.spec == P(None, "data", "model", None), (path, sh.spec)
+        assert seen == leaves, seen
+
+        def decode(params, cache, tok, pos):
+            return model.decode_step(params, cache, tok, pos)
+
+        rep = NamedSharding(mesh, P())
+        with jax.set_mesh(mesh):
+            step = jax.jit(decode, donate_argnums=(1,),
+                           in_shardings=(rep, cache_sh, rep, rep),
+                           out_shardings=(rep, cache_sh))
+            c_mesh = jax.device_put(cache, cache_sh)
+        one = jax.jit(decode)
+        c_one = model.init_cache(2, 64)
+        # each step attends over what the earlier steps wrote
+        for i in range(3):
+            tok = jnp.full((2, 1), 5 + i, jnp.int32)
+            pos = jnp.array([i, 7 + i], jnp.int32)
+            with jax.set_mesh(mesh):
+                lo_mesh, c_mesh = step(params, c_mesh, tok, pos)
+            lo_one, c_one = one(params, c_one, tok, pos)
+            np.testing.assert_allclose(np.asarray(lo_mesh, np.float32),
+                                       np.asarray(lo_one, np.float32),
+                                       rtol=1e-2, atol=1e-2)
+        assert jax.tree.leaves(c_mesh)[0].sharding == jax.tree.leaves(
+            cache_sh)[0]
+    print("CACHE_SHARDING_OK")
+    """, devices=4)
+    assert "CACHE_SHARDING_OK" in out

@@ -73,24 +73,65 @@ def test_arch_gradients(arch):
     assert np.isfinite(gnorm) and gnorm > 0
 
 
-def test_decode_matches_forward():
+#: one decoder per kind of decode cache: full bf16 K/V, the int8 K/V cache
+#: with its scales, MoE, the hybrid family's recurrent state beside its
+#: ``local`` ring buffer, and rwkv's recurrent state
+DECODE_CASES = {
+    "granite-3-8b": ("granite-3-8b", {}),
+    "int8-kv": ("granite-3-8b", {"kv_cache_dtype": "int8"}),
+    # capacity for every token (4 experts): batch-global expert capacity
+    # would otherwise drop different tokens in a decode step and a forward
+    "moe": ("phi3.5-moe-42b-a6.6b", {"moe_capacity_factor": 4.0}),
+    "hybrid-ring": ("recurrentgemma-9b", {}),
+    "rwkv": ("rwkv6-1.6b", {}),
+}
+
+
+def _decode_tokens(model, params, cache, toks, start):
+    """Feed ``toks`` (B, n) one token a step; row ``r`` sits at position
+    ``start[r]`` before the first (a shared scalar when ``start`` is an
+    int).  Returns the last step's logits and the cache."""
+    step = jax.jit(model.decode_step)
+    for i in range(toks.shape[1]):
+        logits, cache = step(params, cache, toks[:, i:i + 1],
+                             jnp.asarray(start, jnp.int32) + i)
+    return logits, cache
+
+
+@pytest.mark.parametrize("per_row", [False, True],
+                         ids=["shared_pos", "per_row_pos"])
+@pytest.mark.parametrize("case", list(DECODE_CASES))
+def test_decode_matches_forward(case, per_row):
     """Greedy decode over a prompt must produce the same last-token logits
-    as a full forward pass (cache correctness)."""
-    cfg = dataclasses.replace(reduced_config(REGISTRY["granite-3-8b"]),
-                              attn_chunk=32)
+    as a full forward pass (cache correctness).  ``per_row``: the rows sit
+    at different positions, as ``Engine`` sends them — row 1 decodes its
+    first ``off`` tokens alone, then both rows decode together at per-row
+    ``(B,)`` positions."""
+    arch, over = DECODE_CASES[case]
+    cfg = dataclasses.replace(reduced_config(REGISTRY[arch]), attn_chunk=32,
+                              **over)
     model = build_model(cfg)
     params = model.init(KEY)
-    b, t = 2, 16
-    toks = (jnp.arange(b * t).reshape(b, t) * 7) % cfg.vocab
-    logits_full, _ = model.forward(params, toks)
-    cache = model.init_cache(b, 64)
-    # feed tokens one by one
-    for i in range(t):
-        logits_dec, cache = model.decode_step(
-            params, cache, toks[:, i:i + 1], jnp.int32(i))
-    np.testing.assert_allclose(
-        np.asarray(logits_dec, np.float32),
-        np.asarray(logits_full[:, -1], np.float32), rtol=2e-2, atol=2e-2)
+    b, t, off = 2, 24, (11 if per_row else 0)
+    # the ring of the hybrid case (local_window 32) wraps at 24 + 11
+    toks = (jnp.arange(b * (t + off)).reshape(b, t + off) * 7) % cfg.vocab
+    seqs = [toks[0, :t], toks[1]]
+    if per_row:
+        _, lead = _decode_tokens(model, params, model.init_cache(1, 64),
+                                 toks[1:, :off], 0)
+        cache = jax.tree.map(lambda a, r: jnp.concatenate([a, r], axis=1),
+                             model.init_cache(1, 64), lead)
+        logits_dec, _ = _decode_tokens(
+            model, params, cache, jnp.stack([seqs[0], seqs[1][off:]]),
+            np.array([0, off]))
+    else:
+        logits_dec, _ = _decode_tokens(model, params, model.init_cache(b, 64),
+                                       toks[:, :t], 0)
+    for r, seq in enumerate(seqs):
+        logits_full, _ = model.forward(params, seq[None])
+        np.testing.assert_allclose(
+            np.asarray(logits_dec[r], np.float32),
+            np.asarray(logits_full[0, -1], np.float32), rtol=2e-2, atol=2e-2)
 
 
 def test_chunked_prefill_matches_stepwise():
