@@ -412,7 +412,7 @@ def analyze_shipped_kernels(verbose: bool = False) -> List[LintFinding]:
     from repro.api import apply_plan, execute_plan, plan_matmul
     from repro.core.formats import BSR
     from repro.kernels.flash_attention import flash_attention
-    from repro.kernels.moe_gemm import build_moe_chunks, moe_gemm
+    from repro.kernels.moe_gemm import moe_gemm
     from repro.kernels.rg_lru import rg_lru
     from repro.kernels.segment_spgemm import segment_spgemm
     from repro.kernels.segment_spmm import segment_spmm
@@ -445,9 +445,12 @@ def analyze_shipped_kernels(verbose: bool = False) -> List[LintFinding]:
     h0 = jnp.zeros((2, 16), jnp.float32)
     ap = jnp.zeros((16,), jnp.float32)
     n_experts = 4
-    chunk_expert = jnp.arange(n_experts, dtype=jnp.int32)
-    xs = jnp.zeros((n_experts * 128, 32), jnp.float32)
-    w = jnp.zeros((n_experts, 32, 64), jnp.float32)
+    # drop-free layout: one spare chunk past the used ones, which skips
+    chunk_expert = jnp.array([0, 1, 2, 3, 3], jnp.int32)
+    n_used = jnp.array([4], jnp.int32)
+    layer = jnp.array([1], jnp.int32)
+    xs = jnp.zeros((5 * 128, 32), jnp.float32)
+    w = jnp.zeros((2, n_experts, 32, 64), jnp.float32)
 
     traces = [
         ("spmm-pipelined",
@@ -506,9 +509,10 @@ def analyze_shipped_kernels(verbose: bool = False) -> List[LintFinding]:
              qq, kk, vv, causal=True, window=128, q_period=128,
              interpret=True))(q, kv, kv), (q, kv, kv)),
         ("moe-gemm",
-         lambda: jax.make_jaxpr(lambda xx, ww, ce: moe_gemm(
-             xx, ww, ce, chunk_rows=128, bn=64,
-             interpret=True))(xs, w, chunk_expert), (xs, w, chunk_expert)),
+         lambda: jax.make_jaxpr(lambda xx, ww, ce, nu, ly: moe_gemm(
+             xx, ww, ce, nu, ly, chunk_rows=128, bn=64,
+             interpret=True))(xs, w, chunk_expert, n_used, layer),
+         (xs, w, chunk_expert, n_used, layer)),
         ("rg-lru",
          lambda: jax.make_jaxpr(lambda *args: rg_lru(
              *args, ct=128, interpret=True))(xt, xt, xt, ap, h0),

@@ -3,14 +3,14 @@ import dataclasses
 
 from .base import SHAPES, ModelConfig, ShapeConfig
 from . import (command_r_plus_104b, granite_3_8b, internvl2_2b,
-               llama4_maverick_400b_a17b, phi3_5_moe_42b_a6_6b,
-               phi3_mini_3_8b, qwen1_5_4b, recurrentgemma_9b, rwkv6_1_6b,
+               llama4_maverick_400b_a17b, moonlight_16b_a3b,
+               phi3_5_moe_42b_a6_6b, phi3_mini_3_8b, qwen1_5_4b, recurrentgemma_9b, rwkv6_1_6b,
                whisper_tiny)
 
 REGISTRY = {m.CONFIG.name: m.CONFIG for m in (
     internvl2_2b, whisper_tiny, phi3_mini_3_8b, qwen1_5_4b, granite_3_8b,
     command_r_plus_104b, recurrentgemma_9b, llama4_maverick_400b_a17b,
-    phi3_5_moe_42b_a6_6b, rwkv6_1_6b)}
+    phi3_5_moe_42b_a6_6b, rwkv6_1_6b, moonlight_16b_a3b)}
 
 ARCH_IDS = list(REGISTRY)
 
@@ -37,6 +37,12 @@ def reduced_config(cfg: ModelConfig) -> ModelConfig:
         vocab=512,
         n_experts=min(cfg.n_experts, 4),
         top_k=min(cfg.top_k, 2),
+        moe_d_ff=min(cfg.moe_d_ff, 32),
+        experts_held=min(cfg.experts_held, 4),
+        kv_lora_rank=min(cfg.kv_lora_rank, 32),
+        qk_nope_head_dim=min(cfg.qk_nope_head_dim, 16),
+        qk_rope_head_dim=min(cfg.qk_rope_head_dim, 8),
+        v_head_dim=min(cfg.v_head_dim, 16),
         enc_layers=min(cfg.enc_layers, 2),
         dec_layers=min(cfg.dec_layers, 2),
         local_window=32,

@@ -21,10 +21,26 @@ class ModelConfig:
     rope_theta: float = 10000.0
     norm_eps: float = 1e-6
     tie_embeddings: bool = True
+    # multi-head latent attention (DeepSeek-V2 MLA); on when kv_lora_rank > 0
+    kv_lora_rank: int = 0         # width of the cached latent c_kv
+    qk_nope_head_dim: int = 0     # per-head query/key width without RoPE
+    qk_rope_head_dim: int = 0     # RoPE'd width, one key stream for all heads
+    v_head_dim: int = 0
     # MoE
-    n_experts: int = 0
+    n_experts: int = 0            # routed experts the router scores over
     top_k: int = 0
-    moe_capacity_factor: float = 1.25
+    moe_d_ff: int = 0             # expert width (0: d_ff)
+    n_shared_experts: int = 0     # always-on experts, one SwiGLU of
+                                  # n_shared_experts * moe_d_ff
+    first_k_dense: int = 0        # leading layers with a dense SwiGLU FFN
+    moe_score: str = "softmax"    # softmax | sigmoid (sigmoid: DeepSeek-V3
+                                  # selection by score + a correction bias)
+    moe_route_scale: float = 1.0  # scale of the routed weights, which are
+                                  # the chosen scores normalized to sum 1
+    experts_held: int = 0         # the expert share: routed experts
+                                  # r*experts_held .. (r+1)*experts_held-1
+                                  # live here, r = expert_rank (0: all)
+    expert_rank: int = 0          # which share of experts_held this is
     # hybrid (RecurrentGemma): repeating layer pattern
     layer_pattern: Tuple[str, ...] = ()   # e.g. ("rec", "rec", "local")
     local_window: int = 2048
@@ -53,6 +69,20 @@ class ModelConfig:
         return ((self.vocab + 255) // 256) * 256
 
     @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def n_held(self) -> int:
+        """Routed experts whose weights this model holds."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def first_expert(self) -> int:
+        """The first routed expert this model holds."""
+        return self.expert_rank * self.experts_held
+
+    @property
     def hd(self) -> int:
         if self.head_dim is not None:
             return self.head_dim
@@ -64,23 +94,35 @@ class ModelConfig:
             return "rwkv"
         if self.layer_pattern:
             return self.layer_pattern[i % len(self.layer_pattern)]
-        if self.n_experts:
+        if self.n_experts and i >= self.first_k_dense:
             return "moe"
         return "attn"
+
+    def attn_param_count(self) -> int:
+        """Parameters of one attention block's projections."""
+        d, h = self.d_model, self.n_heads
+        if self.kv_lora_rank:
+            r, dr = self.kv_lora_rank, self.qk_rope_head_dim
+            return (d * h * (self.qk_nope_head_dim + dr) + d * (r + dr)
+                    + r * h * (self.qk_nope_head_dim + self.v_head_dim)
+                    + h * self.v_head_dim * d)
+        hd = self.hd
+        return d * (h * hd) + 2 * d * (self.n_kv * hd) + (h * hd) * d
 
     def param_count(self) -> int:
         """Approximate total parameters (for roofline MODEL_FLOPS)."""
         d, ff, v = self.d_model, self.d_ff, self.vocab
-        hd = self.hd
         per_layer = 0
         n_layers = self.n_layers if not self.enc_layers else (
             self.enc_layers + self.dec_layers)
         for i in range(n_layers):
             kind = self.layer_kind(i)
-            attn = d * (self.n_heads * hd) + 2 * d * (self.n_kv * hd) \
-                + (self.n_heads * hd) * d
+            attn = self.attn_param_count()
             if kind == "moe":
-                per_layer += attn + self.n_experts * 3 * d * ff + d * self.n_experts
+                eff = self.expert_d_ff
+                per_layer += (attn + self.n_experts * 3 * d * eff
+                              + self.n_shared_experts * 3 * d * eff
+                              + d * self.n_experts)
             elif kind == "rec":
                 per_layer += 4 * d * d + 3 * d * ff  # rglru block + mlp
             elif kind == "rwkv":
@@ -96,7 +138,7 @@ class ModelConfig:
         """Active params per token (MoE: top_k experts only)."""
         if not self.n_experts:
             return self.param_count()
-        d, ff = self.d_model, self.d_ff
+        d, ff = self.d_model, self.expert_d_ff
         total = self.param_count()
         moe_layers = sum(1 for i in range(self.n_layers)
                          if self.layer_kind(i) == "moe")

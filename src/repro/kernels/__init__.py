@@ -5,10 +5,10 @@ Each kernel module pairs with a pure-jnp oracle in :mod:`repro.kernels.ref`;
 unless the default backend is ``pallas``).
 """
 from . import ops, ref
-from .ops import (SpgemmPlan, SpmmPlan, flash_mha, moe_apply,
-                  plan_spgemm, plan_spmm, rg_lru_scan)
+from .ops import (SpgemmPlan, SpmmPlan, flash_mha, plan_spgemm, plan_spmm,
+                  rg_lru_scan)
 
 __all__ = [
     "ops", "ref", "SpgemmPlan", "SpmmPlan", "flash_mha",
-    "moe_apply", "plan_spgemm", "plan_spmm", "rg_lru_scan",
+    "plan_spgemm", "plan_spmm", "rg_lru_scan",
 ]

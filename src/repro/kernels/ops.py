@@ -14,7 +14,6 @@ from __future__ import annotations
 import warnings
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
 from repro.api.backends import default_backend
@@ -23,7 +22,6 @@ from repro.api.planner import plan_matmul
 from repro.core.formats import BSR
 from . import ref
 from .flash_attention import flash_attention
-from .moe_gemm import build_moe_chunks, moe_gemm
 from .rg_lru import rg_lru
 
 
@@ -58,7 +56,7 @@ def plan_spgemm(a: BSR, b: BSR, policy: str = "segment",
 
 
 # ---------------------------------------------------------------------------
-# Attention / recurrences / MoE
+# Attention / recurrences
 # ---------------------------------------------------------------------------
 
 
@@ -114,44 +112,7 @@ def rg_lru_scan(x, a_gate, x_gate, a_param, h0=None, *, ct: int = 128,
                   interpret=interpret)
 
 
-def moe_apply(x, w_up, w_down, router_logits, *, top_k: int = 1,
-              chunk_rows: int = 128, capacity_factor: float = 1.25,
-              activation=jax.nn.silu, interpret: Optional[bool] = None):
-    """Full MoE FFN: route → Segment-sort → grouped GEMMs → unsort-combine.
-
-    x: (T, d_model); w_up: (E, d_model, d_ff); w_down: (E, d_ff, d_model).
-    Returns (T, d_model).
-    """
-    interpret = _interpret(interpret)
-    t, d_model = x.shape
-    n_exp = w_up.shape[0]
-    top_vals, top_idx = jax.lax.top_k(router_logits, top_k)      # (T, top_k)
-    gates = jax.nn.softmax(top_vals, axis=-1)
-    out = jnp.zeros((t, d_model), jnp.float32)
-    for j in range(top_k):
-        expert = top_idx[:, j]
-        order, slot, chunk_expert, keep, n_chunks, cap_rows = build_moe_chunks(
-            expert, n_exp, chunk_rows, capacity_factor)
-        cap_total = n_exp * cap_rows
-        # scatter tokens (sorted by expert) into the padded chunk buffer;
-        # dropped tokens land on the trash row which is cut before the GEMM
-        buf = jnp.zeros((cap_total + 1, d_model), x.dtype)
-        buf = buf.at[slot].set(jnp.where(keep[:, None], x[order], 0))
-        buf = buf[:-1]
-        h = moe_gemm(buf, w_up, chunk_expert, chunk_rows=chunk_rows,
-                     interpret=interpret)
-        h = activation(h).astype(x.dtype)
-        y = moe_gemm(h, w_down, chunk_expert, chunk_rows=chunk_rows,
-                     interpret=interpret)
-        # gather back: sorted position s ↔ original token order[s]
-        vals = jnp.where(keep[:, None],
-                         y[jnp.minimum(slot, cap_total - 1)], 0.0)
-        y_tok = jnp.zeros((t, d_model), jnp.float32).at[order].set(vals)
-        out = out + y_tok * gates[:, j][:, None]
-    return out
-
-
 __all__ = [
     "SpmmPlan", "SpgemmPlan", "plan_spmm", "plan_spgemm",
-    "flash_mha", "rg_lru_scan", "moe_apply", "ref",
+    "flash_mha", "rg_lru_scan", "ref",
 ]

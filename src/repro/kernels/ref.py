@@ -80,12 +80,16 @@ def spgemm_ref(a_blocks, a_brow, a_bcol, a_grid, b_blocks, b_brow, b_bcol,
 
 def moe_gemm_ref(x, w, chunk_expert, chunk_rows):
     """Grouped GEMM: rows of x are chunked; chunk c uses expert weight
-    w[chunk_expert[c]].  x: (C*rows, d_in), w: (E, d_in, d_out)."""
+    w[chunk_expert[c]].  x: (C*rows, d_in), w: (E, d_in, d_out).  Every
+    chunk is computed (a skipped chunk's rows are zero, so are its
+    products); float32 at the highest precision."""
     n_chunks = chunk_expert.shape[0]
     d_out = w.shape[-1]
     def per_chunk(c):
         xs = jax.lax.dynamic_slice(x, (c * chunk_rows, 0), (chunk_rows, x.shape[1]))
-        return xs.astype(jnp.float32) @ w[chunk_expert[c]].astype(jnp.float32)
+        return jnp.dot(xs.astype(jnp.float32),
+                       w[chunk_expert[c]].astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
     out = jax.vmap(per_chunk)(jnp.arange(n_chunks))
     return out.reshape(n_chunks * chunk_rows, d_out)
 

@@ -113,7 +113,8 @@ def rope(x, positions, theta: float = 10000.0):
 
 def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
                       kv_len=None, chunk=1024):
-    """q: (B, Tq, H, D); k/v: (B, Tk, Hkv, D). Returns (B, Tq, H, D) f32-acc.
+    """q/k: (B, Tq|Tk, H|Hkv, D); v: (B, Tk, Hkv, Dv). Returns
+    (B, Tq, H, Dv), accumulated in f32.
 
     Online-softmax over KV chunks: peak memory O(Tq·chunk) per head instead
     of O(Tq·Tk).  ``q_offset`` is the absolute position of q[0]; ``kv_len``
@@ -122,6 +123,7 @@ def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
     """
     b, tq, h, d = q.shape
     tk, hkv = k.shape[1], k.shape[2]
+    dv = v.shape[-1]
     rep = h // hkv
     kv_len = tk if kv_len is None else kv_len
     kv_len = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (b,))
@@ -138,7 +140,7 @@ def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
 
     # reshape kv to (n_chunks, B, chunk, Hkv, D) for scan
     ks = k.reshape(b, n_chunks, chunk, hkv, d).transpose(1, 0, 2, 3, 4)
-    vs = v.reshape(b, n_chunks, chunk, hkv, d).transpose(1, 0, 2, 3, 4)
+    vs = v.reshape(b, n_chunks, chunk, hkv, dv).transpose(1, 0, 2, 3, 4)
 
     def body(carry, inp):
         m_prev, l_prev, acc = carry
@@ -164,7 +166,7 @@ def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
 
     m0 = jnp.full((b, h, tq), -1e30, jnp.float32)
     l0 = jnp.zeros((b, h, tq), jnp.float32)
-    a0 = jnp.zeros((b, h, tq, d), jnp.float32)
+    a0 = jnp.zeros((b, h, tq, dv), jnp.float32)
     (m_f, l_f, acc), _ = jax.lax.scan(
         body, (m0, l0, a0), (jnp.arange(n_chunks), ks, vs))
     out = acc / jnp.maximum(l_f, 1e-30)[..., None]
@@ -403,6 +405,143 @@ def attention_apply(p, x, *, n_heads, n_kv, head_dim, positions,
             window=window, q_offset=cache_pos, kv_len=cache_pos + t,
             chunk=chunk)
     out = out.reshape(b, t, n_heads * head_dim)
+    return dense_apply(p["wo"], out), new_cache
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2 MLA, no query LoRA)
+# ---------------------------------------------------------------------------
+
+
+def latent_width(kv_lora_rank: int, qk_rope_head_dim: int) -> int:
+    """Columns of one token's cached latent ``[c | k_rope | 0...]``: padded
+    with zeros to a whole number of 128-wide lanes.  The TPU lays out an
+    array whose minor axis is not lane-aligned with another axis minor, and
+    the decode scan would then copy the whole cache in and out of that
+    layout every step."""
+    return -(-(kv_lora_rank + qk_rope_head_dim) // 128) * 128
+
+
+def mla_init(key, d_model, n_heads, *, kv_lora_rank, qk_nope_head_dim,
+             qk_rope_head_dim, v_head_dim, dtype=jnp.float32):
+    k1, k2, k3, k4 = jax.random.split(key, 4)
+    dq = qk_nope_head_dim + qk_rope_head_dim
+    return {
+        "wq": dense_init(k1, d_model, n_heads * dq, dtype=dtype),
+        "wkv_a": dense_init(k2, d_model, kv_lora_rank + qk_rope_head_dim,
+                            dtype=dtype),
+        "kv_norm": rmsnorm_init(kv_lora_rank, dtype),
+        "wkv_b": dense_init(k3, kv_lora_rank,
+                            n_heads * (qk_nope_head_dim + v_head_dim),
+                            dtype=dtype),
+        "wo": dense_init(k4, n_heads * v_head_dim, d_model, dtype=dtype),
+    }
+
+
+def _mla_expanded(p, q, latent, *, n_heads, qk_nope_head_dim, v_head_dim,
+                  q_offset, kv_len, chunk):
+    """Causal attention with every head's K and V made from the latent
+    ``[c, k_rope, 0...]`` (B, T, latent_width): ``[k_nope | v] = c W_kvb``
+    per head, ``k = [k_nope | k_rope]``."""
+    b, tk, _ = latent.shape
+    r = p["kv_norm"]["scale"].shape[0]
+    dr = q.shape[-1] - qk_nope_head_dim
+    kv = dense_apply(p["wkv_b"], latent[..., :r]).reshape(
+        b, tk, n_heads, qk_nope_head_dim + v_head_dim)
+    k_rope = jnp.broadcast_to(latent[:, :, None, r:r + dr],
+                              (b, tk, n_heads, dr))
+    k = jnp.concatenate([kv[..., :qk_nope_head_dim],
+                         k_rope.astype(kv.dtype)], axis=-1)
+    return chunked_attention(q, k, kv[..., qk_nope_head_dim:], causal=True,
+                             q_offset=q_offset, kv_len=kv_len, chunk=chunk)
+
+
+def _mla_absorbed(p, q, latent, *, n_heads, qk_nope_head_dim, v_head_dim,
+                  q_offset, kv_len):
+    """Decode attention read straight from the latent cache (B, T,
+    latent_width): ``W_uk`` is folded into the query (``q_nope W_ukᵀ`` is r
+    wide, beside ``q_rope`` and zeros against the padding), so scores are
+    one dot with the stored latent, and ``W_uv`` is applied to the attended
+    latent.  The cache is never expanded."""
+    b, tq, h, dq = q.shape
+    tk, width = latent.shape[1:]
+    r = p["kv_norm"]["scale"].shape[0]
+    dt = latent.dtype
+    w = p["wkv_b"]["w"].reshape(r, n_heads, qk_nope_head_dim + v_head_dim)
+    q_lat = jnp.einsum("bthn,rhn->bthr", q[..., :qk_nope_head_dim],
+                       w[..., :qk_nope_head_dim].astype(q.dtype),
+                       preferred_element_type=jnp.float32)
+    qf = jnp.concatenate([q_lat.astype(dt),
+                          q[..., qk_nope_head_dim:].astype(dt)], axis=-1)
+    qf = jnp.pad(qf, ((0, 0),) * 3 + ((0, width - qf.shape[-1]),))
+    # the stored latent is the dot's row operand with its minor axis
+    # contracted, the query (B, t, r + dr, H) the column operand, so the
+    # cache keeps one layout for the token write and both reads (as
+    # _direct_attention's K and V do)
+    s = jnp.einsum("bkf,btfh->bhtk", latent, jnp.swapaxes(qf, 2, 3),
+                   preferred_element_type=jnp.float32) / np.sqrt(dq)
+    mask = _decode_mask(b, tq, tk, q_offset=q_offset, kv_len=kv_len,
+                        causal=True, window=None)
+    s = jnp.where(mask[:, None], s, -1e30)
+    pr = jax.nn.softmax(s, axis=-1)
+    # attend over the whole stored row (the rope and padding columns'
+    # products are dropped after) rather than slicing c out of the cache
+    o = jnp.einsum("bhtk,bkf->bhtf", pr.astype(dt), latent,
+                   preferred_element_type=jnp.float32)[..., :r]
+    out = jnp.einsum("bhtr,rhv->bthv", o.astype(dt),
+                     w[..., qk_nope_head_dim:].astype(dt),
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
+
+
+def mla_apply(p, x, *, n_heads, qk_nope_head_dim, qk_rope_head_dim,
+              v_head_dim, positions, rope_theta, norm_eps, cache=None,
+              cache_layer=None, cache_pos=None, chunk=1024):
+    """Causal multi-head latent attention.
+
+    ``q = x W_q`` splits per head into ``q_nope`` and ``q_rope``;
+    ``[c, k_r] = x W_kva``, ``c`` RMS-normalized; RoPE on ``q_rope`` and on
+    ``k_r``, which all heads share; scores ``(q_nope·k_nope + q_rope·k_r) /
+    √(nope + rope)``; ``out = concat_h(o_h) W_o``.
+
+    ``cache``: dict(latent) of (L, B, T_max, latent_width) — one stream a
+    token and layer.  The chunk's ``[c, RoPE(k_r), 0...]`` is written into
+    layer
+    ``cache_layer`` at ``cache_pos`` (a scalar or per-row ``(B,)``), then a
+    decode step (t ≤ 8) attends in the absorbed form over that layer's
+    stored latents and a prefill chunk expands them.  Returns (out,
+    new_cache)."""
+    b, t, _ = x.shape
+    r = p["kv_norm"]["scale"].shape[0]
+    q = dense_apply(p["wq"], x).reshape(
+        b, t, n_heads, qk_nope_head_dim + qk_rope_head_dim)
+    q = jnp.concatenate([q[..., :qk_nope_head_dim],
+                         rope(q[..., qk_nope_head_dim:], positions,
+                              rope_theta)], axis=-1)
+    kv_a = dense_apply(p["wkv_a"], x)
+    c = rmsnorm_apply(p["kv_norm"], kv_a[..., :r], norm_eps)
+    k_r = rope(kv_a[:, :, None, r:], positions, rope_theta)[:, :, 0]
+    latent = jnp.concatenate([c, k_r], axis=-1)
+    latent = jnp.pad(latent, ((0, 0), (0, 0), (0, latent_width(
+        r, qk_rope_head_dim) - latent.shape[-1])))
+    dims = dict(n_heads=n_heads, qk_nope_head_dim=qk_nope_head_dim,
+                v_head_dim=v_head_dim)
+    new_cache = None
+    if cache is None:
+        out = _mla_expanded(p, q, latent, q_offset=0, kv_len=None,
+                            chunk=chunk, **dims)
+    else:
+        new_cache = {"latent": kv_cache_write(cache["latent"], latent,
+                                              cache_layer, cache_pos)}
+        cur = jax.lax.dynamic_index_in_dim(new_cache["latent"], cache_layer,
+                                           0, keepdims=False)
+        if t <= 8:
+            out = _mla_absorbed(p, q, cur, q_offset=cache_pos,
+                                kv_len=cache_pos + t, **dims)
+        else:
+            out = _mla_expanded(p, q, cur, q_offset=cache_pos,
+                                kv_len=cache_pos + t, chunk=chunk, **dims)
+    out = out.reshape(b, t, n_heads * v_head_dim)
     return dense_apply(p["wo"], out), new_cache
 
 

@@ -3,7 +3,8 @@
 Families (``ModelConfig.family``):
 * ``dense`` / ``vlm`` / ``audio-as-decoder`` — GQA attention + SwiGLU (or
   block-sparse Segment) FFN, scanned over layers;
-* ``moe``    — GQA attention + Segment-dispatched MoE FFN;
+* ``moe``    — GQA or latent (MLA) attention + the expert-share MoE FFN
+  on the Segment grouped GEMM, after ``first_k_dense`` dense layers;
 * ``hybrid`` — RecurrentGemma: repeating (rec, rec, local-attention) units;
 * ``ssm``    — RWKV-6 time-mix/channel-mix;
 * ``enc_dec``— Whisper backbone: bidirectional encoder over frame embeddings
@@ -88,14 +89,24 @@ def _quantize_mlp_params(mlp, dtype: str):
 # ---------------------------------------------------------------------------
 
 
+def _attn_init(cfg: ModelConfig, key, dt):
+    if cfg.kv_lora_rank:
+        return layers.mla_init(
+            key, cfg.d_model, cfg.n_heads, kv_lora_rank=cfg.kv_lora_rank,
+            qk_nope_head_dim=cfg.qk_nope_head_dim,
+            qk_rope_head_dim=cfg.qk_rope_head_dim,
+            v_head_dim=cfg.v_head_dim, dtype=dt)
+    return layers.attention_init(key, cfg.d_model, cfg.n_heads, cfg.n_kv,
+                                 cfg.hd, qkv_bias=cfg.qkv_bias, dtype=dt)
+
+
 def _block_init(cfg: ModelConfig, key, kind: str, sparse_mlp: Optional[SparseMLP]):
     dt = jnp.float32
     d = cfg.d_model
     p: Dict[str, Any] = {"norm1": layers.rmsnorm_init(d), "norm2": layers.rmsnorm_init(d)}
     k1, k2 = jax.random.split(key)
     if kind in ("attn", "attn_bidir", "local", "cross"):
-        p["attn"] = layers.attention_init(k1, d, cfg.n_heads, cfg.n_kv, cfg.hd,
-                                          qkv_bias=cfg.qkv_bias, dtype=dt)
+        p["attn"] = _attn_init(cfg, k1, dt)
         if kind == "cross":
             p["norm_x"] = layers.rmsnorm_init(d)
             p["xattn"] = layers.attention_init(
@@ -106,9 +117,11 @@ def _block_init(cfg: ModelConfig, key, kind: str, sparse_mlp: Optional[SparseMLP
         else:
             p["mlp"] = layers.swiglu_init(k2, d, cfg.d_ff, dtype=dt)
     elif kind == "moe":
-        p["attn"] = layers.attention_init(k1, d, cfg.n_heads, cfg.n_kv, cfg.hd,
-                                          qkv_bias=cfg.qkv_bias, dtype=dt)
-        p["moe"] = moe.moe_init(k2, d, cfg.d_ff, cfg.n_experts, dtype=dt)
+        p["attn"] = _attn_init(cfg, k1, dt)
+        p["moe"] = moe.moe_init(
+            k2, d, cfg.expert_d_ff, cfg.n_experts, n_held=cfg.n_held,
+            shared_ff=cfg.n_shared_experts * cfg.expert_d_ff,
+            score=cfg.moe_score, dtype=dt)
     elif kind == "rec":
         p["rec"] = recurrent.rglru_block_init(k1, d, dtype=dt)
         p["mlp"] = layers.swiglu_init(k2, d, cfg.d_ff, dtype=dt)
@@ -121,31 +134,51 @@ def _block_init(cfg: ModelConfig, key, kind: str, sparse_mlp: Optional[SparseMLP
     return p
 
 
+def _self_attention(cfg: ModelConfig, p, x, kind: str, *, positions,
+                    cache, layer, cache_pos):
+    """The block's self-attention sublayer: (h, new kv cache or None)."""
+    kv_cache = cache.get("kv") if cache else None
+    with jax.named_scope("attn"):
+        a = layers.rmsnorm_apply(p["norm1"], x, cfg.norm_eps)
+        if cfg.kv_lora_rank:
+            return layers.mla_apply(
+                p["attn"], a, n_heads=cfg.n_heads,
+                qk_nope_head_dim=cfg.qk_nope_head_dim,
+                qk_rope_head_dim=cfg.qk_rope_head_dim,
+                v_head_dim=cfg.v_head_dim, positions=positions,
+                rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps,
+                cache=kv_cache, cache_layer=layer, cache_pos=cache_pos,
+                chunk=cfg.attn_chunk)
+        return layers.attention_apply(
+            p["attn"], a, n_heads=cfg.n_heads, n_kv=cfg.n_kv,
+            head_dim=cfg.hd, positions=positions,
+            causal=(kind != "attn_bidir"),
+            window=cfg.local_window if kind == "local" else None,
+            rope_theta=cfg.rope_theta, cache=kv_cache, cache_layer=layer,
+            cache_pos=cache_pos, chunk=cfg.attn_chunk,
+            ring=(kind == "local" and cache is not None))
+
+
 def _block_apply(cfg: ModelConfig, p, x, kind: str, *, positions,
                  sparse_mlp: Optional[SparseMLP], enc_out=None,
                  cache=None, layer=None, cache_pos=None):
-    """Returns (x, aux_loss, new_cache).
+    """Returns (x, aux_loss, new_cache, counts).
 
     ``cache`` is the block's layer-stacked decode cache and ``layer`` the
     block's index in it; ``new_cache`` is the stack with this layer's
-    tokens (KV caches) or state (recurrent blocks) written in."""
+    tokens (KV caches) or state (recurrent blocks) written in.  ``counts``
+    is the MoE layer's work (:data:`repro.models.moe.COUNTERS`), zero for
+    other blocks."""
     if cfg.seq_shard and cache is None:
         x = act_constrain(x, "seq")
     aux = jnp.zeros((), jnp.float32)
+    counts = jnp.zeros((len(moe.COUNTERS),), jnp.int32)
     new_cache: Dict[str, Any] = {}
     # the named scopes ("attn", "ffn", "lm_head" in layers.py) are op
     # metadata only: the profiler's op-profile view groups device ops by them
     if kind in ("attn", "attn_bidir", "local", "cross"):
-        window = cfg.local_window if kind == "local" else None
-        with jax.named_scope("attn"):
-            h, kv = layers.attention_apply(
-                p["attn"], layers.rmsnorm_apply(p["norm1"], x, cfg.norm_eps),
-                n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.hd,
-                positions=positions, causal=(kind != "attn_bidir"),
-                window=window, rope_theta=cfg.rope_theta,
-                cache=cache.get("kv") if cache else None, cache_layer=layer,
-                cache_pos=cache_pos, chunk=cfg.attn_chunk,
-                ring=(kind == "local" and cache is not None))
+        h, kv = _self_attention(cfg, p, x, kind, positions=positions,
+                                cache=cache, layer=layer, cache_pos=cache_pos)
         x = x + h
         if kv is not None:
             new_cache["kv"] = kv
@@ -165,20 +198,17 @@ def _block_apply(cfg: ModelConfig, p, x, kind: str, *, positions,
             else:
                 x = x + layers.swiglu_apply(p["mlp"], n2)
     elif kind == "moe":
-        with jax.named_scope("attn"):
-            h, kv = layers.attention_apply(
-                p["attn"], layers.rmsnorm_apply(p["norm1"], x, cfg.norm_eps),
-                n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.hd,
-                positions=positions, causal=True, rope_theta=cfg.rope_theta,
-                cache=cache.get("kv") if cache else None, cache_layer=layer,
-                cache_pos=cache_pos, chunk=cfg.attn_chunk)
+        h, kv = _self_attention(cfg, p, x, kind, positions=positions,
+                                cache=cache, layer=layer, cache_pos=cache_pos)
         x = x + h
         if kv is not None:
             new_cache["kv"] = kv
         with jax.named_scope("ffn"):
-            h, aux = moe.moe_apply(
+            h, aux, counts = moe.moe_apply(
                 p["moe"], layers.rmsnorm_apply(p["norm2"], x, cfg.norm_eps),
-                top_k=cfg.top_k, capacity_factor=cfg.moe_capacity_factor)
+                layer=layer, top_k=cfg.top_k, score=cfg.moe_score,
+                route_scale=cfg.moe_route_scale,
+                first_expert=cfg.first_expert)
         x = x + h
     elif kind == "rec":
         h, st = recurrent.rglru_block_apply(
@@ -207,7 +237,34 @@ def _block_apply(cfg: ModelConfig, p, x, kind: str, *, positions,
                              else st)
     else:
         raise ValueError(kind)
-    return x, aux, new_cache
+    return x, aux, new_cache, counts
+
+
+def _split_experts(tree):
+    """(the tree without the MoE expert weights, those weights): the
+    grouped GEMM reads the layer-stacked experts whole, by layer, so the
+    layer scan does not slice them."""
+    if not isinstance(tree, dict):
+        return tree, {}
+    scanned, whole = {}, {}
+    for k, v in tree.items():
+        if k == "moe":
+            scanned[k] = {n: a for n, a in v.items()
+                          if n not in moe.EXPERT_WEIGHTS}
+            whole[k] = {n: v[n] for n in moe.EXPERT_WEIGHTS}
+        else:
+            scanned[k], w = _split_experts(v)
+            if w:
+                whole[k] = w
+    return scanned, whole
+
+
+def _merge(tree, extra):
+    if not extra:
+        return tree
+    return {k: _merge(v, extra.get(k)) if isinstance(v, dict) else v
+            for k, v in tree.items()} | {k: v for k, v in extra.items()
+                                         if k not in tree}
 
 
 def _state_read(stack, layer):
@@ -229,8 +286,13 @@ def _block_cache_init(cfg: ModelConfig, kind: str, b: int, t_max: int, dt):
     """One layer's decode cache.  K and V are (B, T, n_kv·hd): heads and
     head dim merged into one minor axis, so the token write and the
     attention read share a layout with no padding of hd to the lane
-    width."""
+    width.  Latent attention caches one stream instead, shared by every
+    head: ``[RMSNorm(c_kv), RoPE(k_rope)]`` zero-padded to whole lanes
+    (:func:`layers.latent_width`)."""
     def kv(t_len):
+        if cfg.kv_lora_rank:
+            return {"latent": jnp.zeros((b, t_len, layers.latent_width(
+                cfg.kv_lora_rank, cfg.qk_rope_head_dim)), dt)}
         f = cfg.n_kv * cfg.hd
         if cfg.kv_cache_dtype == "int8":
             return {"k": jnp.zeros((b, t_len, f), jnp.int8),
@@ -275,6 +337,12 @@ class Transformer:
             self.groups = [("units", tuple(cfg.layer_pattern), n_units)]
             if rem:
                 self.groups.append(("tail", tuple(cfg.layer_pattern[:rem]), 1))
+        elif cfg.first_k_dense:
+            # leading dense layers, then the rest (DeepSeek-V3's
+            # first_k_dense_replace): one scan each
+            self.groups = [("dense", "attn", cfg.first_k_dense),
+                           ("layers", cfg.layer_kind(cfg.first_k_dense),
+                            cfg.n_layers - cfg.first_k_dense)]
         else:
             kind = cfg.layer_kind(0)
             self.groups = [("layers", kind, cfg.n_layers)]
@@ -359,13 +427,17 @@ class Transformer:
         prefill) the layer-stacked cache rides in the scan's carry: each
         layer writes its new tokens (or its recurrent state) into the
         stack and reads its own slice back, so a donated cache is updated
-        in place and no second stack is built.  Returns (x, aux, caches).
+        in place and no second stack is built.  Returns (x, aux, caches,
+        counts), ``counts`` the MoE work summed over the layers.
         """
         cfg = self.cfg
 
+        params_g, experts = _split_experts(params_g)
+
         def body(carry, inp):
-            x, aux, cache = carry
+            x, aux, counts, cache = carry
             p_l, layer = inp
+            p_l = _merge(p_l, experts)
             if isinstance(kinds, tuple):
                 blocks = [(p_l[f"b{j}"], kd, f"b{j}")
                           for j, kd in enumerate(kinds)]
@@ -373,30 +445,32 @@ class Transformer:
                 blocks = [(p_l, kinds, None)]
             for p_b, kd, sub in blocks:
                 c_b = cache if cache is None or sub is None else cache[sub]
-                x, a, c_b = _block_apply(
+                x, a, c_b, n = _block_apply(
                     cfg, p_b, x, kd, positions=positions,
                     sparse_mlp=self.sparse_mlp, enc_out=enc_out,
                     cache=c_b, layer=layer, cache_pos=cache_pos)
                 if cache is not None:
                     cache = c_b if sub is None else {**cache, sub: c_b}
                 aux = aux + a
-            return (x, aux, cache), None
+                counts = counts + n
+            return (x, aux, counts, cache), None
 
         body_fn = body
         if cfg.remat and caches is None:
             body_fn = jax.checkpoint(body, prevent_cse=False)
         n = jax.tree.leaves(params_g)[0].shape[0]
-        xs = (params_g, None if caches is None else jnp.arange(n))
+        xs = (params_g, jnp.arange(n))
         # NOTE (decode on CPU backend): XLA's bf16-dot emulation hoists f32
         # converts of the per-layer KV-cache slices out of this scan and
         # carries full f32 cache copies in the while tuple. This is a
         # CPU-only artifact (TPU bf16 dots are native); the dry-run measures
         # and subtracts it — see launch/dryrun.py `cpu_artifact_bytes`.
         # the body is traced once and runs once per layer
+        counts = jnp.zeros((len(moe.COUNTERS),), jnp.int32)
         with obs.repeated(n):
-            (x, aux, caches), _ = jax.lax.scan(
-                body_fn, (x, jnp.zeros((), jnp.float32), caches), xs)
-        return x, aux, caches
+            (x, aux, counts, caches), _ = jax.lax.scan(
+                body_fn, (x, jnp.zeros((), jnp.float32), counts, caches), xs)
+        return x, aux, caches, counts
 
     # -- forward (train / prefill logits) -------------------------------------
     def forward(self, params, tokens, vis_embeds=None, enc_embeds=None):
@@ -417,16 +491,17 @@ class Transformer:
         if cfg.family == "enc_dec":
             e = layers.dense_apply(params["frontend"], enc_embeds.astype(dt))
             ep = jnp.broadcast_to(jnp.arange(e.shape[1]), (b, e.shape[1]))
-            enc_out, aux, _ = self._run_group(
+            enc_out, aux, _, _ = self._run_group(
                 params["enc"], e, "attn_bidir", positions=ep)
             aux_total += aux
-            x, aux, _ = self._run_group(params["dec"], x, "cross",
-                                        positions=positions, enc_out=enc_out)
+            x, aux, _, _ = self._run_group(params["dec"], x, "cross",
+                                           positions=positions,
+                                           enc_out=enc_out)
             aux_total += aux
         else:
             for (name, kinds, n) in self.groups:
-                x, aux, _ = self._run_group(params[name], x, kinds,
-                                            positions=positions)
+                x, aux, _, _ = self._run_group(params[name], x, kinds,
+                                               positions=positions)
                 aux_total += aux
         x = layers.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
         head = params.get("lm_head", params["embed"])
@@ -462,8 +537,19 @@ class Transformer:
         return {name: stack(kinds, n) for (name, kinds, n) in self.groups
                 if name != "enc"}
 
+    def has_moe(self) -> bool:
+        return any(k == "moe" for (_, kinds, _) in self.groups
+                   for k in (kinds if isinstance(kinds, tuple) else (kinds,)))
+
     def decode_step(self, params, cache, token, pos, enc_out=None, *,
                     logit_idx=None):
+        """:meth:`decode_step_counted` without its counts."""
+        logits, cache, _ = self.decode_step_counted(
+            params, cache, token, pos, enc_out, logit_idx=logit_idx)
+        return logits, cache
+
+    def decode_step_counted(self, params, cache, token, pos, enc_out=None, *,
+                            logit_idx=None):
         """token: (B, T) int32 (T=1 decode, T>1 chunked prefill); pos:
         absolute position of token[:, 0] — a shared scalar int32 (lockstep
         decode) or a per-row (B,) int32 vector (continuous batching: every
@@ -474,7 +560,9 @@ class Transformer:
         ``T-1`` (mixed-length chunked prefill: a row whose prompt ends
         mid-chunk must not sample its first token from padding).
 
-        Returns (logits (B, vocab), new_cache)."""
+        Returns (logits (B, vocab), new_cache, counts): ``counts`` maps
+        each of :data:`repro.models.moe.COUNTERS` to its int32 total over
+        the MoE layers (empty for a model without them)."""
         cfg = self.cfg
         dt = _dtype(cfg)
         x = layers.embedding_apply(params["embed"], token).astype(dt)
@@ -487,13 +575,15 @@ class Transformer:
             positions = pos[:, None] + jnp.arange(t)[None, :]
         positions = positions.astype(jnp.int32)
         new_cache = {}
+        counts = jnp.zeros((len(moe.COUNTERS),), jnp.int32)
         for (name, kinds, n) in self.groups:
             if name == "enc":
                 continue
-            x, _, nc = self._run_group(
+            x, _, nc, c = self._run_group(
                 params[name], x, kinds, positions=positions, enc_out=enc_out,
                 caches=cache[name], cache_pos=pos)
             new_cache[name] = nc
+            counts = counts + c
         x = layers.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
         # gather each row's output position *before* the lm_head so the
         # (B, T, vocab) prefill logits never materialize
@@ -504,4 +594,5 @@ class Transformer:
             x = jnp.take_along_axis(x, idx[:, None, None], axis=1)
         head = params.get("lm_head", params["embed"])
         logits = layers.lm_head_apply(head, x)
-        return logits[:, 0], new_cache
+        named = dict(zip(moe.COUNTERS, counts)) if self.has_moe() else {}
+        return logits[:, 0], new_cache, named

@@ -33,10 +33,9 @@ construction:
 
 Free slots ride along in the batched decode with ``pos=0`` and a dummy
 token; their writes land in rows that the next admission's fresh prefill
-resets/overwrites, and attention masking keeps them invisible.  (For MoE
-models the rows are not perfectly independent — expert capacity is
-batch-global — so batched MoE decode is faithful to *batched* MoE
-serving, not to one-request-at-a-time routing.)
+resets/overwrites, and attention masking keeps them invisible.  MoE rows
+are independent too: expert dispatch drops no token, so each row is
+computed as if alone.
 
 Kernel backend selection goes through :mod:`repro.api.backends`: an
 engine constructed with ``backend="interpret"`` (CPU correctness runs) or
@@ -50,7 +49,9 @@ profiler trace); the host work is in ``segfold.engine.*`` spans
 (:mod:`repro.obs`): ``admit`` per admitted request holding a ``prefill``
 per chunk and the ``first_token`` sync, and ``step`` per decode step
 holding ``prepare``, ``dispatch``, ``sync`` and ``update``.
-:meth:`Engine.counters` counts the work.
+:meth:`Engine.counters` counts the work; an MoE model's counts are
+device data that come back with the tokens each program returns, so they
+add no sync.
 """
 from __future__ import annotations
 
@@ -64,6 +65,7 @@ import numpy as np
 
 from repro import obs
 from repro.api.backends import resolve_backend, use_backend
+from repro.models.moe import COUNTERS as MOE_COUNTERS
 
 
 @dataclasses.dataclass
@@ -87,7 +89,8 @@ class _Slot:
 
 #: the names :meth:`Engine.counters` returns
 COUNTERS = ("decode_steps", "decode_rows", "prefill_chunks", "prefill_tokens",
-            "prefill_padded_tokens", "spmm_cols_useful", "spmm_cols_computed")
+            "prefill_padded_tokens", "spmm_cols_useful", "spmm_cols_computed"
+            ) + MOE_COUNTERS
 
 
 def _round_up(x: int, m: int) -> int:
@@ -161,6 +164,8 @@ class Engine:
         self.decode_traces = 0
         self.prefill_traces = 0
         self._counts = dict.fromkeys(COUNTERS, 0)
+        # the counts each program returns after its tokens
+        self._device_counts = MOE_COUNTERS if model.has_moe() else ()
         # per compiled program: the Segment SpMM calls its trace reported
         self._spmm_calls: Dict[tuple, List[obs.SpmmCall]] = {}
 
@@ -191,14 +196,26 @@ class Engine:
 
     # -- jitted step functions ----------------------------------------------
 
+    def _with_counts(self, logits, counts):
+        """The greedy tokens, then the program's device counts."""
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return jnp.concatenate(
+            [tok] + [counts[k][None] for k in self._device_counts])
+
+    def _add_counts(self, out: np.ndarray, n_tokens: int) -> None:
+        for k, v in zip(self._device_counts, out[n_tokens:]):
+            self._counts[k] += int(v)
+
     def _decode_fn(self, params, cache, tok, pos):
         """tok (S, 1), pos (S,) — one batched decode step at per-slot
-        positions; returns (greedy next token (S,), new cache)."""
+        positions; returns (greedy next token (S,) then the device counts,
+        new cache)."""
         self.decode_traces += 1
         with use_backend(self.backend), obs.spmm_columns() as calls:
-            logits, cache = self.model.decode_step(params, cache, tok, pos)
+            logits, cache, counts = self.model.decode_step_counted(
+                params, cache, tok, pos)
         self._spmm_calls[("decode",)] = calls
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+        return self._with_counts(logits, counts), cache
 
     def _prefill_fn(self, params, cache, slot, tok, pos, last_idx, fresh):
         """Prefill one chunk of one slot: slice the slot's cache row out,
@@ -214,14 +231,14 @@ class Engine:
         if fresh:
             row = jax.tree.map(jnp.zeros_like, row)
         with use_backend(self.backend), obs.spmm_columns() as calls:
-            logits, row = self.model.decode_step(params, row, tok, pos,
-                                                 logit_idx=last_idx)
+            logits, row, counts = self.model.decode_step_counted(
+                params, row, tok, pos, logit_idx=last_idx)
         self._spmm_calls[("prefill", tok.shape[1], fresh)] = calls
         cache = jax.tree.map(
             lambda full, r: jax.lax.dynamic_update_slice_in_dim(
                 full, r, slot, axis=1),
             cache, row)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+        return self._with_counts(logits, counts), cache
 
     # -- request lifecycle ---------------------------------------------------
 
@@ -271,26 +288,31 @@ class Engine:
         prompt = req.prompt
         length = int(prompt.shape[0])
         done = 0
-        tok_dev = None
+        outs = []
         with obs.span("engine.admit", rid=req.rid):
             for i, c in enumerate(self._chunk_schedule(length)):
                 with obs.span("engine.prefill"):
                     n = min(c, length - done)
                     buf = np.zeros((1, c), np.int32)
                     buf[0, :n] = prompt[done:done + n]
-                    tok_dev, self.cache = self._prefill(
+                    out, self.cache = self._prefill(
                         self.params, self.cache, jnp.int32(s),
                         jnp.asarray(buf), jnp.int32(done),
                         jnp.asarray([n - 1], jnp.int32), fresh=(i == 0))
+                    outs.append(out)
                 self._counts["prefill_chunks"] += 1
                 self._counts["prefill_tokens"] += n
                 self._counts["prefill_padded_tokens"] += c
                 self._count_spmm(("prefill", c, i == 0), n)
                 done += n
             # only the final chunk's token matters — one host sync per
-            # admission
+            # admission, which brings every chunk's counts along
             with obs.span("engine.first_token"):
-                tok = int(np.asarray(tok_dev)[0])
+                got = (jax.device_get(outs) if self._device_counts
+                       else [np.asarray(outs[-1])])
+                tok = int(got[-1][0])
+                for g in got:
+                    self._add_counts(g, 1)
         slot = _Slot(request=req, pos=length, last_tok=tok, out=[tok])
         self._slots[s] = slot
         if self._finished(slot):
@@ -340,6 +362,7 @@ class Engine:
             with obs.span("engine.sync"):
                 nxt = np.asarray(nxt)
             with obs.span("engine.update"):
+                self._add_counts(nxt, self.slots)
                 self._counts["decode_steps"] += 1
                 self._counts["decode_rows"] += len(live)
                 self._count_spmm(("decode",), len(live))
@@ -377,7 +400,11 @@ class Engine:
         prompt tokens and their bucket sizes; and the columns the Segment
         SpMM kernels computed against those that held a real row or token,
         summed over every SpMM call of every program run (N-tile padding,
-        free slots and a chunk's padding are the difference).  Read it
+        free slots and a chunk's padding are the difference); for an MoE
+        model, summed over its MoE layers and program runs, the rows routed
+        to held experts (free slots' and a chunk's padding tokens route
+        too), the rows the grouped GEMM computed (whole used chunks) and
+        the held experts with a route (expert weight loads).  Read it
         before and after a window and subtract."""
         return dict(self._counts)
 

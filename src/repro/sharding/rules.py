@@ -23,10 +23,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # parameter-name classes
 _COL_PARALLEL = {"up", "gate", "wq", "wk", "wv", "wg", "wr", "in_x", "in_g",
-                 "a_gate", "x_gate", "cm_k", "w_lora_a", "router"}
+                 "a_gate", "x_gate", "cm_k", "w_lora_a", "router",
+                 "wkv_a", "wkv_b"}
 _ROW_PARALLEL = {"down", "wo", "out", "cm_v", "w_lora_b"}
 _REPLICATED = {"scale", "b", "a_param", "mix", "cm_mix", "u", "conv",
-               "w_bias"}
+               "w_bias", "score_bias"}
 
 
 def _path_names(path) -> Tuple[str, ...]:
@@ -60,8 +61,6 @@ def param_pspec(path, leaf, *, dp: str = "data", tp: str = "model") -> P:
         return _last2(ndim, dp, tp)
     if name == "w" and parent in _ROW_PARALLEL:
         return _last2(ndim, tp, dp)
-    if parent in ("moe",) or name in ("gate", "up", "down"):
-        pass
     if name in ("gate", "up") and ndim >= 3:   # (E, d, ff) expert weights
         return _expert(ndim, tp, dp)
     if name == "down" and ndim >= 3:           # (E, ff, d)
@@ -118,14 +117,17 @@ def cache_pspec(mesh: Mesh, path, leaf) -> P:
     command-r cache → 2.1 GB/chip); attention reductions over T psum across
     shards.  The int8 cache's scales ``k_s``/``v_s`` (layers, B, T, n_kv)
     shard T with it (otherwise every layer reshards them — §Perf C4).
-    RWKV state (layers, B, H, hd, hd) shards heads on the same rule.
+    Latent attention's ``latent`` (layers, B, T, r + dr) shards its
+    timeline alike.  RWKV state (layers, B, H, hd, hd) shards heads on the
+    same rule.
     ``path`` is the leaf's key path in the cache tree, as
     ``jax.tree_util.tree_map_with_path`` gives it.
     """
     dp = dp_axes(mesh)
     ndim = leaf.ndim
     names = _path_names(path)
-    timeline = bool(names) and names[-1] in ("k", "v", "k_s", "v_s")
+    timeline = bool(names) and names[-1] in ("k", "v", "k_s", "v_s",
+                                             "latent")
     if timeline or ndim >= 5:
         tp = "model" if (leaf.shape[2] % mesh.shape["model"] == 0) else None
         return P(None, dp, tp, *([None] * (ndim - 3)))
@@ -220,11 +222,6 @@ def act_constrain(x, kind: str):
         # sharded on model — softmax/PV reduce via psum instead of
         # resharding the whole cache slice every layer
         spec = P(dp, None, None, "model")
-    elif kind == "expert" and x.ndim == 4 and tp_ok(x.shape[1]):
-        # expert-parallel dispatch buffers: batch on dp, experts on model
-        spec = P(dp, "model", None, None)
-    elif kind == "expert" and x.ndim == 3 and tp_ok(x.shape[0]):
-        spec = P("model", None, None)
     else:
         spec = P(dp, *([None] * (x.ndim - 1)))
     return jax.lax.with_sharding_constraint(x, spec)

@@ -162,6 +162,122 @@ def test_engine_decode_updates_cache_in_place(one_chip, monkeypatch):
                        cfg.n_kv * cfg.hd)
 
 
+#: the expert-share configuration of the ``moonlight-ep8.offline`` cell
+MOONLIGHT = ROOT / "bench" / "configs" / "moonlight-16b-a3b-ep8.json"
+
+#: what one v5e chip's program may use (the compiler's own figure)
+V5E_HBM = 15.75 * 2 ** 30
+
+
+@pytest.fixture(scope="module")
+def moonlight_engine():
+    """``Engine`` at the cell's configuration (13 layers, 8 of 64 experts
+    held, 64 slots, max_len 1024, float32 weights), from shapes alone."""
+    import dataclasses
+    import json
+
+    from repro.launch.serve import serving_config
+    from repro.models import build_model
+    from repro.runtime.serve import Engine
+
+    served = json.loads(MOONLIGHT.read_text())
+    cfg = dataclasses.replace(serving_config(served["registry"]),
+                              n_layers=served["num_hidden_layers"],
+                              experts_held=served["n_routed_experts"])
+    assert (cfg.d_model, cfg.n_heads, cfg.d_ff, cfg.vocab, cfg.kv_lora_rank,
+            cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
+            cfg.n_experts, cfg.top_k, cfg.moe_d_ff, cfg.n_shared_experts,
+            cfg.first_k_dense, cfg.moe_route_scale) == (
+        served["hidden_size"], served["num_attention_heads"],
+        served["intermediate_size"], served["vocab_size"],
+        served["kv_lora_rank"], served["qk_nope_head_dim"],
+        served["qk_rope_head_dim"], served["v_head_dim"],
+        served["published"]["n_routed_experts"],
+        served["num_experts_per_tok"], served["moe_intermediate_size"],
+        served["n_shared_experts"], served["first_k_dense_replace"],
+        served["routed_scaling_factor"])
+    model = build_model(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    init_cache = model.init_cache
+    model.init_cache = lambda b, t: jax.eval_shape(lambda: init_cache(b, t))
+    engine = Engine(model, params, slots=served["slots"],
+                    max_len=served["max_len"], backend="pallas")
+    return engine, params, served
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill-64", "prefill-16"])
+def test_moonlight_cell_compiles_for_v5e(program, moonlight_engine, one_chip):
+    """The cell's decode program and both prefill buckets compile for a
+    described v5e beside the float32 weights and the latent cache; the
+    held experts run the grouped GEMM kernel in bf16; decode copies no
+    whole latent cache and reads it as stored (no per-head K or V of the
+    cache is made)."""
+    engine, params, served = moonlight_engine
+    slots, i32 = served["slots"], np.int32
+    args = [_struct(params, one_chip), _struct(engine.cache, one_chip)]
+    if program == "decode":
+        compiled = engine._decode.lower(
+            *args, jax.ShapeDtypeStruct((slots, 1), i32, sharding=one_chip),
+            jax.ShapeDtypeStruct((slots,), i32, sharding=one_chip)).compile()
+    else:
+        c = int(program.split("-")[1])
+        scalar = jax.ShapeDtypeStruct((), i32, sharding=one_chip)
+        compiled = engine._prefill.lower(
+            *args, scalar, jax.ShapeDtypeStruct((1, c), i32,
+                                                sharding=one_chip),
+            scalar, jax.ShapeDtypeStruct((1,), i32, sharding=one_chip),
+            fresh=True).compile()
+    text = compiled.as_text()
+    # every grouped GEMM reads its float32 layer-stacked parameter as
+    # stored, and nothing converts, copies or slices the expert stack
+    moe = params["layers"]["moe"]
+    stacks = {k: moe[k].shape for k in ("gate", "up", "down")}
+    weights = re.findall(r"%moe_gemm_bfloat16(?:\.\d+)? = [^\n]*custom-call"
+                         r"[^\n]*operand_layout_constraints=\{[^\n]*?"
+                         r"(\w+)\[([\d,]+)\]\{[\d,]*\}\}", text)
+    assert len(weights) == 3, weights      # gate, up, down in the scan
+    assert {(dt, tuple(int(d) for d in dims.split(",")))
+            for dt, dims in weights} == {("f32", s) for s in stacks.values()}
+    shapes = set(stacks.values()) | {s[1:] for s in stacks.values()}
+    touched = [(name, dtype, dims, opcode) for name, dtype, dims, opcode in
+               _HLO_OP.findall(text)
+               if tuple(int(d) for d in dims.split(",") if d) in shapes
+               and (dtype != "f32" or opcode not in
+                    ("parameter", "get-tuple-element"))]
+    assert not touched, touched
+    # one 576-wide latent a token and layer, padded to whole lanes
+    latent = engine.cache["layers"]["kv"]["latent"]
+    assert latent.shape == (served["num_hidden_layers"] - 1, slots,
+                            served["max_len"], 640)
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(program, "argument/output/alias/temp bytes",
+          mem.argument_size_in_bytes, mem.output_size_in_bytes,
+          mem.alias_size_in_bytes, mem.temp_size_in_bytes)
+    assert total < V5E_HBM, total
+    if program == "decode":
+        # the cache rides in the scans in place, in the layout it is
+        # stored in (prefill writes its slot's row back in place)
+        stack = latent.size * latent.dtype.itemsize
+        moved = [(name, dims) for name, dtype, dims, opcode in
+                 _HLO_OP.findall(text)
+                 if any(w in name + " " + opcode for w in
+                        ("copy", "dynamic-slice", "dynamic-update-slice"))
+                 and _BYTES[dtype] * np.prod([int(d) for d in dims.split(",")
+                                              if d]) >= stack]
+        assert not moved, moved
+        # an expanded cache would be (slots, max_len, heads, >= 128)
+        t_max, heads = served["max_len"], served["num_attention_heads"]
+        expanded = []
+        for name, _, dims, _ in _HLO_OP.findall(text):
+            d = [int(x) for x in dims.split(",") if x]
+            if {t_max, heads} <= set(d) and np.prod(d) >= \
+                    slots * t_max * heads * served["v_head_dim"]:
+                expanded.append((name, dims))
+        assert not expanded, expanded
+
+
 # ---------------------------------------------------------------------------
 # CPU: what the compiled backend needs from the executor and the planner
 # ---------------------------------------------------------------------------

@@ -103,24 +103,37 @@ def test_rwkv_ref_state_continuity():
                                rtol=1e-4, atol=1e-5)
 
 
-def test_moe_apply_vs_dense_oracle():
-    t, dm, dff, e, topk = 128, 32, 64, 4, 2
-    x = RNG.standard_normal((t, dm)).astype(np.float32) * 0.3
-    wu = RNG.standard_normal((e, dm, dff)).astype(np.float32) * 0.1
-    wd = RNG.standard_normal((e, dff, dm)).astype(np.float32) * 0.1
-    logits = RNG.standard_normal((t, e)).astype(np.float32)
-    out = np.asarray(ops.moe_apply(
-        jnp.asarray(x), jnp.asarray(wu), jnp.asarray(wd), jnp.asarray(logits),
-        top_k=topk, chunk_rows=16, capacity_factor=8.0, interpret=True))
-    tv, ti = jax.lax.top_k(jnp.asarray(logits), topk)
-    g = np.asarray(jax.nn.softmax(tv, -1))
-    want = np.zeros((t, dm), np.float32)
-    for tok in range(t):
-        for j in range(topk):
-            ex = int(ti[tok, j])
-            want[tok] += g[tok, j] * np.asarray(
-                jax.nn.silu(x[tok] @ wu[ex]) @ wd[ex])
-    np.testing.assert_allclose(out, want, rtol=1e-3, atol=1e-4)
+def test_moe_gemm_padded_and_skipped_chunks_vs_ref():
+    """The drop-free layout: expert 1 overflows one chunk (3 chunks, the
+    last padded), expert 2 has no route, expert 3 one route, and two spare
+    chunks are skipped — against :func:`ref.moe_gemm_ref` over the same
+    layout, the skipped chunks' rows zero."""
+    from repro.kernels.moe_gemm import build_chunks, moe_gemm
+    rows, dm, dff, e = 8, 32, 64, 4
+    expert = jnp.asarray([1] * 19 + [0] * 5 + [3] + [4] * 3, jnp.int32)
+    ch = build_chunks(expert, e, rows)
+    assert ch.chunk_expert.shape == (28 // rows + e,)
+    assert int(ch.n_used[0]) == 1 + 3 + 1
+    assert np.asarray(ch.chunk_expert).tolist() == [0, 1, 1, 1, 3, 3, 3]
+    assert np.asarray(ch.counts).tolist() == [5, 19, 0, 1]
+    x = np.zeros((ch.n_rows, dm), np.float32)
+    d = np.asarray(ch.dest)
+    held = d < ch.n_rows
+    x[d[held]] = RNG.standard_normal((int(held.sum()), dm)) * 0.3
+    # two layers of experts, the second one used
+    w = (RNG.standard_normal((2, e, dm, dff)) * 0.1).astype(np.float32)
+    got = np.asarray(moe_gemm(jnp.asarray(x), jnp.asarray(w),
+                              ch.chunk_expert, ch.n_used,
+                              jnp.asarray([1], jnp.int32), chunk_rows=rows,
+                              interpret=True))
+    want = np.asarray(ref.moe_gemm_ref(jnp.asarray(x), jnp.asarray(w[1]),
+                                       ch.chunk_expert, rows))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    assert not got[5 * rows:].any()
+    # every route to a held expert has its own row, with its expert's block
+    assert len(set(d[held].tolist())) == int(held.sum())
+    assert (np.asarray(ch.chunk_expert)[d[held] // rows]
+            == np.asarray(expert)[held]).all()
 
 
 @settings(deadline=None, max_examples=15)

@@ -74,14 +74,14 @@ def test_arch_gradients(arch):
 
 
 #: one decoder per kind of decode cache: full bf16 K/V, the int8 K/V cache
-#: with its scales, MoE, the hybrid family's recurrent state beside its
-#: ``local`` ring buffer, and rwkv's recurrent state
+#: with its scales, MoE, MLA's latent cache beside a leading dense layer and
+#: the expert share with shared experts, the hybrid family's recurrent state
+#: beside its ``local`` ring buffer, and rwkv's recurrent state
 DECODE_CASES = {
     "granite-3-8b": ("granite-3-8b", {}),
     "int8-kv": ("granite-3-8b", {"kv_cache_dtype": "int8"}),
-    # capacity for every token (4 experts): batch-global expert capacity
-    # would otherwise drop different tokens in a decode step and a forward
-    "moe": ("phi3.5-moe-42b-a6.6b", {"moe_capacity_factor": 4.0}),
+    "moe": ("phi3.5-moe-42b-a6.6b", {}),
+    "moonlight": ("moonlight-16b-a3b", {"experts_held": 2}),
     "hybrid-ring": ("recurrentgemma-9b", {}),
     "rwkv": ("rwkv6-1.6b", {}),
 }

@@ -67,7 +67,9 @@ def test_engine_counters_match_a_hand_count(tiny):
         "prefill_padded_tokens": sum(chunks),
         "spmm_cols_useful": calls * (rows + 35),
         "spmm_cols_computed": calls * (steps * computed(2)
-                                       + sum(map(computed, chunks)))}
+                                       + sum(map(computed, chunks))),
+        # a model without MoE layers routes nothing
+        "moe_rows_routed": 0, "moe_rows_computed": 0, "moe_expert_loads": 0}
 
 
 def test_spmm_report_counts_the_padded_tile_and_repeats():
