@@ -220,13 +220,18 @@ def _reference_logits(cfg, params, seq, n_prompt: int):
 def phase_serving() -> None:
     import jax
     import jax.numpy as jnp
-    from repro.launch.serve import build_engine, serving_config
-    from repro.runtime import Request
+    from repro.launch.serve import serving_config
+    from repro.models import build_model
+    from repro.runtime import Engine, Request
 
     cfg = serving_config("phi3-mini-3.8b", sparse_ffn=True, ffn_block=BLOCK,
                          ffn_density=0.25)
     t0 = time.time()
-    engine = build_engine(cfg, backend="pallas", slots=4, max_len=1024)
+    model = build_model(cfg)
+    # the float32 weights the reference reads; the engine holds its own
+    # compute-dtype copy
+    params = model.init(jax.random.PRNGKey(0))
+    engine = Engine(model, params, slots=4, max_len=1024, backend="pallas")
     check(engine.backend == "pallas", engine.backend)
     mlp = engine.model.sparse_mlp
     plans = [lin.plan for lin in (mlp.up, mlp.gate, mlp.down)]
@@ -268,7 +273,7 @@ def phase_serving() -> None:
                                    dtype=np.int32))
     got = _cache_logits(engine.model, engine.params, seq, n_prompt,
                         engine.backend)
-    want = _reference_logits(cfg, engine.params, seq, n_prompt)
+    want = _reference_logits(cfg, params, seq, n_prompt)
     err = _rel_err(got, want)
     status = "ok" if err <= LOGIT_TOL else "FAIL"
     print(f"[serving] prefill {n_prompt} + 2 decode steps (bf16) vs fp32 "

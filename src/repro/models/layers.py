@@ -40,6 +40,21 @@ def dense_apply(p, x):
     return y
 
 
+def held_in(a, dt):
+    """``a`` held in dtype ``dt``: an array cast, or a
+    ``jax.ShapeDtypeStruct`` restated (shape trees build serving programs
+    from ``jax.eval_shape`` alone)."""
+    if isinstance(a, jax.ShapeDtypeStruct):
+        return jax.ShapeDtypeStruct(a.shape, dt, sharding=a.sharding)
+    return a.astype(dt)
+
+
+def dense_compute(p, dt):
+    """:func:`dense_apply`'s weights in the compute dtype ``dt``: it reads
+    ``w`` and ``b`` only cast to its input's dtype, which is ``dt``."""
+    return {k: held_in(v, dt) for k, v in p.items()}
+
+
 def sparse_dense_init(key, d_in, d_out, *, block=128, density=0.25,
                       policy="segment", dtype=jnp.float32):
     """Block-sparse drop-in for :func:`dense_init` via :mod:`repro.api`.
@@ -187,6 +202,12 @@ def attention_init(key, d_model, n_heads, n_kv, head_dim, *, qkv_bias=False,
         "wv": dense_init(k3, d_model, n_kv * head_dim, bias=qkv_bias, dtype=dtype),
         "wo": dense_init(k4, n_heads * head_dim, d_model, dtype=dtype),
     }
+
+
+def attention_compute(p, dt):
+    """:func:`attention_apply`'s weights in the compute dtype ``dt``: all
+    four projections are dense."""
+    return {k: dense_compute(v, dt) for k, v in p.items()}
 
 
 def _decode_mask(b, tq, tk, *, q_offset, kv_len, causal, window):
@@ -438,6 +459,15 @@ def mla_init(key, d_model, n_heads, *, kv_lora_rank, qk_nope_head_dim,
     }
 
 
+def mla_compute(p, dt):
+    """:func:`mla_apply`'s weights in the compute dtype ``dt``: the four
+    projections (``wkv_b`` the absorbed decode also reads cast to the
+    query's and the latent's dtype, both ``dt``); ``kv_norm`` is read in
+    float32 and stays as stored."""
+    return {k: v if k == "kv_norm" else dense_compute(v, dt)
+            for k, v in p.items()}
+
+
 def _mla_expanded(p, q, latent, *, n_heads, qk_nope_head_dim, v_head_dim,
                   q_offset, kv_len, chunk):
     """Causal attention with every head's K and V made from the latent
@@ -559,6 +589,12 @@ def swiglu_init(key, d_model, d_ff, dtype=jnp.float32):
     }
 
 
+def swiglu_compute(p, dt):
+    """:func:`swiglu_apply`'s weights in the compute dtype ``dt``: all
+    three projections are dense."""
+    return {k: dense_compute(v, dt) for k, v in p.items()}
+
+
 def swiglu_apply(p, x):
     from repro.sharding import act_constrain
     h = jax.nn.silu(act_constrain(dense_apply(p["gate"], x), "ffn")) \
@@ -577,6 +613,13 @@ def embedding_init(key, vocab, d_model, dtype=jnp.float32):
 
 def embedding_apply(p, tokens):
     return jnp.take(p["table"], tokens, axis=0)
+
+
+def embedding_compute(p, dt):
+    """The table in the compute dtype ``dt``: the embedding gathers rows
+    and casts them to ``dt``, and :func:`lm_head_apply` casts the table to
+    its input's dtype, so both read the same numbers from a cast table."""
+    return {"table": held_in(p["table"], dt)}
 
 
 def lm_head_apply(p, x):

@@ -68,6 +68,16 @@ def moe_init(key, d_model, d_ff, n_experts, *, n_held=None, shared_ff=0,
     return p
 
 
+def moe_compute(p, dt):
+    """:func:`moe_apply`'s weights in the compute dtype ``dt``: the shared
+    experts (a SwiGLU on the layer's input).  The router and its bias are
+    read in float32 and the held experts' stacks by the grouped GEMM as
+    stored, so they stay."""
+    if "shared" not in p:
+        return p
+    return {**p, "shared": layers.swiglu_compute(p["shared"], dt)}
+
+
 def route(p, x, *, top_k: int, score: str = "softmax",
           route_scale: float = 1.0):
     """x: (N, D) → (chosen experts (N, top_k) int32, their weights

@@ -34,6 +34,14 @@ def rglru_block_init(key, d_model, dtype=jnp.float32):
     }
 
 
+def rglru_block_compute(p, dt):
+    """:func:`rglru_block_apply`'s dense projections in the compute dtype
+    ``dt``; the conv taps and ``a_param`` stay as stored."""
+    return {k: layers.dense_compute(v, dt)
+            if k in ("in_x", "in_g", "a_gate", "x_gate", "out") else v
+            for k, v in p.items()}
+
+
 def _causal_conv(x, w, state=None):
     """Depthwise causal conv, width 4. x: (B,T,D), w: (4,D).
     state: (B, 3, D) trailing context for decode. Returns (y, new_state)."""
@@ -104,6 +112,15 @@ def rwkv_block_init(key, d_model, n_heads, d_ff, dtype=jnp.float32):
         "cm_k": layers.dense_init(ks[10], d, d_ff, dtype=dtype),
         "cm_v": layers.dense_init(ks[11], d_ff, d, dtype=dtype),
     }
+
+
+def rwkv_block_compute(p, dt):
+    """The RWKV-6 block's dense projections in the compute dtype ``dt``;
+    the token-shift mixes, the decay's bias and LoRA, ``u`` and the norm
+    stay as stored."""
+    return {k: layers.dense_compute(v, dt)
+            if k in ("wr", "wk", "wv", "wg", "wo", "cm_k", "cm_v") else v
+            for k, v in p.items()}
 
 
 def _token_shift(x, prev):

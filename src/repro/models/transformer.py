@@ -134,6 +134,28 @@ def _block_init(cfg: ModelConfig, key, kind: str, sparse_mlp: Optional[SparseMLP
     return p
 
 
+def _block_compute(cfg: ModelConfig, p, kind: str,
+                   sparse_mlp: Optional[SparseMLP], dt):
+    """One block's parameters as :meth:`Transformer.compute_params` holds
+    them: each sublayer's own declaration; norms stay as stored, and so
+    do the Segment FFN's blocks, which its kernels read."""
+    p = dict(p)
+    if "attn" in p:
+        p["attn"] = (layers.mla_compute if cfg.kv_lora_rank
+                     else layers.attention_compute)(p["attn"], dt)
+    if "xattn" in p:
+        p["xattn"] = layers.attention_compute(p["xattn"], dt)
+    if "mlp" in p and (kind == "rec" or sparse_mlp is None):
+        p["mlp"] = layers.swiglu_compute(p["mlp"], dt)
+    if "moe" in p:
+        p["moe"] = moe.moe_compute(p["moe"], dt)
+    if "rec" in p:
+        p["rec"] = recurrent.rglru_block_compute(p["rec"], dt)
+    if "rwkv" in p:
+        p["rwkv"] = recurrent.rwkv_block_compute(p["rwkv"], dt)
+    return p
+
+
 def _self_attention(cfg: ModelConfig, p, x, kind: str, *, positions,
                     cache, layer, cache_pos):
     """The block's self-attention sublayer: (h, new kv cache or None)."""
@@ -419,6 +441,39 @@ class Transformer:
                 new_g["mlp"] = _quantize_mlp_params(g["mlp"], dtype)
                 new_params[name] = new_g
         return model, new_params
+
+    def compute_params(self, params):
+        """The parameters as the serving programs hold them: each leaf that
+        :meth:`decode_step_counted` reads only cast to the compute dtype
+        (``ModelConfig.dtype``) is held in that dtype, so no program casts
+        it again; the rounding is the same, once.  That is the dense
+        projections of attention, MLA, SwiGLU (the shared experts too) and
+        the recurrent blocks, and the embedding and head tables.  Norm
+        scales, the router and its bias, the recurrent gates' own
+        parameters, the Segment FFN blocks, the held experts' stacks and
+        every quantized payload and scale stay as stored.  Each layer type
+        declares its own leaves beside its apply.  Leaves may be arrays or
+        ``jax.ShapeDtypeStruct``; with float32 compute the tree is returned
+        as it is.  Training reads the float32 tree."""
+        cfg = self.cfg
+        dt = _dtype(cfg)
+        if dt == jnp.float32:
+            return params
+        out = dict(params)
+        for name in ("embed", "lm_head"):
+            if name in params:
+                out[name] = layers.embedding_compute(params[name], dt)
+        if "frontend" in params:
+            out["frontend"] = layers.dense_compute(params["frontend"], dt)
+        for name, kinds, _ in self.groups:
+            g = params[name]
+            if isinstance(kinds, tuple):
+                out[name] = {f"b{j}": _block_compute(
+                    cfg, g[f"b{j}"], kd, self.sparse_mlp, dt)
+                    for j, kd in enumerate(kinds)}
+            else:
+                out[name] = _block_compute(cfg, g, kinds, self.sparse_mlp, dt)
+        return out
 
     # -- scanned stacks -------------------------------------------------------
     def _run_group(self, params_g, x, kinds, *, positions, enc_out=None,

@@ -48,10 +48,14 @@ Observability: the two jitted programs are named ``engine_decode`` and
 profiler trace); the host work is in ``segfold.engine.*`` spans
 (:mod:`repro.obs`): ``admit`` per admitted request holding a ``prefill``
 per chunk and the ``first_token`` sync, and ``step`` per decode step
-holding ``prepare``, ``dispatch``, ``sync`` and ``update``.
-:meth:`Engine.counters` counts the work; an MoE model's counts are
-device data that come back with the tokens each program returns, so they
-add no sync.
+holding ``prepare``, ``dispatch``, ``sync`` and ``update``, and
+``cast_weights`` once in construction.  :meth:`Engine.counters` counts
+the work; an MoE model's counts are device data that come back with the
+tokens each program returns, so they add no sync.
+
+Weights: the engine holds the tree the model's ``compute_params`` gives,
+every weight the programs read only in the compute dtype cast to it once
+at construction, and serves from that tree alone (``Engine.params``).
 """
 from __future__ import annotations
 
@@ -87,7 +91,7 @@ class _Slot:
     out: List[int] = dataclasses.field(default_factory=list)
 
 
-#: the names :meth:`Engine.counters` returns
+#: the work :meth:`Engine.counters` counts
 COUNTERS = ("decode_steps", "decode_rows", "prefill_chunks", "prefill_tokens",
             "prefill_padded_tokens", "spmm_cols_useful", "spmm_cols_computed"
             ) + MOE_COUNTERS
@@ -95,6 +99,11 @@ COUNTERS = ("decode_steps", "decode_rows", "prefill_chunks", "prefill_tokens",
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
+
+
+def _nbytes(a) -> int:
+    """Bytes of an array or a ``jax.ShapeDtypeStruct``."""
+    return int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
 
 
 class Engine:
@@ -124,9 +133,21 @@ class Engine:
             # plans (int8/fp8 payload + fp32-scale leaves) and every weight
             # fetch in the Segment kernels moves ~4x fewer bytes
             model, params = model.quantize(params, quantize)
+        # the programs read the weights in the compute dtype: cast them
+        # once here, before the cache is made, and keep only the cast tree
+        with obs.span("engine.cast_weights"):
+            held = model.compute_params(params)
+            jax.block_until_ready(held)
+        cast = jax.tree.leaves(jax.tree.map(
+            lambda a, h: _nbytes(h) if a.dtype != h.dtype else 0,
+            params, held))
+        del params
+        self.weights_cast_bytes = sum(cast)
+        self.weights_kept_bytes = sum(map(_nbytes, jax.tree.leaves(held))) \
+            - self.weights_cast_bytes
         self.quantize = quantize
         self.model = model
-        self.params = params
+        self.params = held
         self.slots = int(slots)
         self.max_len = int(max_len)
         self.backend = resolve_backend(backend)
@@ -405,8 +426,13 @@ class Engine:
         to held experts (free slots' and a chunk's padding tokens route
         too), the rows the grouped GEMM computed (whole used chunks) and
         the held experts with a route (expert weight loads).  Read it
-        before and after a window and subtract."""
-        return dict(self._counts)
+        before and after a window and subtract.  Two constants of the
+        build come with them: ``weights_cast_bytes``, the bytes of the
+        weights the model's ``compute_params`` cast to the compute
+        dtype, and ``weights_kept_bytes``, those of the weights held as
+        given."""
+        return dict(self._counts, weights_cast_bytes=self.weights_cast_bytes,
+                    weights_kept_bytes=self.weights_kept_bytes)
 
 
 class Server(Engine):

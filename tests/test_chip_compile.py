@@ -105,13 +105,12 @@ _BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1,
           "u8": 1, "pred": 1}
 
 
-def test_engine_decode_updates_cache_in_place(one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def phi3_decode(one_chip):
     """``Engine``'s decode program at the served phi3-mini configuration
     (32 layers, 7 slots, max_len 1024, block-sparse FFN on the Segment
-    kernels), the cache donated, compiled for a described v5e from shapes
-    alone: the cache rides in the layer scan's carry, so no copy, dynamic
-    slice or dynamic update outputs an array as large as one stacked K or V
-    cache, and the temporaries stay below one stacked K+V cache."""
+    kernels), built from shapes alone and compiled for a described v5e
+    over the weights as the engine holds them, the cache donated."""
     import json
 
     from repro.launch.serve import serving_config
@@ -130,16 +129,39 @@ def test_engine_decode_updates_cache_in_place(one_chip, monkeypatch):
     model = build_model(cfg)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     init_cache = model.init_cache
-    monkeypatch.setattr(model, "init_cache", lambda b, t: jax.eval_shape(
-        lambda: init_cache(b, t)))
+    model.init_cache = lambda b, t: jax.eval_shape(lambda: init_cache(b, t))
     engine = Engine(model, params, slots=served["slots"],
                     max_len=served["max_len"], backend="pallas")
     slots = served["slots"]
     compiled = engine._decode.lower(
-        _struct(params, one_chip), _struct(engine.cache, one_chip),
+        _struct(engine.params, one_chip), _struct(engine.cache, one_chip),
         jax.ShapeDtypeStruct((slots, 1), np.int32, sharding=one_chip),
         jax.ShapeDtypeStruct((slots,), np.int32, sharding=one_chip)).compile()
+    return cfg, served, engine, compiled
 
+
+#: a ``convert`` that reads a parameter (of the program or of a fusion):
+#: name, the dtype and shape it outputs
+_CONVERT_PARAM = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]"
+                            r"\S* convert\(%?param", re.M)
+
+
+def _converted(text, shapes):
+    """The program's ``convert`` ops to bf16 that read a stored array of
+    one of ``shapes``: a weight cast to the compute dtype in the program
+    (a fusion's float32 arithmetic on a bf16 operand is no such cast)."""
+    return [(name, dtype, dims) for name, dtype, dims in
+            _CONVERT_PARAM.findall(text) if dtype == "bf16"
+            and tuple(int(d) for d in dims.split(",") if d) in shapes]
+
+
+def test_engine_decode_updates_cache_in_place(phi3_decode):
+    """The phi3-mini cell's decode program: the cache rides in the layer
+    scan's carry, so no copy, dynamic slice or dynamic update outputs an
+    array as large as one stacked K or V cache, and the temporaries stay
+    below one stacked K+V cache."""
+    cfg, served, engine, compiled = phi3_decode
+    slots = served["slots"]
     k = engine.cache["layers"]["kv"]["k"]
     k_bytes = k.size * k.dtype.itemsize
     moved = []
@@ -160,6 +182,28 @@ def test_engine_decode_updates_cache_in_place(one_chip, monkeypatch):
     # one layout for the token write and the attention read
     assert k.shape == (cfg.n_layers, slots, served["max_len"],
                        cfg.n_kv * cfg.hd)
+
+
+def test_engine_decode_reads_weights_as_held(phi3_decode):
+    """The phi3-mini cell's decode program reads the attention projections
+    and the head in bf16 as the engine holds them: nothing converts a
+    projection stack, one layer of it or the head table, and the program
+    fits one v5e."""
+    _, _, engine, compiled = phi3_decode
+    attn = engine.params["layers"]["attn"]
+    stacks = {attn[k]["w"].shape for k in ("wq", "wk", "wv", "wo")}
+    head = engine.params.get("lm_head", engine.params["embed"])["table"]
+    assert {attn[k]["w"].dtype for k in attn} == {head.dtype} \
+        == {np.dtype(jax.numpy.bfloat16)}
+    shapes = stacks | {s[1:] for s in stacks} | {head.shape}
+    assert not _converted(compiled.as_text(), shapes)
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print("decode argument/output/alias/temp bytes",
+          mem.argument_size_in_bytes, mem.output_size_in_bytes,
+          mem.alias_size_in_bytes, mem.temp_size_in_bytes)
+    assert total < V5E_HBM, total
 
 
 #: the expert-share configuration of the ``moonlight-ep8.offline`` cell
@@ -208,13 +252,15 @@ def moonlight_engine():
 @pytest.mark.parametrize("program", ["decode", "prefill-64", "prefill-16"])
 def test_moonlight_cell_compiles_for_v5e(program, moonlight_engine, one_chip):
     """The cell's decode program and both prefill buckets compile for a
-    described v5e beside the float32 weights and the latent cache; the
-    held experts run the grouped GEMM kernel in bf16; decode copies no
+    described v5e beside the weights as the engine holds them and the
+    latent cache; the held experts run the grouped GEMM kernel in bf16 over
+    their float32 stacks, and no other weight is converted; decode copies no
     whole latent cache and reads it as stored (no per-head K or V of the
     cache is made)."""
     engine, params, served = moonlight_engine
     slots, i32 = served["slots"], np.int32
-    args = [_struct(params, one_chip), _struct(engine.cache, one_chip)]
+    args = [_struct(engine.params, one_chip),
+            _struct(engine.cache, one_chip)]
     if program == "decode":
         compiled = engine._decode.lower(
             *args, jax.ShapeDtypeStruct((slots, 1), i32, sharding=one_chip),
@@ -245,6 +291,13 @@ def test_moonlight_cell_compiles_for_v5e(program, moonlight_engine, one_chip):
                and (dtype != "f32" or opcode not in
                     ("parameter", "get-tuple-element"))]
     assert not touched, touched
+    # nothing converts a weight the engine holds in bf16 (MLA, the dense
+    # layer, the shared experts, the head), whole or one layer of it
+    held = [a.shape for a in jax.tree.leaves(engine.params)
+            if a.dtype == jax.numpy.bfloat16]
+    assert len(held) > 10, held
+    assert not _converted(text, set(held) | {s[1:] for s in held
+                                             if len(s) == 3})
     # one 576-wide latent a token and layer, padded to whole lanes
     latent = engine.cache["layers"]["kv"]["latent"]
     assert latent.shape == (served["num_hidden_layers"] - 1, slots,

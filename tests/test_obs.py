@@ -16,6 +16,7 @@ from repro.configs import REGISTRY, reduced_config
 from repro.core.formats import BSR
 from repro.models import build_model
 from repro.runtime import Engine, Request
+from repro.runtime.serve import COUNTERS
 
 BENCH = Path(__file__).resolve().parents[1] / "bench"
 
@@ -45,9 +46,12 @@ def _requests(cfg, seed=0):
 
 
 def test_engine_counters_match_a_hand_count(tiny):
-    cfg = tiny[0]
+    cfg, _, params = tiny
     eng = _engine(tiny)
-    assert set(eng.counters().values()) == {0}
+    # float32 compute: the engine holds the weights as given
+    weights = {"weights_cast_bytes": 0, "weights_kept_bytes": sum(
+        a.nbytes for a in jax.tree.leaves(params))}
+    assert eng.counters() == dict.fromkeys(COUNTERS, 0) | weights
     eng.generate(_requests(cfg))
     # chunks: 5 -> [8]; 21 -> [16, 8]; 9 -> [8, 8]
     chunks = [8, 16, 8, 8, 8]
@@ -69,7 +73,8 @@ def test_engine_counters_match_a_hand_count(tiny):
         "spmm_cols_computed": calls * (steps * computed(2)
                                        + sum(map(computed, chunks))),
         # a model without MoE layers routes nothing
-        "moe_rows_routed": 0, "moe_rows_computed": 0, "moe_expert_loads": 0}
+        "moe_rows_routed": 0, "moe_rows_computed": 0, "moe_expert_loads": 0,
+        **weights}
 
 
 def test_spmm_report_counts_the_padded_tile_and_repeats():
@@ -117,6 +122,7 @@ def test_spans_nest_in_a_cpu_profiler_trace(tiny, tmp_path):
     try:
         eng.generate(_requests(cfg))
         api.execute_plan(plan).block_until_ready()
+        _engine(tiny)
     finally:
         jax.profiler.stop_trace()
     after = eng.counters()
@@ -148,6 +154,9 @@ def test_spans_nest_in_a_cpu_profiler_trace(tiny, tmp_path):
         assert not inside(step, admits)
     (execute,), (launch,) = named("execute"), named("execute.launch")
     assert inside(launch, [execute])
+    # the engine built inside the trace cast its weights once
+    (cast,) = named("engine.cast_weights")
+    assert not inside(cast, admits + steps)
 
 
 def test_engine_programs_are_named(tiny):

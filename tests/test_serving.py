@@ -12,6 +12,7 @@ generating each request alone.
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -25,7 +26,7 @@ KEY = jax.random.PRNGKey(0)
 def _setup(arch="granite-3-8b", **over):
     # f32 so greedy argmax is bitwise batch-size invariant on CPU
     cfg = dataclasses.replace(reduced_config(REGISTRY[arch]),
-                              dtype="float32", **over)
+                              **{"dtype": "float32", **over})
     model = build_model(cfg)
     return cfg, model, model.init(KEY)
 
@@ -344,3 +345,114 @@ def test_int8_kv_long_query_raises_named_error():
     with pytest.raises(ValueError, match="decode-sized"):
         model.decode_step(params, cache, jnp.zeros((1, 16), jnp.int32),
                           jnp.int32(0))
+
+
+# ---------------------------------------------------------------------------
+# the engine's weights in the compute dtype
+# ---------------------------------------------------------------------------
+
+#: decoder families the engine serves (enc_dec is refused above)
+DECODERS = [a for a in REGISTRY if REGISTRY[a].family != "enc_dec"]
+
+#: keys of the leaves the serving programs read as stored (norm scales,
+#: router, bias, recurrent gate parameters, Segment blocks and scales)
+STORED = {"scale", "router", "score_bias", "a_param", "conv", "mix",
+          "cm_mix", "w_bias", "w_lora_a", "w_lora_b", "u", "blocks",
+          "scales"}
+
+
+def _stays_as_stored(path) -> bool:
+    keys = [str(getattr(k, "key", k)) for k in path]
+    # the held experts' layer-stacked gate/up/down, which the grouped GEMM
+    # reads where they lie
+    return bool(STORED & set(keys)) or (len(keys) > 1 and keys[-2] == "moe")
+
+
+def _direct_greedy(model, params, prompt, chunk, new, cache_len):
+    """Greedy tokens and the logits of each program, straight through
+    ``decode_step`` as the engine runs it for one slot: the prompt in
+    chunks of ``chunk`` at a shared position, then one token at a time at
+    a per-row position."""
+    step = jax.jit(model.decode_step)
+    cache = model.init_cache(1, cache_len)
+    for i in range(0, prompt.size, chunk):
+        logits, cache = step(params, cache, prompt[None, i:i + chunk],
+                             np.int32(i))
+    toks, inputs = [int(np.argmax(logits[0]))], []
+    for j in range(new - 1):
+        inputs.append((cache, np.asarray([[toks[-1]]], np.int32),
+                       np.asarray([prompt.size + j], np.int32)))
+        logits, cache = step(params, *inputs[-1])
+        toks.append(int(np.argmax(logits[0])))
+    return toks, step, inputs[-1]
+
+
+@pytest.mark.parametrize("arch", DECODERS)
+def test_engine_serves_compute_dtype_weights_as_the_float32_tree(arch):
+    """An engine built on float32 weights holds each weight its programs
+    read only in the compute dtype (bf16 here) cast to it, once: its
+    greedy tokens equal a direct run of the model on the float32 tree,
+    the last step's logits from the held tree equal those from the float32
+    tree, and every leaf read as stored keeps its float32."""
+    cfg = reduced_config(REGISTRY[arch])
+    assert cfg.dtype == "bfloat16"
+    model = build_model(cfg)
+    params = model.init(KEY)
+    prompt = _prompts(cfg, (8,), seed=21)[0]
+    eng = Engine(model, params, slots=1, max_len=32, prefill_buckets=(8,))
+    r = Request(prompt=prompt.copy(), max_new_tokens=4)
+    eng.generate([r])
+
+    toks, step, last = _direct_greedy(model, params, prompt,
+                                      eng.prefill_buckets[0], 4,
+                                      eng._cache_len)
+    np.testing.assert_array_equal(r.out_tokens, toks)
+    want, _ = step(params, *last)
+    got, _ = step(eng.params, *last)
+    gap = float(np.max(np.abs(np.asarray(got, np.float32)
+                              - np.asarray(want, np.float32))))
+    print(arch, "largest logit difference", gap)
+    assert gap <= 1e-6, gap
+
+    held = jax.tree_util.tree_flatten_with_path(eng.params)[0]
+    given = jax.tree.leaves(params)
+    want_dtypes = [np.float32 if _stays_as_stored(path) else jnp.bfloat16
+                   for path, _ in held]
+    assert [a.dtype for _, a in held] == want_dtypes, [
+        (jax.tree_util.keystr(p), a.dtype) for p, a in held]
+    assert all(a.dtype == np.float32 for a in given)
+    c = eng.counters()
+    assert c["weights_cast_bytes"] == sum(
+        a.nbytes for _, a in held if a.dtype == jnp.bfloat16) > 0
+    assert c["weights_kept_bytes"] == sum(
+        a.nbytes for _, a in held if a.dtype == np.float32) > 0
+
+
+@pytest.mark.parametrize("mode", ["int8", "fp8"])
+def test_quantized_engine_holds_compute_dtype_weights(mode):
+    """A quantizing engine at bf16 compute casts the dense weights after it
+    quantizes the Segment FFN: the payloads and their float32 scales stay
+    as quantized, and it serves."""
+    cfg, model, params = _sparse_setup(dtype="bfloat16")
+    eng = Engine(model, params, slots=2, max_len=64, prefill_buckets=(16, 8),
+                 quantize=mode)
+    reqs = [Request(prompt=p, max_new_tokens=3)
+            for p in _prompts(cfg, (5, 19), seed=22)]
+    eng.generate(reqs)
+    assert all(r.out_tokens.shape == (3,) for r in reqs)
+    mlp = eng.params["layers"]["mlp"]["up"]
+    assert mlp["blocks"].dtype == {"int8": np.int8,
+                                   "fp8": jnp.float8_e4m3fn}[mode]
+    assert mlp["scales"].dtype == np.float32
+    assert eng.params["layers"]["attn"]["wq"]["w"].dtype == jnp.bfloat16
+    assert eng.params["embed"]["table"].dtype == jnp.bfloat16
+
+
+def test_float32_engine_holds_the_tree_as_given():
+    cfg, model, params = _setup()
+    eng = Engine(model, params, slots=1, max_len=64)
+    assert eng.params is params
+    c = eng.counters()
+    assert c["weights_cast_bytes"] == 0
+    assert c["weights_kept_bytes"] == sum(a.nbytes
+                                          for a in jax.tree.leaves(params))
