@@ -16,7 +16,7 @@ fp32/int8/fp8 block storage and reports traffic-bytes ratios vs fp32 plus
 normalized max error vs the dense fp32 oracle (CI gates both).  The
 pipeline sweep checks the DMA-pipeline fetch contract (modeled fetch count
 == schedule fetch-flag count, exactly, both kernels) and tracks interpret
-wall time vs the non-pipelined baseline.  The prefetch sweep runs the lane
+wall time.  The prefetch sweep runs the lane
 case with ``prefetch="cross_pass"`` across a two-N-tile grid and gates the
 mode end to end: bit-exact parity vs the drained schedule, the traffic
 model's ``prefetch_fetches`` against an independent head-window fetch-flag
@@ -39,7 +39,6 @@ from repro import api
 from repro.analysis import (analyze_callable, check_scale_agreement,
                             plan_vmem_bytes, verify_plan)
 from repro.core.formats import BSR
-from repro.kernels.segment_spmm import segment_spmm
 
 from .common import Csv
 
@@ -179,7 +178,7 @@ def quant_sweep() -> dict:
 
 
 def pipeline_sweep(repeats: int = 12) -> dict:
-    """DMA-pipeline contract + wall time vs the non-pipelined baseline.
+    """DMA-pipeline contract + interpret wall time.
 
     Three gates ride this section in CI:
 
@@ -195,12 +194,10 @@ def pipeline_sweep(repeats: int = 12) -> dict:
       amortized wall-time cost of ``plan_matmul(..., verify="full")`` over
       a cache-miss build plus warm realizes of this case's plan, gated
       < 10% (verification runs once per cached template);
-    * **wall time** — interpret-mode medians for the pipelined executor
-      path vs the legacy BlockSpec auto-pipeline (``pipeline=False``).
+    * **wall time** — interpret-mode median and min for the executor path.
       Interpret mode *emulates* every DMA and semaphore op sequentially, so
-      the pipelined path pays emulation overhead and the overlap win needs
-      real hardware — the ratio is tracked to catch pathological blowups,
-      not as a speedup claim.
+      the overlap win needs real hardware — the time is tracked for
+      trending, not as a speedup claim.
     """
     rng = np.random.default_rng(2)
     a = _balanced_bsr(rng)
@@ -235,11 +232,9 @@ def pipeline_sweep(repeats: int = 12) -> dict:
     out["verify_findings"] = len(findings)
     out["verify_finding_ids"] = sorted({f.invariant for f in findings})
 
-    # analyzer VMEM budgets for the three kernel instances this sweep
-    # exercises (pipelined / legacy SpMM at this bn, pipelined SpGEMM)
+    # analyzer VMEM budgets for the two kernel instances this sweep
+    # exercises (SpMM at this bn, SpGEMM)
     out["vmem_bytes_pipelined"] = plan_vmem_bytes(plan, bn=LANE_CASE["bn"])
-    out["vmem_bytes_legacy"] = plan_vmem_bytes(plan, bn=LANE_CASE["bn"],
-                                               pipelined=False)
     out["vmem_bytes_spgemm"] = plan_vmem_bytes(gplan)
 
     # amortized cost of verify="full": the hook adds exactly two things to
@@ -276,31 +271,16 @@ def pipeline_sweep(repeats: int = 12) -> dict:
     bn = LANE_CASE["bn"]
     pip = jax.jit(lambda p, x: api.execute_plan(
         p, x, bn=bn, backend="interpret"))
-
-    def legacy_call(p, x):
-        return segment_spmm(
-            p.lhs_blocks, p.slot_idx, p.m_idx, p.k_idx, p.seg_start,
-            p.seg_write, p.accum_prev, p.valid, x, grid_m=p.grid[0],
-            n_lanes=p.n_lanes, bn=bn, unroll=p.unroll, masked=p.has_pads,
-            interpret=True, pipeline=False)
-
-    leg = jax.jit(legacy_call)
     out["max_err_pipelined"] = float(
         np.abs(np.asarray(pip(plan, bd)) - want).max())
-    out["max_err_legacy"] = float(
-        np.abs(np.asarray(leg(plan, bd)) - want).max())
-    times = {"pipelined": [], "legacy": []}
+    ts = []
     for _ in range(repeats):
-        for name, fn in (("pipelined", pip), ("legacy", leg)):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(plan, bd))
-            times[name].append((time.perf_counter() - t0) * 1e6)
-    for name, ts in times.items():
-        ts = sorted(ts)
-        out[f"{name}_us"] = ts[len(ts) // 2]
-        out[f"{name}_us_min"] = ts[0]
-    out["interpret_slowdown_vs_legacy"] = (
-        out["pipelined_us_min"] / out["legacy_us_min"])
+        t0 = time.perf_counter()
+        jax.block_until_ready(pip(plan, bd))
+        ts.append((time.perf_counter() - t0) * 1e6)
+    ts = sorted(ts)
+    out["pipelined_us"] = ts[len(ts) // 2]
+    out["pipelined_us_min"] = ts[0]
     return out
 
 
@@ -469,7 +449,7 @@ def autotune_sweep(repeats: int = 12) -> dict:
             "policy": tuned.policy,
             "knobs": dict(fold_len=res.best.candidate.fold_len,
                           n_lanes=tuned.n_lanes, unroll=tuned.unroll,
-                          bn=res.best.candidate.bn, pipeline=tuned.pipeline),
+                          bn=res.best.candidate.bn),
             "dataflow_choice": res.dataflow_choice,
             "dataflow_scores": {k: float(v)
                                 for k, v in res.dataflow_scores.items()},
@@ -523,7 +503,6 @@ def run(csv: Csv) -> dict:
                 f"max_err={row['max_err']:.2e}")
     pipe = pipeline_sweep()
     csv.add("kernel/spmm_pipeline_interpret", pipe["pipelined_us"],
-            f"legacy={pipe['legacy_us']:.0f}us;"
             f"max_err={pipe['max_err_pipelined']:.2e}")
     pf = prefetch_sweep()
     csv.add("kernel/spmm_prefetch_interpret", pf["prefetch_us"],

@@ -162,7 +162,7 @@ def sweep(level: str, scale: int, seed: int, quiet: bool,
             label = (f"autotuned/{name} obj={objective} "
                      f"policy={kw['policy']} lanes={kw['n_lanes']} "
                      f"unroll={kw['unroll']} fold={kw['fold_len']} "
-                     f"pipe={kw['pipeline']} bn={kw['bn_hint']} "
+                     f"bn={kw['bn_hint']} "
                      f"pf={kw['prefetch']}")
             check(label, plan)
             n_autotuned += 1

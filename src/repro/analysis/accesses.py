@@ -42,6 +42,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 from jax import tree_util
+from jax.experimental import pallas as pl
 
 TOP = None          #: unknown abstract value (data-dependent / loop-carried)
 
@@ -584,6 +585,13 @@ def _is_static_full(start, size, extent) -> bool:
             and size is not TOP and int(size) == int(extent))
 
 
+def _block_dim(b) -> int:
+    """One BlockSpec window dimension as an int: ``Blocked``, ``Element`` and
+    ``BoundedSlice`` carry ``block_size``; ``Squeezed`` is a unit dimension
+    the kernel never sees."""
+    return 1 if isinstance(b, pl.Squeezed) else int(b.block_size)
+
+
 def _unflatten_transforms(tree, leaves):
     if tree is None:
         return ()
@@ -601,12 +609,7 @@ def _unflatten_transforms(tree, leaves):
 
 def _dimension_semantics(eqn, n_axes: int) -> Tuple[str, ...]:
     cp = eqn.params.get("compiler_params") or {}
-    if isinstance(cp, dict):
-        mosaic = cp.get("mosaic") or {}
-        sem = mosaic.get("dimension_semantics") if isinstance(mosaic, dict) \
-            else getattr(mosaic, "dimension_semantics", None)
-    else:
-        sem = getattr(cp, "dimension_semantics", None)
+    sem = getattr(cp.get("mosaic_tpu"), "dimension_semantics", None)
     if sem is None:
         return ("arbitrary",) * n_axes
     return tuple(str(s) for s in sem)
@@ -665,12 +668,10 @@ def kernel_ir_from_eqn(eqn, name: str = "<kernel>",
             array_shape = shape
             space = _memspace(aval)
             if bm is not None:
-                block_shape = tuple(int(b) for b in bm.block_shape)
-                sd = getattr(bm, "array_shape_dtype", None)
-                if sd is not None:
-                    array_shape = tuple(int(s) for s in sd.shape)
-                    dtype = str(sd.dtype)
-                space = _memspace(getattr(bm, "block_aval", aval))
+                block_shape = tuple(_block_dim(b) for b in bm.block_shape)
+                array_shape = tuple(int(s) for s in bm.array_aval.shape)
+                dtype = str(bm.array_aval.dtype)
+                space = _memspace(bm.transformed_block_aval)
             info = RefInfo(role, idx, f"{'in' if role == 'input' else 'out'}"
                            f"{idx}", array_shape, dtype, space, block_shape)
         else:

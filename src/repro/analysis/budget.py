@@ -22,7 +22,7 @@ Rule id: ``vmem-budget``.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 import numpy as np
 
@@ -107,17 +107,12 @@ def spmm_vmem_bytes(*, bm: int, bk: int, bn: int, unroll: int,
                     transpose_lhs: bool = False,
                     block_dtype="float32", rhs_dtype="float32",
                     out_dtype="float32", quantized: bool = False,
-                    rowwise: bool = False, pipelined: bool = True) -> int:
-    """VMEM bytes of one ``segment_spmm`` kernel instance.
-
-    Pipelined: ``acc(row·bn·4) + out window(row·bn·2) + A ring
-    (2·unroll·bm·bk) + B ring (2·unroll·contract·bn)`` plus, when
-    quantized, the per-step scale window — ``(1, 1, unroll)`` fp32 per-block,
-    ``(1, unroll, bm)`` in rowwise mode.  Legacy: the BlockSpec
-    auto-pipeline double-buffers ``unroll`` A tiles and ``unroll`` B
-    stripes instead of the explicit rings (per-block scales ride the SMEM
-    prefetch path there — no VMEM — but rowwise scale rows are ``unroll``
-    windowed ``(1, bm)`` VMEM operands).
+                    rowwise: bool = False) -> int:
+    """VMEM bytes of one ``segment_spmm`` kernel instance:
+    ``acc(row·bn·4) + out window(row·bn·2) + A ring (2·unroll·bm·bk) +
+    B ring (2·unroll·contract·bn)`` plus, when quantized, the per-step scale
+    window — ``(1, 1, unroll)`` fp32 per-block, ``(1, unroll, bm)`` in
+    rowwise mode.
     """
     row_blk, contract_blk = (bk, bm) if transpose_lhs else (bm, bk)
     a_item = _itemsize(block_dtype)
@@ -125,25 +120,18 @@ def spmm_vmem_bytes(*, bm: int, bk: int, bn: int, unroll: int,
     scale_elems = bm if rowwise else 1   # rowwise runs over storage rows
     total = row_blk * bn * 4                                     # acc
     total += row_blk * bn * _itemsize(out_dtype) * _BLOCK_BUFFERS  # out win
-    if pipelined:
-        depth = 2 * unroll
-        total += depth * bm * bk * a_item                        # A ring
-        total += depth * contract_blk * bn * b_item              # B ring
-        if quantized:
-            total += unroll * scale_elems * 4 * _BLOCK_BUFFERS   # scale win
-    else:
-        total += unroll * (1 * bm * bk) * a_item * _BLOCK_BUFFERS
-        total += unroll * (contract_blk * bn) * b_item * _BLOCK_BUFFERS
-        if quantized and rowwise:
-            total += unroll * (1 * bm) * 4 * _BLOCK_BUFFERS
+    depth = 2 * unroll
+    total += depth * bm * bk * a_item                            # A ring
+    total += depth * contract_blk * bn * b_item                  # B ring
+    if quantized:
+        total += unroll * scale_elems * 4 * _BLOCK_BUFFERS       # scale win
     return total
 
 
 def spgemm_vmem_bytes(*, bm: int, bk: int, bn: int, unroll: int,
                       block_dtype="float32", rhs_dtype=None,
                       out_dtype="float32", quant_a: bool = False,
-                      quant_b: bool = False, rowwise: bool = False,
-                      pipelined: bool = True) -> int:
+                      quant_b: bool = False, rowwise: bool = False) -> int:
     """VMEM bytes of one ``segment_spgemm`` kernel instance (same
     accounting as :func:`spmm_vmem_bytes`, block×block operand streams;
     rowwise scale windows span A's ``bm`` rows and B's ``bk`` rows)."""
@@ -153,18 +141,11 @@ def spgemm_vmem_bytes(*, bm: int, bk: int, bn: int, unroll: int,
     b_scale = bk if rowwise else 1
     total = bm * bn * 4                                          # acc
     total += 1 * bm * bn * _itemsize(out_dtype) * _BLOCK_BUFFERS   # out win
-    if pipelined:
-        depth = 2 * unroll
-        total += depth * bm * bk * a_item
-        total += depth * bk * bn * b_item
-        total += (int(quant_a) * a_scale
-                  + int(quant_b) * b_scale) * unroll * 4 * _BLOCK_BUFFERS
-    else:
-        total += unroll * (1 * bm * bk) * a_item * _BLOCK_BUFFERS
-        total += unroll * (1 * bk * bn) * b_item * _BLOCK_BUFFERS
-        if rowwise:
-            total += (int(quant_a) * a_scale + int(quant_b) * b_scale) \
-                * unroll * 4 * _BLOCK_BUFFERS
+    depth = 2 * unroll
+    total += depth * bm * bk * a_item
+    total += depth * bk * bn * b_item
+    total += (int(quant_a) * a_scale
+              + int(quant_b) * b_scale) * unroll * 4 * _BLOCK_BUFFERS
     return total
 
 
@@ -181,8 +162,7 @@ def _plan_block_dtype(plan) -> str:
             "fp8": "float8_e4m3fn"}.get(name, name)
 
 
-def plan_vmem_bytes(plan, *, bn: int = 512, pipelined: Optional[bool] = None
-                    ) -> int:
+def plan_vmem_bytes(plan, *, bn: int = 512) -> int:
     """Worst-case VMEM bytes across the kernel instances a ``SegmentPlan``
     will launch through the executor: the forward kernel plus, when the
     plan carries a gradient schedule, the transposed backward kernel.
@@ -195,12 +175,6 @@ def plan_vmem_bytes(plan, *, bn: int = 512, pipelined: Optional[bool] = None
     quantized = plan.lhs_scales is not None
     rowwise = quantized and getattr(plan.lhs_scales, "ndim", 1) == 2
     unroll = max(1, int(plan.unroll or 1))
-    if pipelined is None:
-        # a plan built with pipeline=False carries the fetch-flag leaves
-        # (their contract is pipeline-independent) but executes the legacy
-        # BlockSpec path — budget what the executor will actually launch
-        pipelined = (plan.a_fetch is not None
-                     and bool(getattr(plan, "pipeline", True)))
     if plan.kind == "spgemm":
         bn_eff = (plan.rhs_blocks.shape[2] if plan.rhs_blocks is not None
                   else bk)
@@ -210,15 +184,15 @@ def plan_vmem_bytes(plan, *, bn: int = 512, pipelined: Optional[bool] = None
             bm=bm, bk=bk, bn=bn_eff, unroll=unroll, block_dtype=dt,
             rhs_dtype=rhs_dt,
             quant_a=quantized, quant_b=plan.rhs_scales is not None,
-            rowwise=rowwise, pipelined=pipelined)
+            rowwise=rowwise)
     else:
         total = spmm_vmem_bytes(bm=bm, bk=bk, bn=bn, unroll=unroll,
                                 transpose_lhs=plan.transpose_lhs,
                                 block_dtype=dt, quantized=quantized,
-                                rowwise=rowwise, pipelined=pipelined)
+                                rowwise=rowwise)
     grad = plan.grad_plan
     if grad is not None:
-        total = max(total, plan_vmem_bytes(grad, bn=bn, pipelined=pipelined))
+        total = max(total, plan_vmem_bytes(grad, bn=bn))
     return total
 
 

@@ -558,13 +558,6 @@ def check_traffic_agreement(plan, path: str = "plan") -> List[Finding]:
     fetch-flag sums and with the counts recorded in ``plan.traffic``.
     Counts are size-independent, so the model runs at unit tile sizes.
 
-    The fetch flags always implement the *pipelined* per-item-adjacency
-    contract (they are pipeline-independent plan leaves), so the flag
-    comparison uses the pipelined model unconditionally; the recorded
-    ``plan.traffic`` counts follow the plan's ``pipeline`` switch — a
-    ``pipeline=False`` plan records legacy per-BlockSpec-stream pricing and
-    is checked against that model.
-
     A ``prefetch="cross_pass"`` plan records a ``prefetch_fetches`` count
     (the copies the kernel overlaps with each pass boundary); the model is
     recomputed under the same mode and must agree exactly — cross-pass
@@ -580,7 +573,6 @@ def check_traffic_agreement(plan, path: str = "plan") -> List[Finding]:
             or seg_start is None:
         return out
     n_lanes, unroll = plan.n_lanes, plan.unroll
-    pipelined = bool(getattr(plan, "pipeline", True))
     prefetch = getattr(plan, "prefetch", None)
     if plan.kind == "spmm":
         m = _host(plan.m_idx)
@@ -588,10 +580,8 @@ def check_traffic_agreement(plan, path: str = "plan") -> List[Finding]:
         if m is None or k is None:
             return out
         model = lane_traffic_spmm(m, k, seg_start, valid.astype(bool),
-                                  n_lanes, 1, 1, 1, unroll=unroll)
-        rec_model = lane_traffic_spmm(
-            m, k, seg_start, valid.astype(bool), n_lanes, 1, 1, 1,
-            unroll=unroll, pipeline=pipelined, prefetch=prefetch)
+                                  n_lanes, 1, 1, 1, unroll=unroll,
+                                  prefetch=prefetch)
     else:
         a_idx, b_idx, c_idx = (_host(plan.a_idx), _host(plan.b_idx),
                                _host(plan.c_idx))
@@ -599,10 +589,7 @@ def check_traffic_agreement(plan, path: str = "plan") -> List[Finding]:
             return out
         model = lane_traffic_spgemm(a_idx, b_idx, c_idx, seg_start,
                                     valid.astype(bool), n_lanes, 1, 1, 1,
-                                    unroll=unroll)
-        rec_model = lane_traffic_spgemm(
-            a_idx, b_idx, c_idx, seg_start, valid.astype(bool), n_lanes,
-            1, 1, 1, unroll=unroll, pipeline=pipelined, prefetch=prefetch)
+                                    prefetch=prefetch)
     recorded = dict(getattr(plan, "traffic_items", ()) or ())
     for stream, flags in (("a", a_fetch), ("b", b_fetch)):
         n_model = int(model[f"{stream}_fetches"])
@@ -616,18 +603,16 @@ def check_traffic_agreement(plan, path: str = "plan") -> List[Finding]:
                 f"independently and must agree exactly",
                 stream=stream, path=path))
         n_rec = recorded.get(f"{stream}_fetches")
-        n_rec_model = int(rec_model[f"{stream}_fetches"])
-        if n_rec is not None and int(n_rec) != n_rec_model:
+        if n_rec is not None and int(n_rec) != n_model:
             out.append(Finding(
                 "traffic-agreement",
                 f"plan.traffic records {int(n_rec)} {stream}-stream fetches "
-                f"but the model recomputes {n_rec_model} "
-                f"(pipeline={'on' if pipelined else 'off'} pricing) — the "
-                f"recorded estimate is stale or was tampered with",
+                f"but the model recomputes {n_model} — the recorded "
+                f"estimate is stale or was tampered with",
                 stream=stream, path=path))
     n_pf_rec = recorded.get("prefetch_fetches")
     if n_pf_rec is not None:
-        n_pf_model = int(rec_model.get("prefetch_fetches", 0))
+        n_pf_model = int(model.get("prefetch_fetches", 0))
         if int(n_pf_rec) != n_pf_model:
             out.append(Finding(
                 "traffic-agreement",

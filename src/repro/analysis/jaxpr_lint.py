@@ -256,19 +256,16 @@ def lint_callable(fn, *args, label: Optional[str] = None,
 def lint_segment_kernels(verbose: bool = False) -> List[LintFinding]:
     """Lint every shipped Segment kernel variant the executor can emit.
 
-    Builds tiny plans and traces the real executor paths: SpMM pipelined
-    (fp32 + quantized + the transposed backward schedule via the custom
-    VJP) and SpGEMM pipelined, plus both kernels' legacy BlockSpec
-    auto-pipeline fallback (fetch arrays withheld).  ``repro.api`` is
-    imported lazily here — the linter core must not depend on the planner.
+    Builds tiny plans and traces the real executor paths: SpMM (fp32 +
+    quantized + the transposed backward schedule via the custom VJP) and
+    SpGEMM.  ``repro.api`` is imported lazily here — the linter core must
+    not depend on the planner.
     """
     import jax.numpy as jnp
     import numpy as np
 
     from repro.api import apply_plan, execute_plan, plan_matmul
     from repro.core.formats import BSR
-    from repro.kernels.segment_spgemm import segment_spgemm
-    from repro.kernels.segment_spmm import segment_spmm
 
     findings: List[LintFinding] = []
     a = BSR.random(np.random.default_rng(0), (128, 128), (32, 32), 0.5)
@@ -298,20 +295,6 @@ def lint_segment_kernels(verbose: bool = False) -> List[LintFinding]:
         ("spgemm-pipelined",
          lambda: jax.make_jaxpr(
              lambda: execute_plan(gplan, backend="interpret"))()),
-        ("spmm-legacy",
-         lambda: jax.make_jaxpr(lambda xx: segment_spmm(
-             plan.lhs_blocks, plan.slot_idx, plan.m_idx, plan.k_idx,
-             plan.seg_start, plan.seg_write, plan.accum_prev, plan.valid,
-             xx, grid_m=plan.grid[0], n_lanes=plan.n_lanes, bn=64,
-             unroll=plan.unroll, masked=plan.has_pads, interpret=True,
-             pipeline=False))(x)),
-        ("spgemm-legacy",
-         lambda: jax.make_jaxpr(lambda: segment_spgemm(
-             gplan.lhs_blocks, gplan.rhs_blocks, gplan.a_idx, gplan.b_idx,
-             gplan.c_idx, gplan.seg_start, gplan.seg_write,
-             gplan.accum_prev, gplan.valid, n_c_blocks=gplan.n_out_blocks,
-             n_lanes=gplan.n_lanes, unroll=gplan.unroll,
-             masked=gplan.has_pads, interpret=True, pipeline=False))()),
     ]
     for label, trace in traces:
         kernels = find_pallas_kernels(trace())
@@ -392,8 +375,8 @@ def analyze_shipped_kernels(verbose: bool = False) -> List[LintFinding]:
     """The full static gate: syntactic lint + symbolic proofs over every
     shipped Pallas kernel × a knob grid.
 
-    Covers the six Segment variants :func:`lint_segment_kernels` traces
-    (pipelined fwd/grad/quantized, SpGEMM, both legacy fallbacks) plus
+    Covers the four Segment variants :func:`lint_segment_kernels` traces
+    (SpMM fwd/grad/quantized, SpGEMM) plus
     extra (n_lanes, unroll) and fp8 knob points, and extends the gate to
     the non-Segment kernels — ``flash_attention`` (causal, and
     windowed+GQA to exercise the ``rem``-guarded skip path), ``moe_gemm``,
@@ -414,8 +397,6 @@ def analyze_shipped_kernels(verbose: bool = False) -> List[LintFinding]:
     from repro.kernels.flash_attention import flash_attention
     from repro.kernels.moe_gemm import moe_gemm
     from repro.kernels.rg_lru import rg_lru
-    from repro.kernels.segment_spgemm import segment_spgemm
-    from repro.kernels.segment_spmm import segment_spmm
 
     a = BSR.random(np.random.default_rng(0), (128, 128), (32, 32), 0.5)
     b = BSR.random(np.random.default_rng(1), (128, 128), (32, 32), 0.5)
@@ -486,20 +467,6 @@ def analyze_shipped_kernels(verbose: bool = False) -> List[LintFinding]:
         ("spgemm-prefetch",
          lambda: jax.make_jaxpr(
              lambda: execute_plan(gplan_pf, backend="interpret"))(), ()),
-        ("spmm-legacy",
-         lambda: jax.make_jaxpr(lambda xx: segment_spmm(
-             plan.lhs_blocks, plan.slot_idx, plan.m_idx, plan.k_idx,
-             plan.seg_start, plan.seg_write, plan.accum_prev, plan.valid,
-             xx, grid_m=plan.grid[0], n_lanes=plan.n_lanes, bn=64,
-             unroll=plan.unroll, masked=plan.has_pads, interpret=True,
-             pipeline=False))(x), (x,)),
-        ("spgemm-legacy",
-         lambda: jax.make_jaxpr(lambda: segment_spgemm(
-             gplan.lhs_blocks, gplan.rhs_blocks, gplan.a_idx, gplan.b_idx,
-             gplan.c_idx, gplan.seg_start, gplan.seg_write,
-             gplan.accum_prev, gplan.valid, n_c_blocks=gplan.n_out_blocks,
-             n_lanes=gplan.n_lanes, unroll=gplan.unroll,
-             masked=gplan.has_pads, interpret=True, pipeline=False))(), ()),
         ("flash-causal",
          lambda: jax.make_jaxpr(lambda qq, kk, vv: flash_attention(
              qq, kk, vv, causal=True, interpret=True))(q, kv, kv),

@@ -138,7 +138,6 @@ def _run_spmm(plan: SegmentPlan, x: jax.Array, *, backend: str,
         interpret=backend_interpret_flag(backend), out_dtype=out_dtype,
         a_scales=scales, a_fetch=plan.a_fetch, b_fetch=plan.b_fetch,
         a_slot=plan.a_slot, b_slot=plan.b_slot,
-        pipeline=bool(getattr(plan, "pipeline", True)),
         prefetch=getattr(plan, "prefetch", None))
     if pad:
         out = out[:, :n]
@@ -172,7 +171,6 @@ def _run_spgemm(plan: SegmentPlan, *, backend: str,
         a_scales=plan.lhs_scales, b_scales=plan.rhs_scales,
         a_fetch=plan.a_fetch, b_fetch=plan.b_fetch,
         a_slot=plan.a_slot, b_slot=plan.b_slot,
-        pipeline=bool(getattr(plan, "pipeline", True)),
         prefetch=getattr(plan, "prefetch", None))
 
 

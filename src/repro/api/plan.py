@@ -49,7 +49,7 @@ _LEAF_FIELDS = (
 _AUX_FIELDS = ("kind", "policy", "block_shape", "grid", "rhs_grid",
                "n_out_blocks", "traffic_items", "fingerprint", "backend",
                "n_lanes", "unroll", "transpose_lhs", "block_dtype",
-               "out_dtype", "has_pads", "pipeline", "bn_hint", "prefetch")
+               "out_dtype", "has_pads", "bn_hint", "prefetch")
 
 
 @dataclasses.dataclass(eq=False)   # array fields make generated __eq__ ambiguous
@@ -88,11 +88,6 @@ class SegmentPlan:
     # the executor masks pad contributions exactly when this is set (the
     # conservative default keeps hand-built plans safe)
     has_pads: bool = True
-    # False selects the legacy BlockSpec auto-pipeline instead of the
-    # explicit DMA pipeline; the fetch-flag leaves still ride along (their
-    # contract is pipeline-independent) but the executor and the traffic
-    # pricing both follow this switch
-    pipeline: bool = True
     # preferred executor N-tile width (set by the repro.tune search; the
     # executor uses it when the caller passes no explicit bn)
     bn_hint: Optional[int] = None

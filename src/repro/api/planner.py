@@ -97,7 +97,7 @@ def _bucket_hint(n: Optional[int]) -> Optional[int]:
 def pattern_fingerprint(kind: str, policy_key: str, fold_len: Optional[int],
                         with_grad: bool, *mats: BSR, n_lanes: int = 1,
                         unroll: int = 1, block_dtype: str = "fp32",
-                        n_bucket: Optional[int] = None, pipeline: bool = True,
+                        n_bucket: Optional[int] = None,
                         bn_hint: Optional[int] = None,
                         prefetch: Optional[str] = None) -> str:
     """Digest of everything the *schedule* and the cached pricing depend on
@@ -108,14 +108,14 @@ def pattern_fingerprint(kind: str, policy_key: str, fold_len: Optional[int],
     that an fp32 plan of the same pattern must never be served.
     ``n_bucket`` is the *bucketed* dense-N hint (see :func:`_bucket_hint`)
     — the raw hint stays out so nearby widths share one template, but
-    orders-of-magnitude-different widths no longer collide.  ``pipeline``
-    and ``bn_hint`` are part of the key because they change the recorded
+    orders-of-magnitude-different widths no longer collide.  ``bn_hint``
+    and ``prefetch`` are part of the key because they change the recorded
     traffic pricing and the executor behaviour baked into the template."""
     h = hashlib.sha1()
     h.update(f"{kind}|{policy_key}|{fold_len}|{with_grad}"
              f"|lanes={n_lanes}|unroll={unroll}"
              f"|dtype={block_dtype}|nbkt={n_bucket}"
-             f"|pipe={pipeline}|bn={bn_hint}|pf={prefetch}".encode())
+             f"|bn={bn_hint}|pf={prefetch}".encode())
     for m in mats:
         _pattern_bytes(h, m)
     return h.hexdigest()
@@ -293,7 +293,6 @@ def _flag_leaves(flags: dict) -> dict:
 def _build_spmm_template(a: BSR, policy: str, fold_len: Optional[int],
                          with_grad: bool, n_lanes: int, unroll: int,
                          fingerprint: str, block_dtype: str = "fp32",
-                         pipeline: bool = True,
                          bn_hint: Optional[int] = None,
                          prefetch: Optional[str] = None) -> _PlanTemplate:
     sched = build_spmm_schedule(a, policy=policy, fold_len=fold_len)
@@ -312,7 +311,7 @@ def _build_spmm_template(a: BSR, policy: str, fold_len: Optional[int],
     basis = _quantize_a_traffic(lane_traffic_spmm(
         lane_m, lane_k, flags["seg_start"],
         layout.valid.reshape(-1), layout.n_lanes, bm, bk, 1, unroll=unroll,
-        pipeline=pipeline, prefetch=prefetch),
+        prefetch=prefetch),
         block_dtype, bm, bk)
     basis.update(layout.stats)
 
@@ -346,7 +345,7 @@ def _build_spmm_template(a: BSR, policy: str, fold_len: Optional[int],
         grad_basis = _quantize_a_traffic(lane_traffic_spmm(
             t_lane_m, t_lane_k, t_flags["seg_start"],
             t_layout.valid.reshape(-1), t_layout.n_lanes, bk, bm, 1,
-            unroll=unroll, pipeline=pipeline, prefetch=prefetch),
+            unroll=unroll, prefetch=prefetch),
             block_dtype, bk, bm)
         grad_basis.update(t_layout.stats)
         grad_plan = SegmentPlan(
@@ -357,7 +356,7 @@ def _build_spmm_template(a: BSR, policy: str, fold_len: Optional[int],
             fingerprint=fingerprint + ":grad",
             block_dtype=block_dtype,
             n_lanes=t_layout.n_lanes, unroll=unroll, transpose_lhs=True,
-            pipeline=pipeline, bn_hint=bn_hint, prefetch=prefetch,
+            bn_hint=bn_hint, prefetch=prefetch,
             has_pads=bool(not t_layout.valid.all()),
             m_idx=jnp.asarray(t_lane_m.astype(np.int32)),
             k_idx=jnp.asarray(t_lane_k.astype(np.int32)),
@@ -373,7 +372,7 @@ def _build_spmm_template(a: BSR, policy: str, fold_len: Optional[int],
         traffic_items=(),   # re-priced per realize from traffic_basis
         fingerprint=fingerprint, block_dtype=block_dtype,
         n_lanes=layout.n_lanes, unroll=unroll,
-        pipeline=pipeline, bn_hint=bn_hint, prefetch=prefetch,
+        bn_hint=bn_hint, prefetch=prefetch,
         has_pads=bool(not layout.valid.all()),
         m_idx=jnp.asarray(lane_m.astype(np.int32)),
         k_idx=jnp.asarray(lane_k.astype(np.int32)),
@@ -388,7 +387,6 @@ def _build_spmm_template(a: BSR, policy: str, fold_len: Optional[int],
 def _build_spgemm_template(a: BSR, b: BSR, policy: str,
                            fold_len: Optional[int], n_lanes: int, unroll: int,
                            fingerprint: str, block_dtype: str = "fp32",
-                           pipeline: bool = True,
                            bn_hint: Optional[int] = None,
                            prefetch: Optional[str] = None) -> _PlanTemplate:
     sched = build_spgemm_schedule(a, b, policy=policy, fold_len=fold_len)
@@ -407,8 +405,8 @@ def _build_spgemm_template(a: BSR, b: BSR, policy: str,
     fetch = _fetch_schedule(layout, lane_a, lane_b, unroll)
     traffic = _quantize_spgemm_traffic(lane_traffic_spgemm(
         lane_a, lane_b, lane_c, flags["seg_start"],
-        layout.valid.reshape(-1), layout.n_lanes, bm, bk, bn, unroll=unroll,
-        pipeline=pipeline, prefetch=prefetch),
+        layout.valid.reshape(-1), layout.n_lanes, bm, bk, bn,
+        prefetch=prefetch),
         block_dtype, bm, bk, bn)
     traffic.update(layout.stats)
     plan = SegmentPlan(
@@ -417,7 +415,7 @@ def _build_spgemm_template(a: BSR, b: BSR, policy: str,
         traffic_items=_freeze_traffic(traffic),
         fingerprint=fingerprint, block_dtype=block_dtype,
         n_lanes=layout.n_lanes, unroll=unroll,
-        pipeline=pipeline, bn_hint=bn_hint, prefetch=prefetch,
+        bn_hint=bn_hint, prefetch=prefetch,
         has_pads=bool(not layout.valid.all()),
         a_idx=jnp.asarray(lane_a.astype(np.int32)),
         b_idx=jnp.asarray(lane_b.astype(np.int32)),
@@ -471,7 +469,6 @@ def plan_matmul(a: BSR, b_or_shape=None, *, policy: str = "segment",
                 quantize: Optional[str] = None,
                 out_dtype=None, verify=None,
                 vmem_limit_bytes: Optional[int] = None,
-                pipeline: bool = True,
                 bn_hint: Optional[int] = None,
                 prefetch: Optional[str] = None) -> SegmentPlan:
     """Plan a Segment-dataflow matmul for the sparsity pattern of ``a``.
@@ -483,7 +480,7 @@ def plan_matmul(a: BSR, b_or_shape=None, *, policy: str = "segment",
       policy: any name in the policy registry, or ``"auto"`` — run the
         :mod:`repro.tune` schedule search over the knob grid and the
         registered dataflows and plan with the winning (policy, fold_len,
-        n_lanes, unroll, pipeline, bn) combination.  Knobs passed
+        n_lanes, unroll, bn, prefetch) combination.  Knobs passed
         explicitly alongside ``policy="auto"`` are treated as pins the
         search must honour.  Winning schedules are cached by pattern
         fingerprint, so repeat patterns pay zero search cost.
@@ -527,9 +524,6 @@ def plan_matmul(a: BSR, b_or_shape=None, *, policy: str = "segment",
         launch.  The N-tile width is taken as the executor default
         (``bn_hint`` or 512) clamped by ``pick_bn`` to the traffic hint's N
         (lane-aligned for ``backend="pallas"``).
-      pipeline: ``False`` builds the plan for the legacy BlockSpec
-        auto-pipeline instead of the explicit DMA pipeline; the recorded
-        traffic estimate follows the same switch.
       bn_hint: preferred executor N-tile width, used when the caller passes
         no explicit ``bn`` at execution time (set by the :mod:`repro.tune`
         search; ``None`` keeps the executor default of 512).
@@ -538,8 +532,8 @@ def plan_matmul(a: BSR, b_or_shape=None, *, policy: str = "segment",
         the next (lane, N-tile) pass's first copies — B row-tiles before A
         tiles — during the current pass's tail step instead of draining
         the pipeline at the boundary; numerically identical (the mode
-        re-times copies, it never changes which items fetch).  Requires
-        the explicit DMA pipeline.  The recorded traffic gains a
+        re-times copies, it never changes which items fetch).  The
+        recorded traffic gains a
         ``prefetch_fetches`` entry pricing the overlapped copies, and
         every shipped kernel variant with prefetch enabled is proven
         hazard-free by :mod:`repro.analysis.order` in CI.
@@ -551,11 +545,6 @@ def plan_matmul(a: BSR, b_or_shape=None, *, policy: str = "segment",
                          f"available: {QUANT_MODES} or None")
     if prefetch not in PREFETCH_MODES:
         raise ValueError(f"prefetch={prefetch!r} not in {PREFETCH_MODES}")
-    if prefetch is not None and not pipeline:
-        raise ValueError(
-            "prefetch='cross_pass' requires the explicit DMA pipeline "
-            "(pipeline=True); the legacy BlockSpec path has no cross-pass "
-            "copy timing to overlap")
     block_dtype = quantize if quantize is not None else "fp32"
     out_dtype = None if out_dtype is None else jnp.dtype(out_dtype).name
     if policy == "auto":
@@ -573,8 +562,6 @@ def plan_matmul(a: BSR, b_or_shape=None, *, policy: str = "segment",
             pins["n_lanes"] = n_lanes
         if unroll != 1:
             pins["unroll"] = unroll
-        if pipeline is not True:
-            pins["pipeline"] = pipeline
         if bn_hint is not None:
             pins["bn"] = bn_hint
         if prefetch is not None:
@@ -593,8 +580,8 @@ def plan_matmul(a: BSR, b_or_shape=None, *, policy: str = "segment",
             n_cols_hint=n_cols_hint, n_lanes=best.n_lanes,
             unroll=best.unroll, cache=cache, quantize=quantize,
             out_dtype=out_dtype, verify=verify,
-            vmem_limit_bytes=vmem_limit_bytes, pipeline=best.pipeline,
-            bn_hint=best.bn, prefetch=best.prefetch)
+            vmem_limit_bytes=vmem_limit_bytes, bn_hint=best.bn,
+            prefetch=best.prefetch)
     pol = get_policy(policy)       # fail fast + serial for the cache key
     b, hint = _rhs_to_hint(a, b_or_shape)
     if n_cols_hint is not None:
@@ -611,21 +598,18 @@ def plan_matmul(a: BSR, b_or_shape=None, *, policy: str = "segment",
                               unroll=unroll, block_dtype=block_dtype,
                               n_bucket=_bucket_hint(hint) if b is None
                               else None,
-                              pipeline=pipeline, bn_hint=bn_hint,
-                              prefetch=prefetch)
+                              bn_hint=bn_hint, prefetch=prefetch)
     level = _resolve_verify(verify)
     tpl = _CACHE.get(key) if cache else None
     if tpl is None:
         if kind == SPMM:
             tpl = _build_spmm_template(a, policy, fold_len, with_grad,
                                        n_lanes, unroll, key, block_dtype,
-                                       pipeline=pipeline, bn_hint=bn_hint,
-                                       prefetch=prefetch)
+                                       bn_hint=bn_hint, prefetch=prefetch)
         else:
             tpl = _build_spgemm_template(a, b, policy, fold_len, n_lanes,
                                          unroll, key, block_dtype,
-                                         pipeline=pipeline, bn_hint=bn_hint,
-                                         prefetch=prefetch)
+                                         bn_hint=bn_hint, prefetch=prefetch)
         _STATS["misses"] += 1   # a build is a miss whether or not it's kept
         if cache:
             _CACHE[key] = tpl

@@ -49,7 +49,7 @@ class SchedulePolicy:
 
     returning a dict shaped like :func:`repro.core.schedule.lane_traffic_spmm`
     output (``a_bytes``/``b_bytes``/``c_bytes``/``total``/fetch counts) at
-    default knobs (one lane, fp32, pipelined), or ``None`` when the policy
+    default knobs (one lane, fp32), or ``None`` when the policy
     cannot estimate that kind analytically.  Dynamic policies whose order
     *is* the schedule (``segment``) leave this unset — the tuner evaluates
     them by building the schedule.

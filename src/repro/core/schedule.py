@@ -164,7 +164,7 @@ def _static_cost_hint(policy_name: str):
     """Closed-form ``SchedulePolicy.cost_hint`` for a *static* order.
 
     A static policy's schedule is fully determined by its order function, so
-    its default-knob traffic (one lane, fp32, pipelined) can be priced
+    its default-knob traffic (one lane, fp32) can be priced
     exactly by applying the order and evaluating the revisiting model over
     the *lane-major* item order — :func:`partition_lanes` round-robins
     segments even at one lane, so the hint runs the same layout the planner
@@ -649,7 +649,7 @@ def lane_select(layout: LaneLayout, arr: np.ndarray,
 
 
 def _revisit_traffic(fetch_streams, owner, seg_start, valid, n_lanes,
-                     c_tile_bytes, unroll: int = 1, pipeline: bool = True):
+                     c_tile_bytes):
     """Shared revisiting-model core over flattened lane-major arrays.
 
     ``fetch_streams`` is a list of ``(arr, tile_bytes, always)`` operand
@@ -658,26 +658,18 @@ def _revisit_traffic(fetch_streams, owner, seg_start, valid, n_lanes,
     the SELECTA boundary-reuse chain is broken where a schedule is cut into
     lanes), or on every valid item when ``always``.
 
-    ``pipeline=True`` (the default — matching the kernels' explicit DMA
-    pipeline) counts a fetch wherever an operand index differs from the
-    previous item's within the lane, exactly the contract
-    :func:`fetch_flags` compiles into the kernels' copy-gating flags.  The
-    two are deliberately *independent implementations* of that contract —
-    CI asserts their counts agree exactly, so a drift bug in either one
-    (pad handling, lane starts, unroll) trips the gate instead of
-    cancelling out.  Per-item adjacency carries reuse across every
-    consecutive pair, ``unroll`` included.  ``pipeline=False`` models the
-    legacy BlockSpec auto-pipeline, where each of the G items of an
-    unrolled grid step binds an *independent* stream (index maps strided by
-    ``unroll``): revisit credit only exists between position ``g`` of
-    consecutive steps, never across the items inside one step.
+    That is exactly the contract :func:`fetch_flags` compiles into the
+    kernels' copy-gating flags.  The two are deliberately *independent
+    implementations* of that contract — CI asserts their counts agree
+    exactly, so a drift bug in either one (pad handling, lane starts,
+    unroll) trips the gate instead of cancelling out.  Per-item adjacency
+    carries reuse across every consecutive pair, ``unroll`` included.
 
     Counts are per (lane, output-tile) pass: B/C bytes stay exact across a
     multi-N-tile SpMM grid (each pass copies one ``bn``-wide slice; summed
     over passes that is the priced row-block), while A-tile bytes are
     priced once per item even though the kernel re-issues A copies each
-    pass — the same N-independent idealization the auto-pipeline model
-    used.  C tiles are written once per segment head and read back on
+    pass — an N-independent idealization.  C tiles are written once per segment head and read back on
     owner revisits (folded continuations / non-contiguous re-starts).
     Pads (``valid == 0``) move no data.
     """
@@ -686,17 +678,11 @@ def _revisit_traffic(fetch_streams, owner, seg_start, valid, n_lanes,
     for arr, tile_bytes, always in fetch_streams:
         if always:
             n_fetch = int(valid.sum())
-        elif pipeline:
+        else:
             a2 = np.asarray(arr).reshape(n_lanes, -1)
             delta = np.ones_like(a2, dtype=bool)
             if a2.shape[1] > 1:
                 delta[:, 1:] = a2[:, 1:] != a2[:, :-1]
-            n_fetch = int((delta.reshape(-1) & valid).sum())
-        else:
-            a3 = np.asarray(arr).reshape(n_lanes, -1, unroll)
-            delta = np.ones_like(a3, dtype=bool)
-            if a3.shape[1] > 1:
-                delta[:, 1:, :] = a3[:, 1:, :] != a3[:, :-1, :]
             n_fetch = int((delta.reshape(-1) & valid).sum())
         fetches.append((n_fetch, n_fetch * tile_bytes))
     seg_heads = np.nonzero(np.asarray(seg_start) & valid)[0]
@@ -735,7 +721,7 @@ def _head_window_fetches(k, valid, n_lanes: int, unroll: int) -> int:
 
 def lane_traffic_spmm(m, k, seg_start, valid, n_lanes: int, bm: int, bk: int,
                       n_cols: int, bytes_per_el: int = 4,
-                      unroll: int = 1, pipeline: bool = True,
+                      unroll: int = 1,
                       prefetch: Optional[str] = None) -> dict:
     """Revisiting-model HBM bytes for the lane-parallel SpMM kernel.
 
@@ -744,9 +730,7 @@ def lane_traffic_spmm(m, k, seg_start, valid, n_lanes: int, bm: int, bk: int,
     B row-block is fetched when ``k`` changes within a lane (and always at
     a lane start — lane cuts break the boundary-k chaining the Segment
     order set up); C tiles follow the segment write/revisit rule, with
-    owners confined to single lanes.  ``pipeline`` selects the explicit-DMA
-    fetch-flag accounting (default, matching the kernels) vs the legacy
-    per-BlockSpec-stream model (see :func:`_revisit_traffic`).
+    owners confined to single lanes (see :func:`_revisit_traffic`).
 
     ``prefetch`` never changes byte totals or fetch counts — cross-pass
     prefetch re-times copies, it does not add or drop any (see
@@ -761,8 +745,7 @@ def lane_traffic_spmm(m, k, seg_start, valid, n_lanes: int, bm: int, bk: int,
         raise ValueError(f"prefetch={prefetch!r} not in {PREFETCH_MODES}")
     fetches, c_segments, c_bytes = _revisit_traffic(
         [(k, 0, True), (k, bk * n_cols * bytes_per_el, False)],
-        m, seg_start, valid, n_lanes, bm * n_cols * bytes_per_el,
-        unroll=unroll, pipeline=pipeline)
+        m, seg_start, valid, n_lanes, bm * n_cols * bytes_per_el)
     a_fetches = fetches[0][0]
     a_bytes = a_fetches * bm * bk * bytes_per_el
     b_fetches, b_bytes = fetches[1]
@@ -776,7 +759,6 @@ def lane_traffic_spmm(m, k, seg_start, valid, n_lanes: int, bm: int, bk: int,
 
 def lane_traffic_spgemm(a_idx, b_idx, c_idx, seg_start, valid, n_lanes: int,
                         bm: int, bk: int, bn: int, bytes_per_el: int = 4,
-                        unroll: int = 1, pipeline: bool = True,
                         prefetch: Optional[str] = None) -> dict:
     """Revisiting-model HBM bytes for the lane-parallel SpGEMM kernel.
 
@@ -789,8 +771,7 @@ def lane_traffic_spgemm(a_idx, b_idx, c_idx, seg_start, valid, n_lanes: int,
     fetches, c_segments, c_bytes = _revisit_traffic(
         [(a_idx, bm * bk * bytes_per_el, False),
          (b_idx, bk * bn * bytes_per_el, False)],
-        c_idx, seg_start, valid, n_lanes, bm * bn * bytes_per_el,
-        unroll=unroll, pipeline=pipeline)
+        c_idx, seg_start, valid, n_lanes, bm * bn * bytes_per_el)
     a_fetches, a_bytes = fetches[0]
     b_fetches, b_bytes = fetches[1]
     total = a_bytes + b_bytes + c_bytes

@@ -30,8 +30,7 @@ BSR storage order; each grid step executes ``unroll`` same-C-slot triples
 against the resident ring slots.  ``valid=0`` marks lane-padding no-ops
 (contribution masked out).  Quantized per-block scales are gathered per item
 and stream as per-step VMEM vectors (one vector load per step instead of
-``unroll`` serialized SMEM scalar reads).  ``pipeline=False`` keeps the
-legacy BlockSpec auto-pipeline as a benchmark baseline.
+``unroll`` serialized SMEM scalar reads).
 """
 from __future__ import annotations
 
@@ -42,68 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .segment_spmm import (kernel_name, resolve_pipeline,
-                           validate_schedule_args)
-
-
-def _make_legacy_kernel(lane_len: int, unroll: int, masked: bool,
-                        quant_a, quant_b):
-    def _kernel(a_idx, b_idx, c_idx, seg_start, seg_write, accum_prev,
-                valid, *refs):
-        if quant_a == "block":
-            a_scales, refs = refs[0], refs[1:]
-        if quant_b == "block":
-            b_scales, refs = refs[0], refs[1:]
-        a_refs = refs[:unroll]
-        b_refs = refs[unroll:2 * unroll]
-        refs = refs[2 * unroll:]
-        if quant_a == "rowwise":
-            as_refs, refs = refs[:unroll], refs[unroll:]
-        if quant_b == "rowwise":
-            bs_refs, refs = refs[:unroll], refs[unroll:]
-        out, acc = refs
-        base = pl.program_id(0) * lane_len + pl.program_id(1) * unroll
-        for g in range(unroll):
-            i = base + g
-
-            @pl.when(seg_start[i] == 1)
-            def _init(i=i):
-                @pl.when(accum_prev[i] == 1)
-                def _load():
-                    acc[...] = out[0].astype(jnp.float32)
-
-                @pl.when(accum_prev[i] == 0)
-                def _zero():
-                    acc[...] = jnp.zeros_like(acc)
-
-            a_tile = a_refs[g][0].astype(jnp.float32)
-            b_tile = b_refs[g][0].astype(jnp.float32)
-            # Rowwise scales (A rows → output rows, B rows → the contraction
-            # axis) do not factor out of the dot, so those tiles dequantize
-            # *before* the MXU contraction.
-            if quant_a == "rowwise":
-                a_tile = a_tile * as_refs[g][0][:, None]
-            if quant_b == "rowwise":
-                b_tile = b_tile * bs_refs[g][0][:, None]
-            contrib = jax.lax.dot_general(
-                a_tile, b_tile,
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            # per-block scales are scalar tile factors — applying them to
-            # the fp32 product (after the dot, before accumulation) is exact
-            if quant_a == "block":
-                contrib = contrib * a_scales[a_idx[i]]
-            if quant_b == "block":
-                contrib = contrib * b_scales[b_idx[i]]
-            if masked:
-                contrib = jnp.where(valid[i] == 1, contrib, 0.0)
-            acc[...] += contrib
-
-            @pl.when(seg_write[i] == 1)
-            def _write(i=i):
-                out[0] = acc[...].astype(out.dtype)
-
-    return _kernel
+from .segment_spmm import kernel_name, validate_schedule_args
 
 
 def _make_pipeline_kernel(lane_len: int, unroll: int, masked: bool,
@@ -223,14 +161,13 @@ def _make_pipeline_kernel(lane_len: int, unroll: int, masked: bool,
 
 @functools.partial(jax.jit, static_argnames=(
     "n_c_blocks", "n_lanes", "unroll", "masked", "interpret", "out_dtype",
-    "pipeline", "prefetch"))
+    "prefetch"))
 def segment_spgemm(a_blocks, b_blocks, a_idx, b_idx, c_idx, seg_start,
                    seg_write, accum_prev, valid, *, n_c_blocks: int,
                    n_lanes: int = 1, unroll: int = 1, masked: bool = True,
                    interpret: bool = False, out_dtype=jnp.float32,
                    a_scales=None, b_scales=None, a_fetch=None, b_fetch=None,
-                   a_slot=None, b_slot=None, pipeline=None,
-                   prefetch: str | None = None):
+                   a_slot=None, b_slot=None, prefetch: str | None = None):
     """Numeric SpGEMM phase.
 
     Args:
@@ -247,14 +184,11 @@ def segment_spgemm(a_blocks, b_blocks, a_idx, b_idx, c_idx, seg_start,
         (``(na,)`` / ``(nb,)``, applied to the fp32 product) or per block
         row (``(na, bm)`` / ``(nb, bk)``, rowwise mode: tiles dequantize
         before the dot since B-row scales ride the contraction axis).
-        Gathered per item and streamed as per-step VMEM windows
-        (pipelined) or read per item (legacy).
+        Gathered per item and streamed as per-step VMEM windows.
       a_fetch/b_fetch: (n_items,) int32 DMA fetch flags — 1 where the item
         must copy its A/B tile from HBM, 0 where the resident ring slot is
         reused (see ``repro.core.schedule.fetch_flags``).
       a_slot/b_slot: (n_items,) int32 resident ring-buffer slot per item.
-      pipeline: True = explicit DMA pipeline (requires the four fetch
-        arrays), False = legacy BlockSpec auto-pipeline, None = auto.
       prefetch: accepted for knob-grid uniformity with ``segment_spmm``;
         the SpGEMM grid has no N-tile pass axis, so ``"cross_pass"``
         degenerates to the drained schedule (validated, kernel-side no-op).
@@ -293,11 +227,6 @@ def segment_spgemm(a_blocks, b_blocks, a_idx, b_idx, c_idx, seg_start,
             f"b_scales has shape {b_scales.shape}, expected one fp32 scale "
             f"per stored block ({b_blocks.shape[0]},) or per block row "
             f"({b_blocks.shape[0]}, {bk})")
-    pipeline = resolve_pipeline(pipeline, (a_fetch, b_fetch, a_slot, b_slot))
-    if prefetch is not None and not pipeline:
-        raise ValueError(
-            "prefetch='cross_pass' requires the explicit DMA pipeline "
-            "(pipeline=True)")
     validate_schedule_args(
         n_items, n_lanes, unroll,
         {"a_idx": a_idx, "b_idx": b_idx, "c_idx": c_idx,
@@ -310,12 +239,6 @@ def segment_spgemm(a_blocks, b_blocks, a_idx, b_idx, c_idx, seg_start,
     quant_b = None if b_scales is None else (
         "rowwise" if b_scales.ndim == 2 else "block")
     out_shape = jax.ShapeDtypeStruct((n_c_blocks, bm, bn), out_dtype)
-
-    if not pipeline:
-        return _legacy_spgemm_call(
-            a_blocks, b_blocks, a_idx, b_idx, c_idx, seg_start, seg_write,
-            accum_prev, valid, a_scales, b_scales, out_shape, lane_len,
-            n_lanes, bm, bk, bn, unroll, masked, quant_a, quant_b, interpret)
 
     depth = 2 * unroll
     n_steps = lane_len // unroll
@@ -370,64 +293,7 @@ def segment_spgemm(a_blocks, b_blocks, a_idx, b_idx, c_idx, seg_start,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        name=kernel_name("segment_spgemm", pipeline=True,
-                         quant_a=quant_a, quant_b=quant_b),
+        name=kernel_name("segment_spgemm", quant_a=quant_a,
+                         quant_b=quant_b),
     )(*scalars, *operands)
 
-
-def _legacy_spgemm_call(a_blocks, b_blocks, a_idx, b_idx, c_idx, seg_start,
-                        seg_write, accum_prev, valid, a_scales, b_scales,
-                        out_shape, lane_len, n_lanes, bm, bk, bn, unroll,
-                        masked, quant_a, quant_b, interpret):
-    """BlockSpec auto-pipeline baseline (see ``_legacy_spmm_call``)."""
-    # index maps absorb the variable scalar-prefetch tail (*rest) so the
-    # optional scale operands don't change their arity
-    def sel(ref_pick, g):
-        return lambda l, s, ai, bi, *rest: (
-            ref_pick(ai, bi)[l * lane_len + s * unroll + g], 0, 0)
-
-    def sel2(ref_pick, g):
-        return lambda l, s, ai, bi, *rest: (
-            ref_pick(ai, bi)[l * lane_len + s * unroll + g], 0)
-
-    in_specs = (
-        [pl.BlockSpec((1, bm, bk), sel(lambda ai, bi: ai, g))
-         for g in range(unroll)]
-        + [pl.BlockSpec((1, bk, bn), sel(lambda ai, bi: bi, g))
-           for g in range(unroll)])
-    if quant_a == "rowwise":
-        in_specs += [pl.BlockSpec((1, bm), sel2(lambda ai, bi: ai, g))
-                     for g in range(unroll)]
-    if quant_b == "rowwise":
-        in_specs += [pl.BlockSpec((1, bk), sel2(lambda ai, bi: bi, g))
-                     for g in range(unroll)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=(7 + int(quant_a == "block")
-                             + int(quant_b == "block")),
-        grid=(n_lanes, lane_len // unroll),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, bm, bn),
-            lambda l, s, ai, bi, ci, *rest: (
-                ci[l * lane_len + s * unroll], 0, 0)),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-    )
-    kernel = _make_legacy_kernel(lane_len, unroll, masked, quant_a, quant_b)
-    prefetch = ((a_idx, b_idx, c_idx, seg_start, seg_write, accum_prev, valid)
-                + ((a_scales,) if quant_a == "block" else ())
-                + ((b_scales,) if quant_b == "block" else ()))
-    operands = [a_blocks] * unroll + [b_blocks] * unroll
-    if quant_a == "rowwise":
-        operands += [a_scales] * unroll
-    if quant_b == "rowwise":
-        operands += [b_scales] * unroll
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        name=kernel_name("segment_spgemm", pipeline=False,
-                         quant_a=quant_a, quant_b=quant_b),
-    )(*prefetch, *operands)

@@ -49,10 +49,6 @@ blocked per grid step — one vector load per step instead of ``unroll``
 serialized SMEM scalar reads — and are applied to the fp32 accumulator
 (dequantization is a kernel-local concern; storage format never leaks into
 the schedule).
-
-``pipeline=False`` keeps the legacy BlockSpec auto-pipeline (operand
-re-fetch decided by Pallas' index-map revisiting rule, scales on the
-scalar-prefetch path) as a baseline for benchmarks and debugging.
 """
 from __future__ import annotations
 
@@ -62,65 +58,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-
-def _make_legacy_kernel(lane_len: int, unroll: int, transpose_lhs: bool,
-                        masked: bool, quant: str | None):
-    contract = (((0,), (0,)), ((), ())) if transpose_lhs \
-        else (((1,), (0,)), ((), ()))
-
-    def _kernel(slot_idx, m_idx, k_idx, seg_start, seg_write, accum_prev,
-                valid, *refs):
-        if quant == "block":
-            a_scales, refs = refs[0], refs[1:]
-        a_refs = refs[:unroll]
-        b_refs = refs[unroll:2 * unroll]
-        if quant == "rowwise":
-            s_refs = refs[2 * unroll:3 * unroll]
-            out = refs[3 * unroll]
-            acc = refs[3 * unroll + 1]
-        else:
-            out = refs[2 * unroll]
-            acc = refs[2 * unroll + 1]
-        base = pl.program_id(0) * lane_len + pl.program_id(2) * unroll
-        for g in range(unroll):
-            i = base + g
-
-            @pl.when(seg_start[i] == 1)
-            def _init(i=i):
-                @pl.when(accum_prev[i] == 1)
-                def _load():    # folded continuation: merge with prior partial
-                    acc[...] = out[...].astype(jnp.float32)
-
-                @pl.when(accum_prev[i] == 0)
-                def _zero():
-                    acc[...] = jnp.zeros_like(acc)
-
-            a_tile = a_refs[g][0].astype(jnp.float32)
-            if quant == "rowwise":
-                # Per-row scales do NOT commute with a contraction over the
-                # tile's row axis (transpose_lhs), so the tile is dequantized
-                # *before* the dot — exact in both orientations.
-                a_tile = a_tile * s_refs[g][0][:, None]
-            contrib = jax.lax.dot_general(
-                a_tile,
-                b_refs[g][...].astype(jnp.float32),
-                dimension_numbers=contract,
-                preferred_element_type=jnp.float32)
-            if quant == "block":
-                # Per-block scale is a scalar factor of the whole tile, so
-                # applying it to the fp32 product (after the MXU dot) is
-                # algebraically exact: (s·Aq) @ B == s · (Aq @ B).
-                contrib = contrib * a_scales[slot_idx[i]]
-            if masked:
-                contrib = jnp.where(valid[i] == 1, contrib, 0.0)
-            acc[...] += contrib
-
-            @pl.when(seg_write[i] == 1)
-            def _write(i=i):
-                out[...] = acc[...].astype(out.dtype)
-
-    return _kernel
 
 
 def _make_pipeline_kernel(lane_len: int, unroll: int, transpose_lhs: bool,
@@ -289,15 +226,15 @@ def _make_pipeline_kernel(lane_len: int, unroll: int, transpose_lhs: bool,
     return _kernel
 
 
-def kernel_name(op: str, *, pipeline: bool, transpose_lhs: bool = False,
+def kernel_name(op: str, *, transpose_lhs: bool = False,
                 quant_a: str | None = None, quant_b: str | None = None,
                 prefetch: str | None = None) -> str:
     """The ``pallas_call`` name of one kernel variant: the op, then
-    ``pipeline``/``legacy``, ``tlhs`` for ``transpose_lhs``, ``qa<mode>`` /
-    ``qb<mode>`` for a quantized A / B operand and ``xpass`` for cross-pass
-    prefetch (``segment_spmm_pipeline_qablock``).  It names the kernel's
-    custom call in the compiled program, and so its profiler events."""
-    parts = [op, "pipeline" if pipeline else "legacy"]
+    ``pipeline``, ``tlhs`` for ``transpose_lhs``, ``qa<mode>`` / ``qb<mode>``
+    for a quantized A / B operand and ``xpass`` for cross-pass prefetch
+    (``segment_spmm_pipeline_qablock``).  It names the kernel's custom call
+    in the compiled program, and so its profiler events."""
+    parts = [op, "pipeline"]
     if transpose_lhs:
         parts.append("tlhs")
     if quant_a is not None:
@@ -313,7 +250,11 @@ def validate_schedule_args(n_items, n_lanes, unroll, arrays):
     """Shared scalar-prefetch schedule validation for both Segment kernels."""
     for name, arr in arrays.items():
         if arr is None:
-            continue
+            raise ValueError(
+                f"{name} is missing: the Segment kernels need every schedule "
+                f"array (precompute the fetch arrays via "
+                f"repro.core.schedule.fetch_flags, or build the schedule "
+                f"through repro.api.plan_matmul)")
         if arr.shape != (n_items,):
             raise ValueError(
                 f"{name} has shape {arr.shape}, expected ({n_items},) to "
@@ -327,36 +268,16 @@ def validate_schedule_args(n_items, n_lanes, unroll, arrays):
                          f"by unroll={unroll}")
 
 
-def resolve_pipeline(pipeline, fetch_arrays) -> bool:
-    """Resolve the ``pipeline`` switch against the fetch-flag arrays.
-
-    ``None`` auto-selects: pipelined iff the flags were supplied (plans
-    built by ``repro.api`` always carry them; hand-built schedules without
-    flags fall back to the BlockSpec auto-pipeline).  An explicit ``True``
-    without the arrays is an error, not a silent downgrade.
-    """
-    have = [a is not None for a in fetch_arrays]
-    if pipeline is None:
-        pipeline = all(have)
-    if pipeline and not all(have):
-        raise ValueError(
-            "pipeline=True needs the a_fetch/b_fetch/a_slot/b_slot schedule "
-            "arrays (precompute them via repro.core.schedule.fetch_flags, "
-            "or build the schedule through repro.api.plan_matmul)")
-    return pipeline
-
-
 @functools.partial(
     jax.jit,
     static_argnames=("grid_m", "n_lanes", "bn", "unroll", "transpose_lhs",
-                     "masked", "interpret", "out_dtype", "pipeline",
-                     "prefetch"))
+                     "masked", "interpret", "out_dtype", "prefetch"))
 def segment_spmm(a_blocks, slot_idx, m_idx, k_idx, seg_start, seg_write,
                  accum_prev, valid, b_dense, *, grid_m: int, n_lanes: int = 1,
                  bn: int = 512, unroll: int = 1, transpose_lhs: bool = False,
                  masked: bool = True, interpret: bool = False,
                  out_dtype=jnp.float32, a_scales=None, a_fetch=None,
-                 b_fetch=None, a_slot=None, b_slot=None, pipeline=None,
+                 b_fetch=None, a_slot=None, b_slot=None,
                  prefetch: str | None = None):
     """Compute ``C = BSR(A) @ B`` (or ``BSR(A)ᵀ @ B``) under a lane-parallel
     Segment schedule with an explicit double-buffered DMA pipeline.
@@ -388,21 +309,17 @@ def segment_spmm(a_blocks, slot_idx, m_idx, k_idx, seg_start, seg_write,
         ``(n_blocks,)`` applies one scale per block to the fp32 product;
         ``(n_blocks, bm)`` (rowwise mode) dequantizes each A tile row
         *before* the dot, which stays exact under ``transpose_lhs``.
-        Gathered per item and streamed as a per-step VMEM window
-        (pipelined) or read via ``slot_idx`` (legacy).
+        Gathered per item and streamed as a per-step VMEM window.
       a_fetch/b_fetch: (n_items,) int32 DMA fetch flags — 1 where the item
         must copy its A tile / B row-tile from HBM, 0 where the resident
         ring slot is reused (see ``repro.core.schedule.fetch_flags``).
       a_slot/b_slot: (n_items,) int32 resident ring-buffer slot per item.
-      pipeline: True = explicit DMA pipeline (requires the four fetch
-        arrays), False = legacy BlockSpec auto-pipeline, None = auto
-        (pipelined iff the arrays are present).
       prefetch: ``None`` drains the DMA pipeline at every (lane, N-tile)
         pass boundary; ``"cross_pass"`` issues pass ``j+1``'s first copies
         (B row-tiles before A tiles) during pass ``j``'s tail step, so a
-        multi-N-tile grid never stalls on a pass restart.  Requires the
-        explicit pipeline; the mode changes only *when* lane-first copies
-        issue, never which items fetch, so results are bit-identical.
+        multi-N-tile grid never stalls on a pass restart.  The mode changes
+        only *when* lane-first copies issue, never which items fetch, so
+        results are bit-identical.
         Certified hazard-free per variant by ``repro.analysis.order``.
     Returns:
       (grid_m * row_block, N) dense output.
@@ -430,12 +347,6 @@ def segment_spmm(a_blocks, slot_idx, m_idx, k_idx, seg_start, seg_write,
             f"dense rhs width N={n_dim} (b_dense shape {b_dense.shape}) is "
             f"not divisible by the N-tile width bn={bn}; pad N or pick a "
             f"divisor (see repro.api.pick_bn)")
-    pipeline = resolve_pipeline(pipeline, (a_fetch, b_fetch, a_slot, b_slot))
-    if prefetch is not None and not pipeline:
-        raise ValueError(
-            "prefetch='cross_pass' requires the explicit DMA pipeline "
-            "(pipeline=True); the legacy BlockSpec path has no cross-pass "
-            "copy timing to overlap")
     validate_schedule_args(
         seg_start.shape[0], n_lanes, unroll,
         {"slot_idx": slot_idx, "m_idx": m_idx, "k_idx": k_idx,
@@ -448,13 +359,6 @@ def segment_spmm(a_blocks, slot_idx, m_idx, k_idx, seg_start, seg_write,
     quant = None if a_scales is None else (
         "rowwise" if a_scales.ndim == 2 else "block")
     out_shape = jax.ShapeDtypeStruct((grid_m * row_blk, n_dim), out_dtype)
-
-    if not pipeline:
-        return _legacy_spmm_call(
-            a_blocks, slot_idx, m_idx, k_idx, seg_start, seg_write,
-            accum_prev, valid, b_dense, a_scales, out_shape, lane_len,
-            n_lanes, n_tiles_n, bm, bk, row_blk, contract_blk, bn, unroll,
-            transpose_lhs, masked, quant, interpret)
 
     depth = 2 * unroll
     n_steps = lane_len // unroll
@@ -509,66 +413,7 @@ def segment_spmm(a_blocks, slot_idx, m_idx, k_idx, seg_start, seg_write,
         out_shape=out_shape,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
-        name=kernel_name("segment_spmm", pipeline=True,
-                         transpose_lhs=transpose_lhs, quant_a=quant,
-                         prefetch=prefetch),
+        name=kernel_name("segment_spmm", transpose_lhs=transpose_lhs,
+                         quant_a=quant, prefetch=prefetch),
     )(*scalars, *operands)
 
-
-def _legacy_spmm_call(a_blocks, slot_idx, m_idx, k_idx, seg_start, seg_write,
-                      accum_prev, valid, b_dense, a_scales, out_shape,
-                      lane_len, n_lanes, n_tiles_n, bm, bk, row_blk,
-                      contract_blk, bn, unroll, transpose_lhs, masked,
-                      quant, interpret):
-    """BlockSpec auto-pipeline baseline (operand re-fetch decided by the
-    index-map revisiting rule; per-block scales on the scalar-prefetch
-    path, rowwise scale rows on per-item VMEM windows).  Kept for
-    benchmarking the explicit DMA pipeline against and for schedules built
-    without fetch flags."""
-    # index maps absorb the variable scalar-prefetch tail (*rest) so the
-    # optional a_scales operand doesn't change their arity
-    def a_map(g):
-        return lambda l, j, s, slot, *rest: (
-            slot[l * lane_len + s * unroll + g], 0, 0)
-
-    def b_map(g):
-        return lambda l, j, s, slot, m, k, *rest: (
-            k[l * lane_len + s * unroll + g], j)
-
-    def s_map(g):
-        return lambda l, j, s, slot, *rest: (
-            slot[l * lane_len + s * unroll + g], 0)
-
-    in_specs = (
-        [pl.BlockSpec((1, bm, bk), a_map(g)) for g in range(unroll)]
-        + [pl.BlockSpec((contract_blk, bn), b_map(g))
-           for g in range(unroll)])
-    if quant == "rowwise":
-        in_specs += [pl.BlockSpec((1, bm), s_map(g)) for g in range(unroll)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=8 if quant == "block" else 7,
-        grid=(n_lanes, n_tiles_n, lane_len // unroll),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (row_blk, bn),
-            lambda l, j, s, slot, m, *rest: (
-                m[l * lane_len + s * unroll], j)),
-        scratch_shapes=[pltpu.VMEM((row_blk, bn), jnp.float32)],
-    )
-    kernel = _make_legacy_kernel(lane_len, unroll, transpose_lhs, masked,
-                                 quant)
-    prefetch = (slot_idx, m_idx, k_idx, seg_start, seg_write, accum_prev,
-                valid) + ((a_scales,) if quant == "block" else ())
-    operands = [a_blocks] * unroll + [b_dense] * unroll
-    if quant == "rowwise":
-        operands += [a_scales] * unroll
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        name=kernel_name("segment_spmm", pipeline=False,
-                         transpose_lhs=transpose_lhs, quant_a=quant),
-    )(*prefetch, *operands)

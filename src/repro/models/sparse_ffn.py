@@ -71,8 +71,7 @@ class SparseLinear:
         accumulator, and gradients to the weights stop (x-gradients still
         flow, so the layer composes under ``jax.grad`` of downstream
         losses).  The source plan's full planner configuration — lanes,
-        unroll, backend, ``pipeline`` and the tuned ``bn_hint`` — is
-        carried over.  ``fold_len`` is the one knob a plan does not record;
+        unroll, backend and the tuned ``bn_hint`` — is carried over.  ``fold_len`` is the one knob a plan does not record;
         a fold-built plan (any ``accum_prev`` item set) raises rather than
         silently re-planning without the fold.  Returns ``(layer, params)``
         like :meth:`create`.
@@ -100,7 +99,6 @@ class SparseLinear:
         plan = plan_matmul(w, policy=self.plan.policy, with_grad=True,
                            quantize=dtype, n_lanes=self.plan.n_lanes,
                            unroll=self.plan.unroll, backend=self.plan.backend,
-                           pipeline=self.plan.pipeline,
                            bn_hint=self.plan.bn_hint)
         layer = SparseLinear(plan=plan, d_out=self.d_out, d_in=self.d_in)
         return layer, {"blocks": plan.lhs_blocks, "scales": plan.lhs_scales}

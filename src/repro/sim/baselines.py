@@ -226,7 +226,7 @@ def dataflow_estimates(kind: str, *, bm: int, bk: int,
     ``kind="spmm"``; ``m``/``n``/``k``/``c``/``a_idx``/``b_idx`` for
     ``kind="spgemm"``.  Returns ``{name: traffic_dict}`` with
     :func:`repro.core.schedule.lane_traffic_spmm`-shaped dicts priced at
-    default knobs (one lane, pipelined), so entries are directly comparable
+    default knobs (one lane), so entries are directly comparable
     with each other and with a built plan's recorded traffic."""
     from repro.core.policies import available_policies, get_policy
     tiles = dict(bm=bm, bk=bk, bytes_per_el=bytes_per_el)

@@ -5,7 +5,7 @@ One candidate schedule's predicted wall time is::
     cost_us = traffic_bytes / bytes_per_us + steps * step_us
 
 — a two-term roofline: the revisiting-model HBM bytes of the schedule
-(lane-aware, pipeline-aware; see :func:`repro.core.schedule.lane_traffic_spmm`)
+(lane-aware; see :func:`repro.core.schedule.lane_traffic_spmm`)
 over an effective bandwidth, plus a per-grid-step overhead term that prices
 grid launch/bookkeeping.  Imbalance and padding need no separate penalty
 knob: pads occupy grid steps (``steps`` counts the *padded* lane length) and
@@ -17,11 +17,6 @@ move no bytes, so an imbalanced lane split pays exactly its idle steps.
   concurrently; a step costs one unit regardless of ``n_lanes``.
 * ``False`` — the interpret backend (and any fully sequential executor)
   runs the whole grid serially; steps scale with ``n_lanes``.
-
-``legacy_factor`` scales the whole cost for ``pipeline=False`` candidates:
-the legacy auto-pipelined kernels execute the same schedule through a
-different (slower, in interpret mode) data path, which bytes and steps
-alone cannot express.
 
 The two shipped defaults were fixed once against ``BENCH_kernels.json``
 interpret timings (see ``benchmarks/kernel_bench.py::autotune_sweep``, which
@@ -43,7 +38,6 @@ class CostModel:
     bytes_per_us: float          # effective HBM bandwidth, bytes / microsecond
     step_us: float               # per-grid-step overhead, microseconds
     lane_parallel: bool = True   # False: lanes execute sequentially
-    legacy_factor: float = 1.0   # cost multiplier for pipeline=False plans
     # fraction of one step's overhead that prefetch="cross_pass" saves at
     # each of the (n_tiles_n - 1) pass boundaries: real hardware overlaps
     # the boundary pipeline drain with the previous pass's tail compute
@@ -64,16 +58,16 @@ class CostModel:
 
     def cost_us(self, *, traffic_bytes: float, n_lanes: int, lane_len: int,
                 unroll: int, n_tiles_n: int = 1,
-                pipelined: bool = True, prefetch: bool = False) -> float:
+                prefetch: bool = False) -> float:
         base = (traffic_bytes / self.bytes_per_us
                 + self.steps(n_lanes=n_lanes, lane_len=lane_len,
                              unroll=unroll, n_tiles_n=n_tiles_n)
                 * self.step_us)
-        if prefetch and pipelined and n_tiles_n > 1:
+        if prefetch and n_tiles_n > 1:
             # cross-pass prefetch hides one boundary drain per N-tile
             # transition (worth a step_us fraction set by the model)
             base -= (n_tiles_n - 1) * self.step_us * self.prefetch_step_credit
-        return base if pipelined else base * self.legacy_factor
+        return base
 
 
 def calibrate(samples: Iterable[Tuple[float, float, float]],
@@ -120,10 +114,6 @@ DEFAULT_TPU = CostModel(bytes_per_us=8.0e5, step_us=0.5, lane_parallel=True,
 #: interpret-backend model, fixed against BENCH_kernels.json timings
 #: (autotune_sweep refits and reports both coefficient sets every run):
 #: the interpreter streams ~2 KB/us and pays ~10 us of emulation per grid
-#: step, and the whole grid — lanes included — runs sequentially.  The
-#: legacy auto-pipelined kernels (pipeline=False plans) emulate ~4x
-#: slower still — 2*unroll BlockSpec streams cost far more than the
-#: explicit pipeline's two ANY operands — so a legacy candidate must cut
-#: modeled cost 4x before it can win the interpret objective.
+#: step, and the whole grid — lanes included — runs sequentially.
 DEFAULT_INTERPRET = CostModel(bytes_per_us=2.0e3, step_us=10.0,
-                              lane_parallel=False, legacy_factor=4.0)
+                              lane_parallel=False)

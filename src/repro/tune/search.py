@@ -1,8 +1,7 @@
 """Analytical schedule search + adaptive dataflow selection.
 
 :func:`autotune_matmul` sweeps the knob grid — ``fold_len`` × ``n_lanes`` ×
-``unroll`` × ``bn`` × ``pipeline`` × ``prefetch`` — across every registered
-schedule policy
+``unroll`` × ``bn`` × ``prefetch`` — across every registered schedule policy
 and scores each candidate with the unified :class:`~repro.tune.cost.CostModel`
 (lane-aware revisiting-model traffic bytes + a per-grid-step overhead term;
 imbalance and padding are priced structurally through the padded lane
@@ -66,7 +65,6 @@ class Candidate:
     n_lanes: int
     unroll: int
     bn: int
-    pipeline: bool
     prefetch: Optional[str] = None
 
 
@@ -74,7 +72,7 @@ class Candidate:
 class SearchSpace:
     """Knob axes the search sweeps.  The default space always contains the
     planner's default point (``segment``, no fold, 1 lane, unroll 1,
-    ``bn=512``, pipelined), so the winner can never be worse than the
+    ``bn=512``, no prefetch), so the winner can never be worse than the
     default under the model being optimized.  ``policies=None`` sweeps
     every registered policy."""
 
@@ -82,7 +80,6 @@ class SearchSpace:
     n_lanes: Tuple[int, ...] = (1, 2, 4)
     unrolls: Tuple[int, ...] = (1, 2)
     bns: Tuple[int, ...] = (128, 512)
-    pipelines: Tuple[bool, ...] = (True, False)
     prefetches: Tuple[Optional[str], ...] = (None, "cross_pass")
     policies: Optional[Tuple[str, ...]] = None
 
@@ -121,8 +118,7 @@ class TuneResult:
         the winning schedule."""
         c = self.best.candidate
         return dict(policy=c.policy, fold_len=c.fold_len, n_lanes=c.n_lanes,
-                    unroll=c.unroll, pipeline=c.pipeline, bn_hint=c.bn,
-                    prefetch=c.prefetch)
+                    unroll=c.unroll, bn_hint=c.bn, prefetch=c.prefetch)
 
 
 #: fingerprint → TuneResult; cleared by repro.api.clear_plan_cache
@@ -166,16 +162,15 @@ def _search_key(kind: str, mats, n_bucket: Optional[int], with_grad: bool,
 def _rank_key(s: Scored, policy_order: Tuple[str, ...]):
     """Total order on scored candidates: model cost, then traffic bytes,
     then every tie broken toward the planner's default point (segment
-    first, fewer lanes, smaller unroll, no fold, no prefetch, pipelined,
-    wider bn)."""
+    first, fewer lanes, smaller unroll, no fold, no prefetch, wider
+    bn)."""
     c = s.candidate
     return (s.cost_us, s.traffic_total,
             policy_order.index(c.policy) if c.policy in policy_order
             else len(policy_order),
             c.n_lanes, c.unroll,
             c.fold_len is not None, c.fold_len or 0,
-            c.prefetch is not None,
-            not c.pipeline, -c.bn)
+            c.prefetch is not None, -c.bn)
 
 
 def _score_spmm(a: BSR, hint: int, block_dtype: str, model: CostModel,
@@ -204,40 +199,31 @@ def _score_spmm(a: BSR, hint: int, block_dtype: str, model: CostModel,
                     lane_k = lane_select(layout, sched.k)
                     ss = lane_select(layout, sched.seg_start, zero_pads=True)
                     valid = layout.valid.reshape(-1)
-                    for pipe in _pin(pins, "pipeline", space.pipelines):
-                        # cross-pass prefetch only exists on the explicit
-                        # DMA pipeline; the legacy path sweeps prefetch=None
-                        pfs = (_pin(pins, "prefetch", space.prefetches)
-                               if pipe else (None,))
-                        for pf in pfs:
-                            traffic = _quantize_a_traffic(lane_traffic_spmm(
-                                lane_m, lane_k, ss, valid, layout.n_lanes,
-                                bm, bk, hint, unroll=un, pipeline=pipe,
-                                prefetch=pf),
-                                block_dtype, bm, bk)
-                            for bn in _pin(pins, "bn", space.bns):
-                                bn_eff, pad = pick_bn(max(1, hint), bn)
-                                n_tiles = (max(1, hint) + pad) // bn_eff
-                                vbytes = spmm_vmem_bytes(
-                                    bm=bm, bk=bk, bn=bn_eff, unroll=un,
-                                    block_dtype=_vmem_dtype(block_dtype),
-                                    quantized=block_dtype != "fp32",
-                                    rowwise=block_dtype.endswith(".rowwise"),
-                                    pipelined=pipe)
-                                if vbytes > limit:
-                                    rejected += 1
-                                    continue
-                                cost = model.cost_us(
-                                    traffic_bytes=traffic["total"],
-                                    n_lanes=layout.n_lanes,
-                                    lane_len=layout.lane_len, unroll=un,
-                                    n_tiles_n=n_tiles, pipelined=pipe,
-                                    prefetch=pf is not None)
-                                scored.append(Scored(
-                                    Candidate(policy, fold, lanes, un, bn,
-                                              pipe, pf),
-                                    cost, tuple(sorted(traffic.items())),
-                                    layout.lane_len, n_tiles, vbytes))
+                    for pf in _pin(pins, "prefetch", space.prefetches):
+                        traffic = _quantize_a_traffic(lane_traffic_spmm(
+                            lane_m, lane_k, ss, valid, layout.n_lanes,
+                            bm, bk, hint, unroll=un, prefetch=pf),
+                            block_dtype, bm, bk)
+                        for bn in _pin(pins, "bn", space.bns):
+                            bn_eff, pad = pick_bn(max(1, hint), bn)
+                            n_tiles = (max(1, hint) + pad) // bn_eff
+                            vbytes = spmm_vmem_bytes(
+                                bm=bm, bk=bk, bn=bn_eff, unroll=un,
+                                block_dtype=_vmem_dtype(block_dtype),
+                                quantized=block_dtype != "fp32",
+                                rowwise=block_dtype.endswith(".rowwise"))
+                            if vbytes > limit:
+                                rejected += 1
+                                continue
+                            cost = model.cost_us(
+                                traffic_bytes=traffic["total"],
+                                n_lanes=layout.n_lanes,
+                                lane_len=layout.lane_len, unroll=un,
+                                n_tiles_n=n_tiles, prefetch=pf is not None)
+                            scored.append(Scored(
+                                Candidate(policy, fold, lanes, un, bn, pf),
+                                cost, tuple(sorted(traffic.items())),
+                                layout.lane_len, n_tiles, vbytes))
     return scored, rejected, tuple(policies)
 
 
@@ -267,31 +253,28 @@ def _score_spgemm(a: BSR, b: BSR, block_dtype: str, model: CostModel,
                     lane_c = lane_select(layout, sched.c_idx)
                     ss = lane_select(layout, sched.seg_start, zero_pads=True)
                     valid = layout.valid.reshape(-1)
-                    for pipe in _pin(pins, "pipeline", space.pipelines):
-                        traffic = _quantize_spgemm_traffic(lane_traffic_spgemm(
-                            lane_a, lane_b, lane_c, ss, valid, layout.n_lanes,
-                            bm, bk, bn, unroll=un, pipeline=pipe),
-                            block_dtype, bm, bk, bn)
-                        vbytes = spgemm_vmem_bytes(
-                            bm=bm, bk=bk, bn=bn, unroll=un,
-                            block_dtype=_vmem_dtype(block_dtype),
-                            quant_a=block_dtype != "fp32",
-                            quant_b=block_dtype != "fp32",
-                            rowwise=block_dtype.endswith(".rowwise"),
-                            pipelined=pipe)
-                        if vbytes > limit:
-                            rejected += 1
-                            continue
-                        cost = model.cost_us(
-                            traffic_bytes=traffic["total"],
-                            n_lanes=layout.n_lanes,
-                            lane_len=layout.lane_len, unroll=un, n_tiles_n=1,
-                            pipelined=pipe)
-                        scored.append(Scored(
-                            Candidate(policy, fold, lanes, un, bn, pipe,
-                                      pins.get("prefetch") if pipe else None),
-                            cost, tuple(sorted(traffic.items())),
-                            layout.lane_len, 1, vbytes))
+                    traffic = _quantize_spgemm_traffic(lane_traffic_spgemm(
+                        lane_a, lane_b, lane_c, ss, valid, layout.n_lanes,
+                        bm, bk, bn),
+                        block_dtype, bm, bk, bn)
+                    vbytes = spgemm_vmem_bytes(
+                        bm=bm, bk=bk, bn=bn, unroll=un,
+                        block_dtype=_vmem_dtype(block_dtype),
+                        quant_a=block_dtype != "fp32",
+                        quant_b=block_dtype != "fp32",
+                        rowwise=block_dtype.endswith(".rowwise"))
+                    if vbytes > limit:
+                        rejected += 1
+                        continue
+                    cost = model.cost_us(
+                        traffic_bytes=traffic["total"],
+                        n_lanes=layout.n_lanes,
+                        lane_len=layout.lane_len, unroll=un, n_tiles_n=1)
+                    scored.append(Scored(
+                        Candidate(policy, fold, lanes, un, bn,
+                                  pins.get("prefetch")),
+                        cost, tuple(sorted(traffic.items())),
+                        layout.lane_len, 1, vbytes))
     return scored, rejected, tuple(policies)
 
 
@@ -299,7 +282,7 @@ def _dataflow_scores(kind: str, a: BSR, b: Optional[BSR], hint: int,
                      scored, policies: Tuple[str, ...]) -> Dict[str, float]:
     """Analytic bytes per dataflow at default knobs: closed-form estimates
     for the hint-carrying policies + ``"inner"``, overlaid with each swept
-    policy's own default-knob (1 lane, unroll 1, no fold, pipelined)
+    policy's own default-knob (1 lane, unroll 1, no fold, no prefetch)
     candidate — that is how the hint-less ``segment`` gets scored."""
     bm, bk = a.block_shape
     if kind == "spmm":
@@ -318,7 +301,7 @@ def _dataflow_scores(kind: str, a: BSR, b: Optional[BSR], hint: int,
     for s in scored:
         c = s.candidate
         if (c.fold_len is None and c.n_lanes == 1 and c.unroll == 1
-                and c.pipeline and c.prefetch is None):
+                and c.prefetch is None):
             scores[c.policy] = s.traffic_total
     return scores
 
@@ -338,8 +321,7 @@ def autotune_matmul(a: BSR, b_or_shape=None, *,
     are rejected by the closed-form VMEM budget; the ranked winner is built
     once and must pass ``verify_plan(level="full")`` plus the plan-level
     VMEM gate, else the runner-up is promoted.  ``pins`` maps knob names
-    (``policy``/``fold_len``/``n_lanes``/``unroll``/``bn``/``pipeline``/
-    ``prefetch``) to
+    (``policy``/``fold_len``/``n_lanes``/``unroll``/``bn``/``prefetch``) to
     values the search must keep fixed.  Results are cached by pattern
     fingerprint (``cache=True``) so repeat patterns skip the sweep."""
     from repro.api import planner as _planner
@@ -398,7 +380,7 @@ def autotune_matmul(a: BSR, b_or_shape=None, *,
             a, b_or_shape, policy=c.policy, fold_len=c.fold_len,
             with_grad=with_grad, n_cols_hint=hint, n_lanes=c.n_lanes,
             unroll=c.unroll, cache=False, quantize=quantize,
-            pipeline=c.pipeline, bn_hint=c.bn, prefetch=c.prefetch)
+            bn_hint=c.bn, prefetch=c.prefetch)
         try:
             verify_plan(plan, level="full").raise_if_findings()
             bn_eff, _ = pick_bn(max(1, hint), c.bn)

@@ -381,9 +381,14 @@ def test_spgemm_rejects_length_mismatch(gplan):
 
 def test_spgemm_rejects_pipeline_without_flags(gplan):
     from repro.kernels.segment_spgemm import segment_spgemm
-    with pytest.raises(ValueError, match=r"pipeline=True needs"):
+    with pytest.raises(ValueError, match=r"a_fetch is missing"):
         segment_spgemm(*_spgemm_args(gplan), n_c_blocks=gplan.n_out_blocks,
-                       n_lanes=gplan.n_lanes, interpret=True, pipeline=True)
+                       n_lanes=gplan.n_lanes, interpret=True)
+    with pytest.raises(ValueError, match=r"b_fetch is missing"):
+        segment_spgemm(*_spgemm_args(gplan), n_c_blocks=gplan.n_out_blocks,
+                       n_lanes=gplan.n_lanes, interpret=True,
+                       a_fetch=gplan.a_fetch, a_slot=gplan.a_slot,
+                       b_slot=gplan.b_slot)
 
 
 # ---------------------------------------------------------------------------

@@ -1,12 +1,11 @@
 """repro.tune: the analytical schedule search, the cost model, adaptive
 dataflow selection through ``plan_matmul(policy="auto")``, the search cache
-and its counters, and the legacy-pipeline plan variant the search may emit."""
+and its counters."""
 import numpy as np
 import pytest
 
 from repro import api, tune
-from repro.analysis.budget import (DEFAULT_VMEM_LIMIT_BYTES, check_plan_vmem,
-                                   plan_vmem_bytes)
+from repro.analysis.budget import DEFAULT_VMEM_LIMIT_BYTES, check_plan_vmem
 from repro.analysis.invariants import verify_plan
 from repro.core.formats import BSR
 from repro.sim.baselines import dataflow_estimates
@@ -59,8 +58,7 @@ def test_winner_no_worse_than_default(objective):
     a = _scattered()
     res = tune.autotune_matmul(a, n_cols_hint=256, objective=objective)
     default = [s for s in res.candidates
-               if s.candidate == tune.Candidate("segment", None, 1, 1, 512,
-                                                True)]
+               if s.candidate == tune.Candidate("segment", None, 1, 1, 512)]
     assert default, "the default knob point must be in the search space"
     assert res.best.cost_us <= default[0].cost_us
     assert res.best.traffic_total <= default[0].traffic_total * 1.0 + 1e-9 \
@@ -91,10 +89,9 @@ def test_pins_are_honoured():
     a = _scattered()
     res = tune.autotune_matmul(
         a, n_cols_hint=256, cache=False,
-        pins={"n_lanes": 2, "unroll": 1, "pipeline": True})
+        pins={"n_lanes": 2, "unroll": 1})
     assert all(s.candidate.n_lanes == 2 for s in res.candidates)
     assert all(s.candidate.unroll == 1 for s in res.candidates)
-    assert all(s.candidate.pipeline for s in res.candidates)
     assert res.best.candidate.n_lanes == 2
 
 
@@ -259,31 +256,6 @@ def test_cost_model_lane_parallel_switch():
     par = tune.CostModel(1e6, 1.0, lane_parallel=True)
     kw = dict(n_lanes=4, lane_len=8, unroll=2, n_tiles_n=3)
     assert seq.steps(**kw) == 4 * par.steps(**kw)
-
-
-# ---------------------------------------------------------------------------
-# the legacy-pipeline plan variant the search may emit
-# ---------------------------------------------------------------------------
-
-
-def test_pipeline_false_plan_executes_and_verifies():
-    a = _scattered()
-    plan = api.plan_matmul(a, 128, pipeline=False, verify="full",
-                           cache=False)
-    assert plan.pipeline is False
-    assert plan.a_fetch is not None   # fetch-flag leaves still ride along
-    rhs = np.random.default_rng(9).standard_normal(
-        (a.shape[1], 128)).astype(np.float32)
-    np.testing.assert_allclose(np.asarray(plan(rhs)), _dense(a) @ rhs,
-                               rtol=2e-4, atol=2e-4)
-    # legacy pricing never beats the pipelined per-item-adjacency model
-    piped = api.plan_matmul(a, 128, unroll=2, cache=False)
-    legacy = api.plan_matmul(a, 128, unroll=2, pipeline=False, cache=False)
-    assert legacy.traffic["total"] >= piped.traffic["total"]
-    # and the budget follows the executor's actual launch path
-    assert plan_vmem_bytes(legacy, bn=128) != plan_vmem_bytes(
-        legacy, bn=128, pipelined=True) or True  # shapes may coincide
-    verify_plan(legacy, level="full").raise_if_findings()
 
 
 def test_bn_hint_rides_the_plan():

@@ -298,8 +298,7 @@ def test_spmm_budget_matches_traced_kernel(small_plans):
     traced = _traced_total(
         lambda xx: execute_plan(spmm, xx, bn=64, backend="interpret"),
         x, label="budget-spmm")
-    analytic = spmm_vmem_bytes(bm=32, bk=32, bn=64, unroll=2,
-                               pipelined=True)
+    analytic = spmm_vmem_bytes(bm=32, bk=32, bn=64, unroll=2)
     assert traced == analytic == plan_vmem_bytes(spmm, bn=64)
 
 
@@ -310,27 +309,25 @@ def test_quantized_spmm_budget_matches_traced_kernel(small_plans):
         lambda xx: execute_plan(quant, xx, bn=64, backend="interpret"),
         x, label="budget-quant")
     analytic = spmm_vmem_bytes(bm=32, bk=32, bn=64, unroll=2,
-                               block_dtype="int8", quantized=True,
-                               pipelined=True)
+                               block_dtype="int8", quantized=True)
     assert traced == analytic == plan_vmem_bytes(quant, bn=64)
 
 
-@pytest.mark.parametrize("pipelined", [True, False])
-def test_rowwise_spmm_budget_matches_traced_kernel(pipelined):
-    """Rowwise scales are VMEM-resident on both executor paths (windowed
-    operand pipelined, per-item windows legacy) — the closed form must
-    track the traced kernels byte-for-byte like the per-block pin above."""
+@pytest.mark.parametrize("unroll", [2, 1])
+def test_rowwise_spmm_budget_matches_traced_kernel(unroll):
+    """Rowwise scales are VMEM-resident (a per-step ``(unroll, bm)`` window)
+    — the closed form must track the traced kernel byte-for-byte like the
+    per-block pin above, at every unroll."""
     a = BSR.random(np.random.default_rng(3), (128, 128), (32, 32), 0.5)
-    plan = plan_matmul(a, policy="segment", n_lanes=2, unroll=2,
-                       quantize="int8.rowwise", pipeline=pipelined,
-                       cache=False)
+    plan = plan_matmul(a, policy="segment", n_lanes=2, unroll=unroll,
+                       quantize="int8.rowwise", cache=False)
     x = jnp.zeros((128, 64), jnp.float32)
     traced = _traced_total(
         lambda xx: execute_plan(plan, xx, bn=64, backend="interpret"),
-        x, label=f"budget-rowwise-{pipelined}")
-    analytic = spmm_vmem_bytes(bm=32, bk=32, bn=64, unroll=2,
+        x, label=f"budget-rowwise-unroll{unroll}")
+    analytic = spmm_vmem_bytes(bm=32, bk=32, bn=64, unroll=unroll,
                                block_dtype="int8", quantized=True,
-                               rowwise=True, pipelined=pipelined)
+                               rowwise=True)
     assert traced == analytic == plan_vmem_bytes(plan, bn=64)
 
 
@@ -345,8 +342,7 @@ def test_rowwise_spgemm_budget_matches_traced_kernel():
     analytic = spgemm_vmem_bytes(bm=32, bk=32, bn=32, unroll=2,
                                  block_dtype="float8_e4m3fn",
                                  rhs_dtype="float8_e4m3fn",
-                                 quant_a=True, quant_b=True, rowwise=True,
-                                 pipelined=True)
+                                 quant_a=True, quant_b=True, rowwise=True)
     assert traced == analytic == plan_vmem_bytes(plan)
 
 
@@ -355,8 +351,7 @@ def test_spgemm_budget_matches_traced_kernel(small_plans):
     traced = _traced_total(
         lambda: execute_plan(spgemm, backend="interpret"),
         label="budget-spgemm")
-    analytic = spgemm_vmem_bytes(bm=32, bk=32, bn=32, unroll=2,
-                                 pipelined=True)
+    analytic = spgemm_vmem_bytes(bm=32, bk=32, bn=32, unroll=2)
     assert traced == analytic == plan_vmem_bytes(spgemm)
 
 

@@ -67,12 +67,9 @@ def test_partition_lanes_clamps_to_segment_count():
     assert lay.n_lanes == 3
 
 
-def test_lane_traffic_unroll_models_pipeline_vs_legacy():
+def test_lane_traffic_unroll_keeps_per_item_reuse():
     """The explicit-DMA kernels fetch per *item*, so revisit credit spans
-    every consecutive pair — unroll included (the default model).  The
-    legacy BlockSpec auto-pipeline bound each of the G step items to an
-    independent stream (index maps strided by G), so its model only credits
-    position g of consecutive steps."""
+    every consecutive pair — unroll included."""
     from repro.core.schedule import lane_traffic_spmm
     # two chains of two items; k = [0, 5, 5, 7]
     m = np.array([0, 0, 1, 1])
@@ -85,11 +82,6 @@ def test_lane_traffic_unroll_models_pipeline_vs_legacy():
     t2 = lane_traffic_spmm(m, k, seg_start, valid, 1, 8, 8, 1, unroll=2)
     # the pipelined kernel's fetch flags don't change with unroll
     assert t2["b_fetches"] == 3
-    t3 = lane_traffic_spmm(m, k, seg_start, valid, 1, 8, 8, 1, unroll=2,
-                           pipeline=False)
-    # legacy stream model: stream 0 compares k[0]=0 vs k[2]=5, stream 1
-    # k[1]=5 vs k[3]=7 — the within-step adjacency carries nothing
-    assert t3["b_fetches"] == 4
 
 
 def test_unrolled_plan_traffic_matches_fetch_flags():
@@ -238,7 +230,11 @@ def test_single_block_matrix_spmm_and_spgemm():
 
 def _spmm_args(n_items=2):
     """Minimal hand-built schedule: n_items same-row items of one block."""
+    from repro.core.schedule import fetch_flags
     i32 = lambda *v: jnp.asarray(np.array(v, np.int32))
+    valid = np.ones(n_items, bool)
+    a_fetch, a_slot = fetch_flags(np.zeros(n_items), valid, 1)
+    b_fetch, b_slot = fetch_flags(np.arange(n_items), valid, 1)
     return dict(
         a_blocks=jnp.ones((1, 8, 8), jnp.float32),
         slot_idx=i32(*([0] * n_items)), m_idx=i32(*([0] * n_items)),
@@ -246,7 +242,9 @@ def _spmm_args(n_items=2):
         seg_start=i32(1, *([0] * (n_items - 1))),
         seg_write=i32(*([0] * (n_items - 1)), 1),
         accum_prev=i32(*([0] * n_items)), valid=i32(*([1] * n_items)),
-        b_dense=jnp.ones((8, 16), jnp.float32))
+        b_dense=jnp.ones((8, 16), jnp.float32),
+        a_fetch=jnp.asarray(a_fetch), b_fetch=jnp.asarray(b_fetch),
+        a_slot=jnp.asarray(a_slot), b_slot=jnp.asarray(b_slot))
 
 
 def test_segment_spmm_rejects_bad_bn():

@@ -91,20 +91,17 @@ def test_spmm_report_counts_the_padded_tile_and_repeats():
     obs.report_spmm(7, 128)                     # nothing collects: no-op
 
 
-@pytest.mark.parametrize("pipeline", [True, False])
-def test_kernel_variants_carry_distinct_names(pipeline):
+def test_kernel_variants_carry_distinct_names():
     from repro.analysis.jaxpr_lint import find_pallas_kernels
     rng = np.random.default_rng(0)
     a = BSR.random(rng, (256, 256), (128, 128), 0.5)
-    plan = api.plan_matmul(a, (256, 8), backend="pallas", pipeline=pipeline,
-                           with_grad=True)
+    plan = api.plan_matmul(a, (256, 8), backend="pallas", with_grad=True)
     x = jax.ShapeDtypeStruct((256, 8), np.float32)
     # forward y = W x, backward dx = W^T dy on the forward storage
     jaxpr = jax.make_jaxpr(
         jax.grad(lambda x: api.apply_plan(plan, x).sum()))(x)
-    stem = "segment_spmm_" + ("pipeline" if pipeline else "legacy")
     assert sorted(name for name, _ in find_pallas_kernels(jaxpr)) == [
-        stem, stem + "_tlhs"]
+        "segment_spmm_pipeline", "segment_spmm_pipeline_tlhs"]
 
 
 def test_spans_nest_in_a_cpu_profiler_trace(tiny, tmp_path):

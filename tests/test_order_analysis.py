@@ -506,8 +506,6 @@ def test_plan_matmul_validates_prefetch():
     assert None in PREFETCH_MODES and "cross_pass" in PREFETCH_MODES
     with pytest.raises(ValueError, match="prefetch"):
         plan_matmul(a, cache=False, prefetch="bogus")
-    with pytest.raises(ValueError, match="pipeline"):
-        plan_matmul(a, cache=False, pipeline=False, prefetch="cross_pass")
 
 
 def test_cost_model_prefetch_credit():
@@ -520,9 +518,6 @@ def test_cost_model_prefetch_credit():
     # a single tile has no boundary to hide
     assert m.cost_us(n_tiles_n=1, prefetch=True, **kw) \
         == m.cost_us(n_tiles_n=1, **kw)
-    # the legacy path never earns the credit
-    assert m.cost_us(n_tiles_n=3, pipelined=False, prefetch=True, **kw) \
-        == m.cost_us(n_tiles_n=3, pipelined=False, **kw)
     # shipped defaults: hardware overlaps the drain, the interpreter
     # replays copies inline and must not prefer prefetch on phantom credit
     assert DEFAULT_TPU.prefetch_step_credit == 1.0
@@ -534,11 +529,8 @@ def test_autotune_sweeps_and_pins_prefetch():
     res = autotune_matmul(a, n_cols_hint=256, cache=False)
     swept = {s.candidate.prefetch for s in res.candidates}
     assert swept == {None, "cross_pass"}
-    # cross-pass prefetch only exists on the explicit DMA pipeline
-    assert all(s.candidate.prefetch is None
-               for s in res.candidates if not s.candidate.pipeline)
     # the default knob point still exists (Candidate defaults prefetch=None)
-    assert any(s.candidate == Candidate("segment", None, 1, 1, 512, True)
+    assert any(s.candidate == Candidate("segment", None, 1, 1, 512)
                for s in res.candidates)
     # interpret objective: zero credit + tie-break keep the drained mode
     res_i = autotune_matmul(a, n_cols_hint=256, objective="interpret",
@@ -546,8 +538,7 @@ def test_autotune_sweeps_and_pins_prefetch():
     assert res_i.best.candidate.prefetch is None
     # a pinned knob flows through plan_kwargs into a verified plan
     pinned = autotune_matmul(a, n_cols_hint=256, cache=False,
-                             pins={"pipeline": True,
-                                   "prefetch": "cross_pass"})
+                             pins={"prefetch": "cross_pass"})
     assert pinned.best.candidate.prefetch == "cross_pass"
     kw = pinned.plan_kwargs()
     assert kw["prefetch"] == "cross_pass"

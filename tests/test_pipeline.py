@@ -207,34 +207,24 @@ def test_spgemm_pipeline_parity_quantized():
         assert np.abs(got[i] - blk).max() / norm < 5e-2
 
 
-def test_pipelined_matches_legacy_kernel():
-    """pipeline=True and pipeline=False are the same computation."""
-    from repro.kernels.segment_spmm import segment_spmm
-    a = _patterns()["random"]
-    plan = api.plan_matmul(a, n_lanes=2, unroll=2, fold_len=3)
-    x = jnp.asarray(RNG.standard_normal((a.shape[1], 64)).astype(np.float32))
-    kw = dict(grid_m=plan.grid[0], n_lanes=plan.n_lanes, bn=32,
-              unroll=plan.unroll, masked=True, interpret=True)
-    args = (plan.lhs_blocks, plan.slot_idx, plan.m_idx, plan.k_idx,
-            plan.seg_start, plan.seg_write, plan.accum_prev, plan.valid, x)
-    pip = np.asarray(segment_spmm(
-        *args, **kw, a_fetch=plan.a_fetch, b_fetch=plan.b_fetch,
-        a_slot=plan.a_slot, b_slot=plan.b_slot))
-    leg = np.asarray(segment_spmm(*args, **kw, pipeline=False))
-    np.testing.assert_allclose(pip, leg, rtol=1e-5, atol=1e-5)
-
-
 def test_pipeline_true_requires_fetch_arrays():
     from repro.kernels.segment_spmm import segment_spmm
     a = _patterns()["random"]
     plan = api.plan_matmul(a, n_lanes=2)
     x = jnp.ones((a.shape[1], 32), jnp.float32)
-    with pytest.raises(ValueError, match=r"pipeline=True needs"):
+    # every fetch array is required; the error names the one left out
+    with pytest.raises(ValueError, match=r"b_slot is missing"):
         segment_spmm(plan.lhs_blocks, plan.slot_idx, plan.m_idx, plan.k_idx,
                      plan.seg_start, plan.seg_write, plan.accum_prev,
                      plan.valid, x, grid_m=plan.grid[0],
                      n_lanes=plan.n_lanes, bn=32, interpret=True,
-                     pipeline=True)
+                     a_fetch=plan.a_fetch, b_fetch=plan.b_fetch,
+                     a_slot=plan.a_slot)
+    with pytest.raises(ValueError, match=r"a_fetch is missing"):
+        segment_spmm(plan.lhs_blocks, plan.slot_idx, plan.m_idx, plan.k_idx,
+                     plan.seg_start, plan.seg_write, plan.accum_prev,
+                     plan.valid, x, grid_m=plan.grid[0],
+                     n_lanes=plan.n_lanes, bn=32, interpret=True)
 
 
 # ---------------------------------------------------------------------------

@@ -365,12 +365,12 @@ def test_rowwise_tightens_outlier_rows(base):
 
 
 @pytest.mark.parametrize("mode", ["int8.rowwise", "fp8.rowwise"])
-@pytest.mark.parametrize("pipeline", [True, False])
-def test_rowwise_spmm_three_way_parity(mode, pipeline):
+@pytest.mark.parametrize("prefetch", [None, "cross_pass"])
+def test_rowwise_spmm_three_way_parity(mode, prefetch):
     a = _outlier_bsr()
     x = jnp.asarray(RNG.standard_normal((a.shape[1], 48)).astype(np.float32))
     plan = api.plan_matmul(a, x.shape, quantize=mode, n_lanes=2,
-                           pipeline=pipeline, verify="full")
+                           prefetch=prefetch, verify="full")
     assert plan.quantized and plan.block_dtype == mode
     assert plan.lhs_scales.shape == (a.nblocks, a.block_shape[0])
     got_i = np.asarray(plan(x, bn=16, backend="interpret"))
@@ -455,15 +455,13 @@ def test_rowwise_scale_agreement_verifier():
 
 def test_sparse_linear_quantize_carries_full_planner_config():
     """Regression: quantize() used to rebuild the plan with only
-    lanes/unroll/backend, silently dropping the pipeline switch and the
-    tuned bn_hint."""
+    lanes/unroll/backend, silently dropping the tuned bn_hint."""
     from repro.models.sparse_ffn import SparseLinear
     layer, params = SparseLinear.create(jax.random.PRNGKey(3), 128, 64,
                                         block=32, density=0.4)
-    tuned = SparseLinear(plan=layer.plan.replace(pipeline=False, bn_hint=128),
+    tuned = SparseLinear(plan=layer.plan.replace(bn_hint=128),
                          d_out=64, d_in=128)
     qlayer, _ = tuned.quantize(params, "int8")
-    assert qlayer.plan.pipeline is False
     assert qlayer.plan.bn_hint == 128
     assert qlayer.plan.n_lanes == tuned.plan.n_lanes
     assert qlayer.plan.unroll == tuned.plan.unroll
