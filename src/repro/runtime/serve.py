@@ -37,18 +37,33 @@ resets/overwrites, and attention masking keeps them invisible.  MoE rows
 are independent too: expert dispatch drops no token, so each row is
 computed as if alone.
 
+One decode step stays in flight.  Each :meth:`Engine.step` dispatches the
+next step before it reads the last one back: a small program
+(``engine_feed``) builds the next step's tokens on the device from the
+last step's output, overridden for a slot admitted since, from one host
+array (override token, take-from-device flag, position) sent in one
+transfer.  So the host's reading and bookkeeping of step t overlap the
+device's work on step t+1.  A slot that step t would finish by its
+``max_new_tokens`` is known on the host: no step is dispatched after t,
+so the slot's next occupant and the other rows start the next step
+together, and each call still books one token for every live request.  A
+slot that an unread EOS finished runs one extra row, whose token is
+dropped (``decode_rows_discarded``); it writes only that slot's own cache
+row, which the next admission's fresh prefill zeroes.
+
 Kernel backend selection goes through :mod:`repro.api.backends`: an
 engine constructed with ``backend="interpret"`` (CPU correctness runs) or
 ``backend="pallas"`` (TPU) traces its jitted step functions under that
 backend, so Segment-plan layers in the model bake the right execution
 mode in.
 
-Observability: the two jitted programs are named ``engine_decode`` and
-``engine_prefill`` (``jit_engine_decode``/``jit_engine_prefill`` in a
-profiler trace); the host work is in ``segfold.engine.*`` spans
+Observability: the jitted programs are named ``engine_decode``,
+``engine_prefill`` and ``engine_feed`` (``jit_engine_decode`` and so on
+in a profiler trace); the host work is in ``segfold.engine.*`` spans
 (:mod:`repro.obs`): ``admit`` per admitted request holding a ``prefill``
-per chunk and the ``first_token`` sync, and ``step`` per decode step
-holding ``prepare``, ``dispatch``, ``sync`` and ``update``, and
+per chunk and the ``first_token`` sync; ``step`` per :meth:`Engine.step`
+call that decodes, holding ``prepare`` and ``dispatch`` for each step it
+dispatches and ``sync`` and ``update`` for each step it books; and
 ``cast_weights`` once in construction.  :meth:`Engine.counters` counts
 the work; an MoE model's counts are device data that come back with the
 tokens each program returns, so they add no sync.
@@ -91,8 +106,18 @@ class _Slot:
     out: List[int] = dataclasses.field(default_factory=list)
 
 
+@dataclasses.dataclass
+class _Step:
+    """A decode step dispatched and not yet booked."""
+    out: jax.Array                     # greedy tokens (S,), then the counts
+    rows: Dict[int, _Slot]             # slot index -> the occupant it ran for
+    ahead: bool                        # dispatched while the previous step
+                                       # was unread on the device
+
+
 #: the work :meth:`Engine.counters` counts
-COUNTERS = ("decode_steps", "decode_rows", "prefill_chunks", "prefill_tokens",
+COUNTERS = ("decode_steps", "decode_steps_ahead", "decode_rows",
+            "decode_rows_discarded", "prefill_chunks", "prefill_tokens",
             "prefill_padded_tokens", "spmm_cols_useful", "spmm_cols_computed"
             ) + MOE_COUNTERS
 
@@ -184,9 +209,14 @@ class Engine:
         # assert these stay flat across request arrivals/retirements
         self.decode_traces = 0
         self.prefill_traces = 0
+        self.feed_traces = 0
         self._counts = dict.fromkeys(COUNTERS, 0)
         # the counts each program returns after its tokens
         self._device_counts = MOE_COUNTERS if model.has_moe() else ()
+        # the decode step in flight, and what the feed reads without one
+        self._flight: Optional[_Step] = None
+        self._no_out = jnp.zeros((self.slots + len(self._device_counts),),
+                                 jnp.int32)
         # per compiled program: the Segment SpMM calls its trace reported
         self._spmm_calls: Dict[tuple, List[obs.SpmmCall]] = {}
 
@@ -199,7 +229,11 @@ class Engine:
             return self._prefill_fn(params, cache, slot, tok, pos, last_idx,
                                     fresh)
 
+        def engine_feed(prev, feed):
+            return self._feed_fn(prev, feed)
+
         self._decode = jax.jit(engine_decode, donate_argnums=(1,))
+        self._feed = jax.jit(engine_feed)
         self._prefill = jax.jit(engine_prefill, static_argnames=("fresh",),
                                 donate_argnums=(1,))
 
@@ -237,6 +271,14 @@ class Engine:
                 params, cache, tok, pos)
         self._spmm_calls[("decode",)] = calls
         return self._with_counts(logits, counts), cache
+
+    def _feed_fn(self, prev, feed):
+        """The next decode step's tok (S, 1) and pos (S,): ``feed`` is
+        (take, token, position) by slot; a slot with ``take`` set reads its
+        token from ``prev``, the step before's output."""
+        self.feed_traces += 1
+        take, tok, pos = feed
+        return jnp.where(take != 0, prev[:self.slots], tok)[:, None], pos
 
     def _prefill_fn(self, params, cache, slot, tok, pos, last_idx, fresh):
         """Prefill one chunk of one slot: slice the slot's cache row out,
@@ -363,43 +405,96 @@ class Engine:
         return filled
 
     def step(self) -> int:
-        """Admit into free slots, then run one batched decode step.
-        Returns the number of live slots that advanced."""
+        """Admit into free slots, then give every live request one token.
+        Returns the number of live slots that advanced.
+
+        Each pass dispatches the next decode step, from the tokens of the
+        step in flight where there is one, and then reads back and books
+        the step in flight.  A request admitted while a step was in flight
+        is not in it: its first pass books no token for it, so a second
+        pass runs, and the requests in the older step get two tokens in
+        this call.  The engine dispatches no step after one that finishes
+        a slot by ``max_new_tokens``, so that a request admitted into the
+        freed slot joins the others at an empty pipeline."""
         self.admit_pending()
-        live = [s for s in range(self.slots) if self._slots[s] is not None]
-        if not live:
+        want = {s for s in range(self.slots) if self._slots[s] is not None}
+        if not want:
+            self._drain()
             return 0
+        live = len(want)
         with obs.span("engine.step"):
-            with obs.span("engine.prepare"):
-                tok = np.zeros((self.slots, 1), np.int32)
-                pos = np.zeros((self.slots,), np.int32)
-                for s in live:
-                    tok[s, 0] = self._slots[s].last_tok
-                    pos[s] = self._slots[s].pos
-                tok, pos = jnp.asarray(tok), jnp.asarray(pos)
-            with obs.span("engine.dispatch"):
-                nxt, self.cache = self._decode(self.params, self.cache, tok,
-                                               pos)
-            with obs.span("engine.sync"):
-                nxt = np.asarray(nxt)
-            with obs.span("engine.update"):
-                self._add_counts(nxt, self.slots)
-                self._counts["decode_steps"] += 1
-                self._counts["decode_rows"] += len(live)
-                self._count_spmm(("decode",), len(live))
-                for s in live:
-                    slot = self._slots[s]
-                    slot.pos += 1               # last_tok now sits in cache
-                    slot.last_tok = int(nxt[s])
-                    slot.out.append(slot.last_tok)
-                    if self._finished(slot):
-                        self._retire(s)
-        return len(live)
+            while want:
+                prev = self._flight
+                self._flight = (None if self._finishes(prev)
+                                else self._dispatch(prev))
+                want -= self._book(prev)
+        return live
+
+    def _finishes(self, step: Optional[_Step]) -> bool:
+        """Whether booking ``step`` retires a slot by its
+        ``max_new_tokens``: the host knows before it reads the step."""
+        return step is not None and any(
+            self._slots[s] is slot
+            and len(slot.out) + 1 >= slot.request.max_new_tokens
+            for s, slot in step.rows.items())
+
+    def _dispatch(self, prev: Optional[_Step]) -> _Step:
+        """Dispatch a decode step over every live slot; a slot that
+        ``prev`` carries takes its token from ``prev`` on the device, one
+        position on."""
+        with obs.span("engine.prepare"):
+            feed = np.zeros((3, self.slots), np.int32)  # take, token, pos
+            rows = {}
+            for s, slot in enumerate(self._slots):
+                if slot is not None:
+                    take = prev is not None and prev.rows.get(s) is slot
+                    feed[:, s] = (take, slot.last_tok, slot.pos + take)
+                    rows[s] = slot
+            feed = jax.device_put(feed)
+        with obs.span("engine.dispatch"):
+            tok, pos = self._feed(self._no_out if prev is None else prev.out,
+                                  feed)
+            out, self.cache = self._decode(self.params, self.cache, tok, pos)
+        return _Step(out=out, rows=rows, ahead=prev is not None)
+
+    def _book(self, step: Optional[_Step]) -> set:
+        """Read ``step``'s tokens back and give each row's slot its token;
+        a row whose slot an EOS retired since it was dispatched is
+        dropped.  Returns the slots that got a token."""
+        if step is None:
+            return set()
+        with obs.span("engine.sync"):
+            out = np.asarray(step.out)
+        with obs.span("engine.update"):
+            kept = {s for s, slot in step.rows.items()
+                    if self._slots[s] is slot}
+            self._add_counts(out, self.slots)
+            self._counts["decode_steps"] += 1
+            self._counts["decode_steps_ahead"] += step.ahead
+            self._counts["decode_rows"] += len(kept)
+            self._counts["decode_rows_discarded"] += len(step.rows) - len(kept)
+            self._count_spmm(("decode",), len(kept))
+            for s in kept:
+                slot = self._slots[s]
+                slot.pos += 1               # last_tok now sits in cache
+                slot.last_tok = int(out[s])
+                slot.out.append(slot.last_tok)
+                if self._finished(slot):
+                    self._retire(s)
+        return kept
+
+    def _drain(self) -> None:
+        """Book the step in flight, if any, and dispatch none after it."""
+        prev, self._flight = self._flight, None
+        if prev is not None:
+            with obs.span("engine.step"):
+                self._book(prev)
 
     def run(self) -> None:
         """Drain the queue and all occupied slots."""
         while self._queue or any(s is not None for s in self._slots):
             self.step()
+        self._drain()
 
     def generate(self, requests: List[Request]) -> List[Request]:
         """Submit + drain; fills each request's ``out_tokens`` in place."""
@@ -412,12 +507,17 @@ class Engine:
 
     @property
     def compiled_shapes(self) -> Dict[str, int]:
-        """Trace counts per step function — flat after warmup."""
-        return {"decode": self.decode_traces, "prefill": self.prefill_traces}
+        """Trace counts per program — flat after warmup."""
+        return {"decode": self.decode_traces, "prefill": self.prefill_traces,
+                "feed": self.feed_traces}
 
     def counters(self) -> Dict[str, int]:
-        """The work done since construction (:data:`COUNTERS`): decode
-        steps and the live rows they carried; prefill chunks, their real
+        """The work done since construction (:data:`COUNTERS`), each
+        step counted when its tokens are booked: decode steps, those
+        dispatched while the step before was still unread on the device
+        (``decode_steps_ahead``), the rows that gave a live request its
+        token and those computed for a slot an unread EOS had already
+        finished (``decode_rows_discarded``); prefill chunks, their real
         prompt tokens and their bucket sizes; and the columns the Segment
         SpMM kernels computed against those that held a real row or token,
         summed over every SpMM call of every program run (N-tile padding,

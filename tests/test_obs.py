@@ -55,8 +55,10 @@ def test_engine_counters_match_a_hand_count(tiny):
     eng.generate(_requests(cfg))
     # chunks: 5 -> [8]; 21 -> [16, 8]; 9 -> [8, 8]
     chunks = [8, 16, 8, 8, 8]
-    # slots 0, 1 take requests 0 and 1; step 1 decodes both; step 2 retires
-    # request 0 (3 tokens); request 2 takes slot 0 and step 3 retires both
+    # slots 0, 1 take requests 0 and 1; step 1 decodes both; step 2,
+    # dispatched while step 1 was unread, retires request 0 (3 tokens), so
+    # nothing is dispatched after it; request 2 takes slot 0 and step 3
+    # retires both
     steps, rows = 3, 2 + 2 + 2
     assert rows == sum(m - 1 for _, m in MIX)   # the first token is prefill's
     calls = 3 * cfg.n_layers                    # up, gate, down in each layer
@@ -66,7 +68,8 @@ def test_engine_counters_match_a_hand_count(tiny):
         return n + pad
 
     assert eng.counters() == {
-        "decode_steps": steps, "decode_rows": rows,
+        "decode_steps": steps, "decode_steps_ahead": 1, "decode_rows": rows,
+        "decode_rows_discarded": 0,
         "prefill_chunks": len(chunks), "prefill_tokens": 35,
         "prefill_padded_tokens": sum(chunks),
         "spmm_cols_useful": calls * (rows + 35),
@@ -141,13 +144,17 @@ def test_spans_nest_in_a_cpu_profiler_trace(tiny, tmp_path):
     for child in prefills + named("engine.first_token"):
         assert len(inside(child, admits)) == 1
     assert len(named("engine.first_token")) == len(MIX)
-    phases = ["engine.prepare", "engine.dispatch", "engine.sync",
-              "engine.update"]
+    # each call dispatches the next step (prepare, dispatch), then books
+    # the one in flight (sync, update); no MIX request ends early, so each
+    # call books exactly one step
+    phases = {"segfold.engine." + p: p[0] for p in ("prepare", "dispatch",
+                                                    "sync", "update")}
+    for phase in ("dispatch", "sync"):
+        assert len(named("engine." + phase)) == len(steps)
     for step in steps:
-        held = [[s for s in named(p) if inside(s, [step])] for p in phases]
-        assert [len(h) for h in held] == [1] * 4
-        starts = [h[0].start for h in held]
-        assert starts == sorted(starts)
+        held = sorted((s.start, phases[s.name]) for s in spans
+                      if s.name in phases and inside(s, [step]))
+        assert re.fullmatch("(pd)?(pd)?su", "".join(p for _, p in held))
         assert not inside(step, admits)
     (execute,), (launch,) = named("execute"), named("execute.launch")
     assert inside(launch, [execute])

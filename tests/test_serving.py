@@ -109,7 +109,7 @@ def test_no_retrace_across_arrivals_and_retirements():
     eng.generate([Request(prompt=p, max_new_tokens=3)
                   for p in _prompts(cfg, (5, 20), seed=1)])
     warm = dict(eng.compiled_shapes)
-    assert warm["decode"] == 1
+    assert warm == {"decode": 1, "prefill": warm["prefill"], "feed": 1}
     # new arrivals: different lengths, different budgets, queueing + slot
     # churn — all served by the warm shapes
     eng.generate([Request(prompt=p, max_new_tokens=m)
@@ -185,6 +185,172 @@ def test_recurrent_family_mixed_lengths():
         reqs = [Request(prompt=p.copy(), max_new_tokens=4) for p in prompts]
         eng.generate(reqs)
         assert [r.out_tokens.tolist() for r in reqs] == alone, arch
+
+
+# ---------------------------------------------------------------------------
+# one decode step in flight
+# ---------------------------------------------------------------------------
+
+#: arch, config overrides, slots, prompt lengths, max_new_tokens, the index
+#: of each request's own token that is its eos_token (None: no eos), and how
+#: many requests arrive last, while a decode step is in flight
+PIPELINE_CASES = {
+    "eos": ("granite-3-8b", {}, 2, (5, 17, 9), (8, 6, 7), (2, None, 3), 0),
+    "max_new_1": ("granite-3-8b", {}, 2, (5, 12, 9, 4), (1, 5, 1, 3),
+                  (None,) * 4, 0),
+    "admit_in_flight": ("granite-3-8b", {}, 3, (6, 11, 8), (7, 6, 4),
+                        (None, 4, None), 1),
+    "rwkv": ("rwkv6-1.6b", {}, 1, (3, 6), (6, 4), (1, None), 0),
+    "hybrid": ("recurrentgemma-9b", {}, 2, (3, 6, 4), (5, 4, 3),
+               (1, None, None), 1),
+    "int8_kv": ("granite-3-8b", {"kv_cache_dtype": "int8"}, 1, (4, 11),
+                (6, 4), (2, None), 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PIPELINE_CASES))
+def test_pipelined_engine_matches_single_request_oracle(case):
+    """With one decode step in flight, every request still gets the tokens
+    it gets alone: mixed lengths, EOS part-way (the row a slot runs past
+    its unread EOS is dropped, and the next occupant's fresh prefill wipes
+    what it wrote), ``max_new_tokens=1``, an admission while a step is in
+    flight, recurrent state and the int8 KV cache."""
+    arch, over, slots, lens, news, eos_at, late = PIPELINE_CASES[case]
+    cfg, model, params = _setup(arch, **over)
+    prompts = _prompts(cfg, lens, seed=30)
+    want, eos = [], []
+    for p, m, k in zip(prompts, news, eos_at):
+        r = Request(prompt=p.copy(), max_new_tokens=m)
+        Engine(model, params, slots=1, max_len=64).generate([r])
+        full = r.out_tokens.tolist()
+        eos.append(None if k is None else full[k])
+        want.append(full if k is None else full[:full.index(full[k]) + 1])
+    eng = Engine(model, params, slots=slots, max_len=64)
+    reqs = [Request(prompt=p.copy(), max_new_tokens=m, eos_token=e)
+            for p, m, e in zip(prompts, news, eos)]
+    early = len(reqs) - late
+    for r in reqs[:early]:
+        eng.submit(r)
+    if late:
+        eng.step()
+        eng.step()
+        assert eng._flight is not None
+        assert any(s is None for s in eng._slots)
+        for r in reqs[early:]:
+            eng.submit(r)
+    eng.run()
+    assert [r.out_tokens.tolist() for r in reqs] == want
+    assert eng._flight is None and eng.completed == len(reqs)
+    c = eng.counters()
+    assert c["decode_rows"] == sum(len(w) - 1 for w in want)
+    assert c["decode_steps_ahead"] < c["decode_steps"]
+
+
+def _booked(eng, req) -> int:
+    """Tokens the engine has booked for ``req``: on the host, in order."""
+    slot = next((s for s in eng._slots
+                 if s is not None and s.request is req), None)
+    return len(slot.out) if slot is not None else len(req.out_tokens)
+
+
+def test_each_step_books_one_token_per_live_request():
+    """The benchmark's contract: driven as the benchmark drives it (one
+    request handed over for each free slot, then ``step``), every call
+    books exactly one new token for each request that was live at the
+    call, the first call after an admission included.  A step follows
+    the one before on the device except after a retirement, which the
+    host sees coming and dispatches nothing after, and at the start."""
+    cfg, model, params = _setup()
+    eng = Engine(model, params, slots=3, max_len=64, prefill_buckets=(16, 8))
+    reqs = [Request(prompt=p, max_new_tokens=m) for p, m in zip(
+        _prompts(cfg, (5, 9, 13, 4, 20, 7, 11), seed=31),
+        (6, 2, 9, 4, 3, 7, 5))]
+    queue, live, calls, behind, retired = list(reqs), [], 0, 0, True
+    while queue or live:
+        while queue and len(live) < eng.slots:
+            live.append(queue.pop(0))
+            eng.submit(live[-1])
+            assert eng.admit_pending() == 1
+        before = [_booked(eng, r) for r in live]
+        behind += retired
+        assert eng.step() == len(live)
+        calls += 1
+        assert [_booked(eng, r) for r in live] == [b + 1 for b in before]
+        retired = any(r.out_tokens is not None for r in live)
+        live = [r for r in live if r.out_tokens is None]
+    c = eng.counters()
+    assert c["decode_steps"] == calls
+    assert c["decode_steps_ahead"] == calls - behind > 0
+    assert c["decode_rows_discarded"] == 0
+    assert c["decode_rows"] == sum(r.max_new_tokens - 1 for r in reqs)
+
+
+def test_rows_past_an_unread_eos_are_counted_and_dropped():
+    """The first request's EOS comes back while the step after it is in
+    flight: that row is dropped, and the second request, admitted into the
+    same slot, serves as it does alone."""
+    cfg, model, params = _setup()
+    p1, p2 = _prompts(cfg, (7, 10), seed=32)
+    alone = []
+    for p, m in ((p1, 8), (p2, 3)):
+        r = Request(prompt=p.copy(), max_new_tokens=m)
+        Engine(model, params, slots=1, max_len=64,
+               prefill_buckets=(8,)).generate([r])
+        alone.append(r.out_tokens.tolist())
+    eos = alone[0][2]
+    assert alone[0].index(eos) == 2
+    eng = Engine(model, params, slots=1, max_len=64, prefill_buckets=(8,))
+    reqs = [Request(prompt=p1.copy(), max_new_tokens=8, eos_token=eos),
+            Request(prompt=p2.copy(), max_new_tokens=3)]
+    eng.generate(reqs)
+    assert [r.out_tokens.tolist() for r in reqs] == [alone[0][:3], alone[1]]
+    c = eng.counters()
+    # steps 1-2 bring the first request's two decoded tokens, step 3 runs
+    # past its EOS; steps 4-5 serve the second: only step 1 found nothing
+    # in flight
+    assert (c["decode_steps"], c["decode_steps_ahead"], c["decode_rows"],
+            c["decode_rows_discarded"]) == (5, 4, 4, 1)
+
+
+def test_moe_counts_book_every_program_run_once():
+    """On a tiny MoE engine the ``moe_*`` counters are the sums of what
+    each program run returned, served through the pipeline with slot
+    churn, an EOS and a dropped row; and each request's tokens are those
+    it gets served alone."""
+    from repro.models.moe import COUNTERS as MOE
+    cfg, model, params = _setup("moonlight-16b-a3b", experts_held=2)
+    prompts = _prompts(cfg, (5, 19, 9), seed=33)
+    alone = []
+    for p in prompts:
+        r = Request(prompt=p.copy(), max_new_tokens=5)
+        Engine(model, params, slots=1, max_len=64,
+               prefill_buckets=(16, 8)).generate([r])
+        alone.append(r.out_tokens.tolist())
+    eng = Engine(model, params, slots=2, max_len=64, prefill_buckets=(16, 8))
+    ran = []
+
+    def record(name, tokens):
+        program = getattr(eng, name)
+
+        def run(*args, **kw):
+            out, cache = program(*args, **kw)
+            ran.append(np.asarray(out)[tokens:])
+            return out, cache
+        setattr(eng, name, run)
+
+    record("_decode", eng.slots)
+    record("_prefill", 1)
+    eos = alone[0][1]
+    reqs = [Request(prompt=p.copy(), max_new_tokens=5) for p in prompts]
+    reqs[0].eos_token = eos
+    eng.generate(reqs)
+    stop = alone[0].index(eos) + 1
+    assert [r.out_tokens.tolist() for r in reqs] == [alone[0][:stop],
+                                                     *alone[1:]]
+    c = eng.counters()
+    assert c["decode_steps"] + c["prefill_chunks"] == len(ran)
+    assert [c[k] for k in MOE] == np.sum(ran, axis=0).tolist()
+    assert c["decode_rows_discarded"] >= 1 and c["moe_rows_routed"] > 0
 
 
 # ---------------------------------------------------------------------------
